@@ -17,11 +17,13 @@ except ImportError:  # source checkout without `pip install -e .`
 
 import flexflow_tpu as ff
 from flexflow_tpu.models.alexnet import build_alexnet
+from flexflow_tpu.utils.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
     cfg = ff.FFConfig()
     cfg.parse_args(argv)
+    enable_compile_cache()
     print(f"batchSize({cfg.batch_size}) workersPerNodes({cfg.workers_per_node}) "
           f"numNodes({cfg.num_nodes})")
     model = ff.FFModel(cfg)

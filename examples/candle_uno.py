@@ -23,6 +23,7 @@ import flexflow_tpu as ff
 from flexflow_tpu.models.candle_uno import (DEFAULT_FEATURE_SHAPES,
                                             DEFAULT_INPUT_FEATURES,
                                             build_candle_uno)
+from flexflow_tpu.utils.compile_cache import enable_compile_cache
 
 
 def synthetic_batch(batch_size, input_features, feature_shapes, seed=0):
@@ -38,6 +39,7 @@ def synthetic_batch(batch_size, input_features, feature_shapes, seed=0):
 def main(argv=None):
     cfg = ff.FFConfig()
     cfg.parse_args(argv)
+    enable_compile_cache()
     print(f"batchSize({cfg.batch_size}) workersPerNodes({cfg.workers_per_node}) "
           f"numNodes({cfg.num_nodes})")
 

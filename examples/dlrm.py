@@ -16,11 +16,13 @@ except ImportError:  # source checkout without `pip install -e .`
 
 import flexflow_tpu as ff
 from flexflow_tpu.models.dlrm import build_dlrm, synthetic_batch
+from flexflow_tpu.utils.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
     cfg = ff.FFConfig()
     rest = cfg.parse_args(argv)
+    enable_compile_cache()
     # reference DLRM flags (dlrm.cc parse_input_args)
     emb_sizes = [1000000] * 8
     sparse_dim = 64

@@ -19,11 +19,13 @@ import numpy as np
 
 import flexflow_tpu as ff
 from flexflow_tpu.keras.datasets import mnist
+from flexflow_tpu.utils.compile_cache import enable_compile_cache
 
 
 def top_level_task(argv=None, num_samples=4096):
     cfg = ff.FFConfig()
     cfg.parse_args(argv)
+    enable_compile_cache()
     (x_train, y_train), _ = mnist.load_data()
     x_train = x_train[:num_samples].reshape(-1, 784).astype(np.float32) / 255.0
     y_train = y_train[:num_samples].astype(np.int32).reshape(-1, 1)

@@ -18,11 +18,13 @@ except ImportError:  # source checkout without `pip install -e .`
 
 import flexflow_tpu as ff
 from flexflow_tpu.models.nmt import build_nmt, synthetic_batch
+from flexflow_tpu.utils.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
     cfg = ff.FFConfig(batch_size=64)
     rest = cfg.parse_args(argv)
+    enable_compile_cache()
     seq, hidden, embed, vocab, layers, iters = 20, 2048, 2048, 20 * 1024, 2, 10
     translate = False
     i = 0

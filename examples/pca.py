@@ -21,6 +21,7 @@ except ImportError:  # source checkout without `pip install -e .`
 import numpy as np
 
 import flexflow_tpu as ff
+from flexflow_tpu.utils.compile_cache import enable_compile_cache
 
 NPCS = 5
 NN_SHL = [10, 10, 10, 10, 10, 1]
@@ -58,6 +59,7 @@ def build_pca(model: ff.FFModel, batch_size: int):
 def main(argv=None):
     cfg = ff.FFConfig()
     cfg.parse_args(argv)
+    enable_compile_cache()
     model = ff.FFModel(cfg)
     inputs, out = build_pca(model, cfg.batch_size)
     model.compile(ff.SGDOptimizer(model, lr=0.05),

@@ -13,12 +13,14 @@ except ImportError:  # source checkout without `pip install -e .`
 
 import flexflow_tpu as ff
 from flexflow_tpu.models.resnet import build_resnet50
+from flexflow_tpu.utils.compile_cache import enable_compile_cache
 from examples.common import train_and_report
 
 
 def main(argv=None):
     cfg = ff.FFConfig()
     cfg.parse_args(argv)
+    enable_compile_cache()
     print(f"batchSize({cfg.batch_size}) workersPerNodes({cfg.workers_per_node}) "
           f"numNodes({cfg.num_nodes})")
     model = ff.FFModel(cfg)

@@ -21,12 +21,14 @@ import numpy as np
 
 import flexflow_tpu as ff
 from flexflow_tpu.models.transformer import build_transformer
+from flexflow_tpu.utils.compile_cache import enable_compile_cache
 
 
 def top_level_task(argv=None, seq=64, layers=4, dim=128, heads=8,
                    vocab=1024, iters=6):
     cfg = ff.FFConfig(batch_size=16)
     argv = cfg.parse_args(argv)
+    enable_compile_cache()
     for i, a in enumerate(list(argv or [])):
         if a == "--seq":
             seq = int(argv[i + 1])

@@ -20,6 +20,7 @@ import numpy as np
 
 import flexflow_tpu as ff
 from flexflow_tpu.models.transformer import build_transformer
+from flexflow_tpu.utils.compile_cache import enable_compile_cache
 
 
 def cyclic_batch(batch_size, seq, vocab, seed):
@@ -37,6 +38,7 @@ def cyclic_batch(batch_size, seq, vocab, seed):
 def top_level_task(argv=None, seq=32, vocab=32, iterations=150):
     cfg = ff.FFConfig(batch_size=16)
     cfg.parse_args(argv)
+    enable_compile_cache()
     if cfg.iterations > 0:  # --iterations (parse_args consumes the flag)
         iterations = cfg.iterations
 

@@ -35,8 +35,10 @@ def main():
     # config-module stash (``python -m flexflow_tpu`` has already
     # imported the package, so this costs nothing extra).
     from . import config as _config
+    from .utils.compile_cache import enable_compile_cache
 
     _config.set_runner_argv(argv[1:])
+    enable_compile_cache()
     sys.argv = [script] + passthrough
     runpy.run_path(script, run_name="__main__")
     return 0

@@ -140,12 +140,12 @@ def set_runner_argv(argv: Sequence[str]) -> None:
 
 
 def _env_default_devices() -> int:
-    try:
-        import jax
+    """All devices of the default backend.  A backend that fails to
+    initialise raises here — guessing "one device" would start a
+    training run on hardware nobody asked for."""
+    import jax
 
-        return max(1, len(jax.devices()))
-    except Exception:  # pragma: no cover - jax always present in practice
-        return 1
+    return len(jax.devices())
 
 
 @dataclasses.dataclass

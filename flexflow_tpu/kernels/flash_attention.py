@@ -12,6 +12,11 @@ sequence shards.
 Layout: (batch, heads, seq, head_dim), f32 or bf16 in / f32 accumulate.
 Grid is (batch*heads, q_blocks, k_blocks) with the k dimension innermost
 so the accumulator lives in VMEM scratch across the k sweep.
+
+The kernels compile through Mosaic and run on a TPU only.  Callers pick
+the implementation by platform (ops/attention.py); ``interpret=True``
+runs the same kernel bodies in the Pallas interpreter on any backend and
+is something a test asks for by name, never a default.
 """
 
 from __future__ import annotations
@@ -30,20 +35,46 @@ NEG_INF = -1e30
 _LANES = 128
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _largest_block(seq: int, cap: int) -> Optional[int]:
+    """The whole sequence when it fits under ``cap``; otherwise its
+    largest divisor that is <= ``cap`` and a multiple of 8 rows; None
+    when it has none."""
+    if seq <= cap:
+        return seq
+    for b in range(cap // 8 * 8, 0, -8):
+        if seq % b == 0:
+            return b
+    return None
 
 
-def _block_sizes(seq_q: int, seq_k: int, block_q: Optional[int], block_k: Optional[int]):
-    bq = block_q or min(512, seq_q)
-    bk = block_k or min(512, seq_k)
-    bq = min(bq, seq_q)
-    bk = min(bk, seq_k)
-    if seq_q % bq != 0:
-        bq = math.gcd(seq_q, bq)
-    if seq_k % bk != 0:
-        bk = math.gcd(seq_k, bk)
-    return bq, bk
+def unsupported_reason(seq_q: int, seq_k: int,
+                       block_q: Optional[int] = None,
+                       block_k: Optional[int] = None) -> Optional[str]:
+    """Why the kernel cannot tile this shape, or None when it can.
+
+    A block must divide its sequence (the grid has no remainder step).
+    Mosaic takes a block equal to the whole dimension whatever its
+    length, and otherwise wants whole 8-row sublane groups: on the v5e
+    with libtpu 0.0.34 blocks of 100 (= the sequence), 40 and 200 rows
+    compile and match the reference in f32 and bf16 (chip run, PR 21).
+    That leaves out a sequence longer than 512 with no divisor that is
+    a multiple of 8 (1009, 1018) — the caller's cue for the XLA path."""
+    for name, seq, want in (("q", seq_q, block_q), ("k", seq_k, block_k)):
+        if _largest_block(seq, want or 512) is None:
+            return (f"flash_attention: {name} sequence length {seq} has no "
+                    f"divisor <= {want or 512} that is a multiple of 8 rows")
+    return None
+
+
+def _block_sizes(seq_q: int, seq_k: int,
+                 block_q: Optional[int] = None, block_k: Optional[int] = None):
+    """(block_q, block_k) the kernels tile the two sequences with;
+    ValueError naming the sequence ``unsupported_reason`` rejects."""
+    why = unsupported_reason(seq_q, seq_k, block_q, block_k)
+    if why is not None:
+        raise ValueError(why)
+    return (_largest_block(seq_q, block_q or 512),
+            _largest_block(seq_k, block_k or 512))
 
 
 # ---------------------------------------------------------------------------
@@ -101,7 +132,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
 
 
-def _flash_forward(q, k, v, scale, causal, block_q, block_k):
+def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
     b, h, sq, d = q.shape
     sk = k.shape[2]
     bq, bk = _block_sizes(sq, sk, block_q, block_k)
@@ -133,7 +164,7 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k):
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
         ],
-        interpret=_use_interpret(),
+        interpret=interpret,
     )(qr, kr, vr)
     return (out.reshape(b, h, sq, d), lse[:, :, 0].reshape(b, h, sq))
 
@@ -234,7 +265,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[0] = dq_sc[:].astype(dq_ref.dtype)
 
 
-def _flash_backward(scale, causal, block_q, block_k, res, grads):
+def _flash_backward(scale, causal, block_q, block_k, interpret, res, grads):
     q, k, v, out, lse = res
     do, _ = grads
     b, h, sq, d = q.shape
@@ -276,7 +307,7 @@ def _flash_backward(scale, causal, block_q, block_k, res, grads):
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
-        interpret=_use_interpret(),
+        interpret=interpret,
     )(qr, kr, vr, dor, lser, deltar)
 
     dq = pl.pallas_call(
@@ -294,7 +325,7 @@ def _flash_backward(scale, causal, block_q, block_k, res, grads):
         out_specs=pl.BlockSpec((1, bq, d), lambda bh_, qi, ki: (bh_, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=_use_interpret(),
+        interpret=interpret,
     )(qr, kr, vr, dor, lser, deltar)
 
     return (dq.reshape(b, h, sq, d),
@@ -306,14 +337,14 @@ def _flash_backward(scale, causal, block_q, block_k, res, grads):
 # Public API
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, scale, causal, block_q, block_k):
-    out, _ = _flash_forward(q, k, v, scale, causal, block_q, block_k)
-    return out, _
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, scale, causal, block_q, block_k, interpret):
+    return _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret)
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
-    out, lse = _flash_forward(q, k, v, scale, causal, block_q, block_k)
+def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
+    out, lse = _flash_forward(q, k, v, scale, causal, block_q, block_k,
+                              interpret)
     return (out, lse), (q, k, v, out, lse)
 
 
@@ -324,16 +355,20 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    return_lse: bool = False):
+                    return_lse: bool = False,
+                    interpret: bool = False):
     """Fused attention: softmax(q k^T * scale [+ causal mask]) v.
 
     Args are (B, H, S, D).  Returns the output, plus the per-row
     logsumexp (B, H, S) when ``return_lse`` — ring attention uses the
-    lse to merge shard-local partials.
+    lse to merge shard-local partials.  Raises ValueError for a sequence
+    length the kernel cannot tile (``unsupported_reason``).
+    ``interpret=True`` runs the kernel in the Pallas interpreter (any
+    backend, for tests); the default compiles it for the TPU.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    out, lse = _flash(q, k, v, scale, causal, block_q, block_k)
+    out, lse = _flash(q, k, v, scale, causal, block_q, block_k, interpret)
     if return_lse:
         return out, lse
     return out

@@ -18,6 +18,10 @@ kernel runs a 1-D grid of row-blocks with all operands aliased in-place.
 XLA fuses unrolled elementwise updates well already, so the win here is
 bounded — the point is parity of the "native kernel" path and the
 in-place aliasing (no param-sized temporaries at peak memory).
+
+The kernels compile through Mosaic for a TPU; ``interpret=True`` runs
+them in the Pallas interpreter on any backend.  The caller decides
+(``FFModel.compile`` by the machine's platform, and says so).
 """
 
 from __future__ import annotations
@@ -31,10 +35,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
 _ROWS = 8  # f32 sublane tile
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _row_block(n: int) -> int:
@@ -75,7 +75,8 @@ def _sgd_kernel(hp_ref, w_ref, g_ref, m_ref, w_out, m_out, *, momentum, nesterov
     w_out[:] = (w - lr * upd).astype(w_out.dtype)
 
 
-def fused_sgd_update(w, g, m, lr, wd=0.0, momentum=0.0, nesterov=False):
+def fused_sgd_update(w, g, m, lr, wd=0.0, momentum=0.0, nesterov=False,
+                     interpret=False):
     """One fused SGD step on a single parameter; returns (w_new, m_new)."""
     wt, n = _to_tiles(w)
     gt, _ = _to_tiles(g)
@@ -101,7 +102,7 @@ def fused_sgd_update(w, g, m, lr, wd=0.0, momentum=0.0, nesterov=False):
             jax.ShapeDtypeStruct(mt.shape, mt.dtype),
         ],
         input_output_aliases={1: 0, 3: 1},
-        interpret=_use_interpret(),
+        interpret=interpret,
     )(hp, wt, gt, mt)
     return (_from_tiles(w2, n, w.shape, w.dtype),
             _from_tiles(m2, n, m.shape, m.dtype))
@@ -122,7 +123,7 @@ def _adam_kernel(hp_ref, w_ref, g_ref, m_ref, v_ref, w_out, m_out, v_out,
 
 
 def fused_adam_update(w, g, m, v, alpha_t, wd=0.0, beta1=0.9, beta2=0.999,
-                      eps=1e-8):
+                      eps=1e-8, interpret=False):
     """One fused Adam step; ``alpha_t`` carries the bias correction.
 
     Returns (w_new, m_new, v_new)."""
@@ -156,7 +157,7 @@ def fused_adam_update(w, g, m, v, alpha_t, wd=0.0, beta1=0.9, beta2=0.999,
             jax.ShapeDtypeStruct(vt.shape, vt.dtype),
         ],
         input_output_aliases={1: 0, 3: 1, 4: 2},
-        interpret=_use_interpret(),
+        interpret=interpret,
     )(hp, wt, gt, mt, vt)
     return (_from_tiles(w2, n, w.shape, w.dtype),
             _from_tiles(m2, n, m.shape, m.dtype),

@@ -15,8 +15,8 @@ predictions against the walls the telemetry log actually records:
     standalone wall.
 
 ``tools/health_report.py`` folds these into the predicted-vs-measured
-agreement table that slots into CALIBRATION.md's multi-point
-validation.  Heavy imports stay inside functions: this module is only
+agreement table (docs/simulator.md, "Calibrating the cost
+model").  Heavy imports stay inside functions: this module is only
 reached from post-compile paths, but importing it must stay cheap for
 the stdlib-only health monitor.
 """

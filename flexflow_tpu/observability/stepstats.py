@@ -10,11 +10,12 @@ Computes, per ``update()``:
   * first-step wall time separately (jit trace + XLA compile happen
     inside step 0 — the reference's epoch-0 Legion trace capture),
   * samples/s and samples/s/chip,
-  * analytic-FLOP MFU: train FLOPs estimated as 3x the graph's forward
-    FLOPs (fwd + dgrad + wgrad — the same accounting bench.py and the
-    reference's backward multiplier use) against the machine model's
-    peak (``simulator/machine.py``, the calibrated numbers behind
-    ``simulator/cost_model.py``'s roofline),
+  * analytic-FLOP MFU, on a TPU only: train FLOPs estimated as 3x the
+    graph's forward FLOPs (fwd + dgrad + wgrad — the same accounting
+    bench.py and the reference's backward multiplier use) against the
+    published peak of the chip the step ran on
+    (``simulator/machine.py`` ``DEVICE_PEAKS``, keyed by
+    ``device_kind``).  On any other platform the field is absent,
   * estimated per-step collective bytes from each op's RESOLVED
     ``ParallelConfig`` (gradient all-reduce of replicated weights over
     the batch axis + activation redistribution for non-batch splits),
@@ -133,11 +134,12 @@ class StepStats:
         if self._fwd_flops_per_sample is None:
             self._fwd_flops_per_sample = float(
                 sum(op.flops_per_sample() for op in self.model.ops))
-            from ..simulator.machine import TPUMachineModel
+            from ..simulator.machine import device_peak_flops
 
-            nd = self.model.machine.num_devices if self.model.machine else 1
-            self._peak_flops = float(
-                TPUMachineModel.calibrated(num_devices=nd).peak_flops)
+            # None off-TPU: a CPU step has no chip peak to be a share of
+            dev = self.model.machine.devices[0]
+            self._peak_flops = (device_peak_flops(dev.device_kind)
+                                if dev.platform == "tpu" else None)
             self._collective_bytes = estimate_collective_bytes(self.model)
         return self._fwd_flops_per_sample, self._peak_flops
 
@@ -161,17 +163,18 @@ class StepStats:
         bs = self.model.config.batch_size
         nd = self.model.machine.num_devices if self.model.machine else 1
         sps = bs / dur if dur > 0 else 0.0
-        # fwd + dgrad + wgrad ~= 3x forward (reference backward accounting)
-        mfu = (3.0 * fwd_fps * sps / (nd * peak)) if peak else 0.0
-        log.span_at("step", t0, dur, step=step_idx, first=first,
-                    trace_id=self.trace_id, batch_size=bs,
-                    samples_per_sec=round(sps, 2),
-                    samples_per_sec_per_chip=round(sps / nd, 2),
-                    mfu=round(mfu, 6))
+        attrs = dict(step=step_idx, first=first, trace_id=self.trace_id,
+                     batch_size=bs, samples_per_sec=round(sps, 2),
+                     samples_per_sec_per_chip=round(sps / nd, 2))
+        if peak:
+            # fwd + dgrad + wgrad ~= 3x forward (reference accounting)
+            attrs["mfu"] = round(3.0 * fwd_fps * sps / (nd * peak), 6)
+        log.span_at("step", t0, dur, **attrs)
         log.counter("samples", float(bs))
         log.gauge("samples_per_sec", round(sps, 2))
         log.gauge("samples_per_sec_per_chip", round(sps / nd, 2))
-        log.gauge("mfu", round(mfu, 6))
+        if peak:
+            log.gauge("mfu", attrs["mfu"])
         if first:
             # step 0 wall includes jit trace + XLA compile
             log.gauge("first_step_wall_s", round(dur, 6))

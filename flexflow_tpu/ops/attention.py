@@ -5,17 +5,23 @@ its SOAP abstraction (partition any output dim, include/config.h:42-51) is
 what these ops extend to the sequence dim.  A MultiHeadAttention output is
 (B, S, E); a ParallelConfig of (dp, sp, 1) lowers to:
 
-  * sp == 1: fused flash attention on-chip (kernels/flash_attention.py,
-    pallas), GSPMD handling dp like any other op;
+  * sp == 1: one attention call per chip, GSPMD handling dp like any
+    other op;
   * sp > 1: ring attention over the mesh axes assigned to the sequence
     dim (parallel/sequence.py) — K/V rotate over ICI via ppermute and
     per-chip memory stays O(S/sp · S/sp) instead of O(S²).
+
+Either way the per-chip attention is the Pallas flash kernel
+(kernels/flash_attention.py) on a TPU when the kernel can tile the
+shape, and XLA's ``blockwise_attention`` otherwise; the op records which
+ran and why in ``impl_used`` (``MultiHeadAttention._pick_impl``).
 """
 
 from __future__ import annotations
 
 import math
-from typing import List, Optional
+import warnings
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -83,6 +89,12 @@ class MultiHeadAttention(Op):
         self.dropout = dropout
         self.use_bias = use_bias
         self.seq_parallel_mode = seq_parallel_mode
+        # None: chosen by platform and shape at trace time (_pick_impl).
+        # A test sets "pallas_interpret" (the kernel in the Pallas
+        # interpreter, any backend), "pallas" or "xla" by name.
+        self.impl: Optional[str] = None
+        # (impl, why) of the last trace — what actually ran.
+        self.impl_used: Optional[Tuple[str, str]] = None
         b, sq, _ = query.dims
         self._add_output((b, sq, embed_dim), query.dtype)
         init = kernel_initializer or DefaultWeightInitializer()
@@ -120,6 +132,27 @@ class MultiHeadAttention(Op):
             return 1
         return pc.dims[1]
 
+    def _pick_impl(self, seq_q: int, seq_k: int) -> Tuple[str, str]:
+        """(impl, why) for one chip's (seq_q, seq_k) attention block:
+        the Pallas kernel on a TPU when it can tile the shape, else the
+        XLA path — and on a TPU that is worth a warning, because the
+        shape is then running without the kernel written for it."""
+        if self.impl is not None:
+            if self.impl not in ("pallas", "pallas_interpret", "xla"):
+                raise ValueError(f"{self.name}: unknown attention impl "
+                                 f"{self.impl!r}")
+            return self.impl, "set on the op"
+        from ..kernels.flash_attention import unsupported_reason
+
+        platform = self.model.machine.devices[0].platform
+        if platform != "tpu":
+            return "xla", f"platform is {platform}"
+        why = unsupported_reason(seq_q, seq_k)
+        if why is not None:
+            warnings.warn(f"{self.name}: {why}; using XLA attention")
+            return "xla", why
+        return "pallas", "platform is tpu"
+
     def forward(self, params, xs: List[jax.Array], ctx: FwdCtx):
         q_in, k_in, v_in = xs
         B, Sq, _ = q_in.shape
@@ -135,7 +168,16 @@ class MultiHeadAttention(Op):
 
         sp = self._seq_degree()
         machine = self.model.machine
-        if sp > 1 and machine.num_devices > 1 and Sq == k_in.shape[1]:
+        Sk = k_in.shape[1]
+        seq_par = sp > 1 and machine.num_devices > 1 and Sq == Sk
+        if seq_par and self.seq_parallel_mode == "ring":
+            impl, why = self._pick_impl(Sq // sp, Sk // sp)
+        else:  # ulysses re-shards to whole sequences per chip
+            impl, why = self._pick_impl(Sq, Sk)
+        self.impl_used = (impl, why)
+        use_flash = impl != "xla"
+        interpret = impl == "pallas_interpret"
+        if seq_par:
             from ..parallel.sequence import sequence_parallel_attention
             degrees = list(self.pc.dims) + [1] * (3 - len(self.pc.dims))
             groups = machine.axes_for_degrees(degrees[:3])
@@ -143,10 +185,12 @@ class MultiHeadAttention(Op):
             seq_axes = groups[1]
             oh = sequence_parallel_attention(
                 qh, kh, vh, machine.mesh, seq_axes, batch_axes=batch_axes,
-                causal=self.causal, scale=scale, mode=self.seq_parallel_mode)
-        elif jax.default_backend() == "tpu":
+                causal=self.causal, scale=scale, mode=self.seq_parallel_mode,
+                use_flash=use_flash, interpret=interpret)
+        elif use_flash:
             from ..kernels.flash_attention import flash_attention
-            oh = flash_attention(qh, kh, vh, causal=self.causal, scale=scale)
+            oh = flash_attention(qh, kh, vh, causal=self.causal, scale=scale,
+                                 interpret=interpret)
         else:
             from ..parallel.sequence import blockwise_attention
             oh, _ = blockwise_attention(qh, kh, vh, causal=self.causal,

@@ -52,6 +52,9 @@ class Optimizer:
     param_specs = None
     nonfused_paths: frozenset = frozenset()
     zero_specs = None  # ZeRO-1: {(op, weight): PartitionSpec} for STATE
+    # True runs the fused kernels in the Pallas interpreter; set by
+    # FFModel.compile when the machine is not a TPU.
+    fused_interpret = False
 
     def set_mesh(self, mesh, param_specs, nonfused_paths=()) -> None:
         """``nonfused_paths``: (op_name, weight_name) leaves that must
@@ -110,9 +113,8 @@ class Optimizer:
         single device.  ``hp`` is a replicated scalar vector."""
         if self.mesh is None or self.mesh.devices.size <= 1 or spec is None:
             return fn
+        from jax import shard_map
         from jax.sharding import PartitionSpec
-
-        from .compat import shard_map
 
         scalar = PartitionSpec()
         in_specs = tuple([scalar] + [spec] * n_in)
@@ -179,8 +181,9 @@ class SGDOptimizer(Optimizer):
                         step = gt + mom * vn if self.nesterov else vn
                         return w - lr * step.astype(w.dtype), vn
                     def body(hp, w, g, v):
-                        return fused_sgd_update(w, g, v, hp, wd, mom,
-                                                self.nesterov)
+                        return fused_sgd_update(
+                            w, g, v, hp, wd, mom, self.nesterov,
+                            interpret=self.fused_interpret)
                     return self._shardwise(body, self._spec_for_path(path),
                                            3, 2)(lr, w, g, v)
 
@@ -193,7 +196,9 @@ class SGDOptimizer(Optimizer):
                     return w - lr * (g + wd * w).astype(w.dtype)
                 def body(hp, w, g):
                     # momentum buffer unused: the kernel passes it through
-                    return fused_sgd_update(w, g, g, hp, wd, 0.0, False)[0]
+                    return fused_sgd_update(
+                        w, g, g, hp, wd, 0.0, False,
+                        interpret=self.fused_interpret)[0]
                 return self._shardwise(body, self._spec_for_path(path),
                                        2, 1)(lr, w, g)
 
@@ -262,7 +267,9 @@ class AdamOptimizer(Optimizer):
                     wt = (w - alpha_t * mt / (jnp.sqrt(vt) + eps)).astype(w.dtype)
                     return wt, mt, vt
                 def body(hp, w, g, m, v):
-                    return fused_adam_update(w, g, m, v, hp, wd, b1, b2, eps)
+                    return fused_adam_update(
+                        w, g, m, v, hp, wd, b1, b2, eps,
+                        interpret=self.fused_interpret)
                 return self._shardwise(body, self._spec_for_path(path),
                                        4, 3)(alpha_t, w, g, m, v)
 

@@ -22,10 +22,8 @@ from typing import Callable, List, Optional, Sequence, Tuple, Union
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec
-
-from ..compat import shard_map
 
 
 def sequential_stages(stage_fn: Callable, stage_params, x):

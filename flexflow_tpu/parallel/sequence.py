@@ -27,8 +27,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
-from ..compat import shard_map
 
 from ..kernels.flash_attention import flash_attention, NEG_INF
 
@@ -61,8 +61,8 @@ def blockwise_attention(q, k, v, *, scale: Optional[float] = None,
     (normalized out, lse).  Offsets give the blocks' absolute sequence
     positions so a causal mask works across shards; they may be traced.
 
-    This is the jnp fallback path — the pallas flash kernel is used
-    instead when shapes/placement allow (see ring_attention).
+    This is the XLA path — the caller picks between it and the Pallas
+    flash kernel (ops/attention.py decides by platform and shape).
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -90,7 +90,7 @@ def blockwise_attention(q, k, v, *, scale: Optional[float] = None,
 
 def ring_attention(q, k, v, axis_name: str, *, causal: bool = False,
                    scale: Optional[float] = None,
-                   use_flash: Optional[bool] = None):
+                   use_flash: bool = False, interpret: bool = False):
     """Ring attention over sequence shards.  Call inside shard_map.
 
     q, k, v: (B, H, S_local, D), the local shard of a sequence split
@@ -98,12 +98,14 @@ def ring_attention(q, k, v, axis_name: str, *, causal: bool = False,
     block against the currently-held K/V block, then rotates K/V one hop
     around the ring (lax.ppermute over ICI), merging the normalized
     partials by logsumexp.  Numerically identical to full attention over
-    the gathered sequence.
+    the gathered sequence.  ``use_flash`` runs each block pair through
+    the Pallas kernel (``interpret`` as in ``flash_attention``) instead
+    of ``blockwise_attention``; the caller chooses.
     """
-    if use_flash is None:
-        use_flash = jax.default_backend() == "tpu"
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    flash = partial(flash_attention, scale=scale, return_lse=True,
+                    interpret=interpret)
     n = jax.lax.psum(1, axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, h, s_loc, d = q.shape
@@ -115,17 +117,16 @@ def ring_attention(q, k, v, axis_name: str, *, causal: bool = False,
         src = (idx - step) % n
         if not causal:
             if use_flash:
-                return flash_attention(qb, kb, vb, scale=scale, return_lse=True)
+                return flash(qb, kb, vb)
             return blockwise_attention(qb, kb, vb, scale=scale)
         if use_flash:
             if step == 0:
                 # Diagonal block: positions align, plain causal flash.
-                return flash_attention(qb, kb, vb, scale=scale, causal=True,
-                                       return_lse=True)
+                return flash(qb, kb, vb, causal=True)
             # step >= 1: block is strictly earlier (full attention) when
             # src < idx, i.e. idx >= step; otherwise fully masked.
             def full(_):
-                return flash_attention(qb, kb, vb, scale=scale, return_lse=True)
+                return flash(qb, kb, vb)
 
             def masked(_):
                 return (jnp.zeros_like(qb),
@@ -146,7 +147,7 @@ def ring_attention(q, k, v, axis_name: str, *, causal: bool = False,
 
 def ulysses_attention(q, k, v, axis_name: str, *, causal: bool = False,
                       scale: Optional[float] = None,
-                      use_flash: Optional[bool] = None):
+                      use_flash: bool = False, interpret: bool = False):
     """DeepSpeed-Ulysses-style sequence parallelism.  Call inside shard_map.
 
     q, k, v: (B, H, S_local, D) sequence shards.  all_to_all re-shards to
@@ -154,15 +155,13 @@ def ulysses_attention(q, k, v, axis_name: str, *, causal: bool = False,
     sequence, and the inverse all_to_all restores sequence sharding.
     Requires H divisible by the axis size.
     """
-    if use_flash is None:
-        use_flash = jax.default_backend() == "tpu"
-    n = jax.lax.psum(1, axis_name)
     # seq-sharded → head-sharded: split heads, concat seq.
     qh = jax.lax.all_to_all(q, axis_name, split_axis=1, concat_axis=2, tiled=True)
     kh = jax.lax.all_to_all(k, axis_name, split_axis=1, concat_axis=2, tiled=True)
     vh = jax.lax.all_to_all(v, axis_name, split_axis=1, concat_axis=2, tiled=True)
     if use_flash:
-        oh = flash_attention(qh, kh, vh, scale=scale, causal=causal)
+        oh = flash_attention(qh, kh, vh, scale=scale, causal=causal,
+                             interpret=interpret)
     else:
         oh, _ = blockwise_attention(qh, kh, vh, scale=scale, causal=causal)
     return jax.lax.all_to_all(oh, axis_name, split_axis=2, concat_axis=1, tiled=True)
@@ -172,7 +171,8 @@ def sequence_parallel_attention(q, k, v, mesh: Mesh, seq_axes, *,
                                 batch_axes=None, causal: bool = False,
                                 scale: Optional[float] = None,
                                 mode: str = "ring",
-                                use_flash: Optional[bool] = None):
+                                use_flash: bool = False,
+                                interpret: bool = False):
     """Run ring/Ulysses attention over global (B, H, S, D) arrays.
 
     Wraps shard_map over ``mesh``: sequence dim sharded by ``seq_axes``
@@ -195,6 +195,6 @@ def sequence_parallel_attention(q, k, v, mesh: Mesh, seq_axes, *,
              out_specs=spec, check_vma=False)
     def run(ql, kl, vl):
         return fn(ql, kl, vl, axis_name, causal=causal, scale=scale,
-                  use_flash=use_flash)
+                  use_flash=use_flash, interpret=interpret)
 
     return run(q, k, v)

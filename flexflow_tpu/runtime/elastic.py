@@ -2,8 +2,8 @@
 
 The reference is strictly fail-stop — any CUDA error aborts the process
 (FatalError, cuda_helper.h:6-36) and nothing is checkpointed (SURVEY
-§5.3/5.4).  TPU jobs get preempted and tunnels/pods can wedge (every op
-hangs without erroring), so this module adds the recovery pieces a
+§5.3/5.4).  TPU jobs get preempted and a pod can hang (every op blocks
+without erroring), so this module adds the recovery pieces a
 long-running training needs:
 
   * ``elastic_train`` — drives the epoch loop through a

@@ -25,13 +25,39 @@ from typing import Dict, Tuple
 # Roofline constants fitted to real-chip measurements by tools/calibrate.py.
 CALIBRATION_PATH = os.path.join(os.path.dirname(__file__), "machine_v5e.json")
 
+# Published peaks of ONE chip, keyed by ``device_kind`` as JAX reports
+# it.  The only place a peak is written down: utilization figures
+# (bench.py, observability/stepstats.py) divide by these, and the
+# simulator's defaults below are the v5e row.
+# Source: Google Cloud documentation, "TPU v5e" (system architecture):
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s per chip.
+DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bandwidth": 819e9,
+                    "hbm_capacity": 16e9},
+}
+
+
+def device_peak_flops(device_kind: str) -> float:
+    """Peak bf16 FLOP/s of one chip of ``device_kind``.  An unknown kind
+    is an error, never a default: a utilization divided by another
+    chip's peak is a wrong number with nothing to flag it."""
+    try:
+        return DEVICE_PEAKS[device_kind]["bf16_flops"]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r}; add a "
+            f"sourced row to DEVICE_PEAKS "
+            f"(known: {sorted(DEVICE_PEAKS)})") from None
+
+_V5E = DEVICE_PEAKS["TPU v5 lite"]
+
 
 @dataclasses.dataclass
 class TPUMachineModel:
     num_devices: int = 8
     chips_per_host: int = 8
-    peak_flops: float = 197e12        # bf16 MXU
-    hbm_bandwidth: float = 819e9      # bytes/s
+    peak_flops: float = _V5E["bf16_flops"]        # bf16 MXU
+    hbm_bandwidth: float = _V5E["hbm_bandwidth"]  # bytes/s
     ici_bandwidth: float = 45e9       # bytes/s per link per direction
     dcn_bandwidth: float = 25e9       # bytes/s per host
     kernel_launch_overhead: float = 2e-6   # s; XLA per-fused-region overhead
@@ -41,11 +67,10 @@ class TPUMachineModel:
     # ZCM placement): chip<->host PCIe and host DDR stream bandwidth.
     pcie_bandwidth: float = 32e9      # bytes/s per direction (gen4 x16)
     host_memory_bandwidth: float = 100e9  # bytes/s effective DDR gather
-    # Fixed per-transfer host<->device latency (0 on local PCIe; tens of
-    # ms behind a network tunnel — tools/calibrate.py fits it from the
-    # measured host_xfer ladder alongside pcie_bandwidth).
+    # Fixed per-transfer host<->device latency — tools/calibrate.py fits
+    # it from the measured host_xfer ladder alongside pcie_bandwidth.
     host_xfer_latency: float = 0.0
-    hbm_capacity: float = 16e9        # bytes per chip (v5e 16 GB)
+    hbm_capacity: float = _V5E["hbm_capacity"]  # bytes per chip
     # Per-op-family roofline overrides fitted by tools/calibrate.py once
     # enough measured families land (e.g. {"Conv2D": 0.5, "LSTM": 0.3});
     # families absent here use the global constants above.  One global

@@ -31,7 +31,7 @@ or above (padding, layout copies) these numbers.  The compile plane
 (``observability/memplane.py``) folds ``compiled.memory_analysis()``
 into the same trace so ``tools/memory_report.py`` can show all three
 views side by side — divergence there feeds fixes here, exactly as
-CALIBRATION.md's runtime loop does for ``cost_model.py``.
+the calibration loop (docs/simulator.md) does for ``cost_model.py``.
 """
 
 from __future__ import annotations

@@ -20,7 +20,8 @@ Spec grammar (``FF_CHAOS`` environment variable)::
                               NaN (step site: the step's loss and grads
                               go non-finite)
                | "hang"       sleep ``arg`` seconds (default 3600) —
-                              a wedged device/tunnel for watchdog tests
+                              a device that stopped answering, for
+                              watchdog tests
                | "io_error"   raise ChaosIOError (an OSError: retried by
                               the checkpoint retry wrapper)
                | "sigterm"    os.kill(self, SIGTERM) — a preemption

@@ -56,8 +56,8 @@ def main(argv: List[str] = None) -> int:
     ap.add_argument("--workdir", default="bench_fleet",
                     help="output directory (BENCH_FLEET.json + traces)")
     ap.add_argument("--ledger", default=None,
-                    help="perf ledger path (default: FF_PERF_LEDGER or "
-                         "repo PERF_LEDGER.jsonl)")
+                    help="perf log path (default: FF_PERF_LEDGER or "
+                         "the checkout's ff_perf_log.jsonl)")
     ap.add_argument("--no-ledger", action="store_true",
                     help="skip the perf-ledger append")
     args = ap.parse_args(argv)

@@ -12,8 +12,7 @@ simulator".  Sections:
   * simulator agreement: step-level predicted-vs-measured and the
     per-op table from ``sim_divergence`` events (ratio per op/dir,
     worst-case band, both sides' provenance — prediction src and
-    measurement src) — rows slot into CALIBRATION.md's multi-point
-    validation table,
+    measurement src),
   * op runtime: the in-training measured attribution table from
     ``FF_OPPROF``'s ``op_runtime`` events (measured vs analytic ms,
     divergence ratio, cadence coverage),

@@ -17,7 +17,7 @@ Cross-checks the three HBM views the memory & compile plane records:
 Any two views disagreeing by more than the divergence band (a factor of
 |2| either way) get a loud ``!!`` row — that is the signal that either
 the analytic model or the deployment assumption is wrong, and it feeds
-the calibration loop (see CALIBRATION.md).
+the calibration loop (see docs/simulator.md).
 
 STDLIB-ONLY: a trace from a TPU pod must be foldable on any laptop.
 
@@ -239,7 +239,7 @@ def render(f: Dict[str, Any], path: str) -> str:
             out.append("")
             out.append(f"`!!` marks a ratio outside [1/{DIVERGENCE_BAND:g}, "
                        f"{DIVERGENCE_BAND:g}] — the analytic model or the "
-                       "deployment assumption is wrong; see CALIBRATION.md")
+                       "deployment assumption is wrong; see docs/simulator.md")
     else:
         out.append("(fewer than two views in trace — nothing to cross-check)")
     out.append("")

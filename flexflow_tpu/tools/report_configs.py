@@ -5,7 +5,7 @@ The calibration (tools/calibrate.py) and the SOAP reports
 model, or the reports' measured provenance silently stays at zero —
 cache keys encode sub-tensor shapes, so a batch mismatch means no
 measured entry ever matches a priced op.  Both tools default from this
-table; tools/chip_session.sh pins overrides through both consistently.
+table.
 
 Reference anchors: AlexNet global batch 64 is the reference default
 (src/runtime/model.cc:1238, BASELINE.json config #1); DLRM/NMT use the
@@ -55,8 +55,8 @@ REPORT_COMPUTE_DTYPE = "bfloat16"
 THIN_FIT_POINTS = 16
 THIN_FIT_OP_TYPES = 3
 
-# tpu_watch stops converting windows once the measured cache holds this
-# many TPU entries (the default ~654-job space is majority-measured);
+# doctor's "perf" section reports measured-cache coverage against this
+# many TPU entries (the default ~654-job space majority-measured);
 # shrink alongside --models if the job space is narrowed.
 CALIBRATION_TARGET_ENTRIES = 350
 

@@ -6,7 +6,7 @@ twice — FF_SIM_DELTA=1 then FF_SIM_DELTA=0 — asserts the two
 SearchResults are IDENTICAL (strategy map, best_s, dp_s: the delta
 simulator's bitwise-equality contract), prints a JSON line with both
 proposals/sec numbers and their ratio, and appends a
-``search_throughput`` entry to PERF_LEDGER.jsonl so
+``search_throughput`` entry to the program's perf log so
 tools/perf_ledger.py regression detection covers search speed the same
 way it covers training throughput.
 
@@ -159,7 +159,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                         "fastest (results must agree across repeats; "
                         "throughput mode only)")
     p.add_argument("--ledger", default=None,
-                   help="perf-ledger path (default: repo PERF_LEDGER.jsonl)")
+                   help="perf-log path (default: FF_PERF_LEDGER or the "
+                        "checkout's ff_perf_log.jsonl)")
     p.add_argument("--no-ledger", action="store_true",
                    help="measure + compare only, append nothing")
     args = p.parse_args(argv)

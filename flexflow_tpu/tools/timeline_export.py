@@ -25,8 +25,6 @@ Track layout (process -> threads):
   search     strategy-search spans + search_*/sim_* progress events
   compile    the compile-plane observatory (compile_done retrace
              markers, XLA memory/cost probes)
-  chips      chip-session probes (chip_probe / chip_window /
-             measurement_progress)
 
 STDLIB-ONLY like every reader in tools/: a trace from a TPU pod must
 fold on any laptop.  Timestamps are the log's relative seconds scaled
@@ -56,8 +54,6 @@ SEARCH_SPANS = frozenset((
 COMPILE_EVENTS = frozenset((
     "compile_done", "xla_memory", "xla_memory_error", "xla_cost",
     "xla_cost_error", "memory_predicted", "memory_predicted_error"))
-CHIP_EVENTS = frozenset((
-    "chip_probe", "chip_window", "measurement_progress"))
 SERVE_EVENT_PREFIXES = ("serve_", "request_", "replica_", "pool_",
                         "slo_", "kv_")
 
@@ -127,8 +123,6 @@ def _classify_event(rec: Dict[str, Any],
         return "requests", _request_tid(attrs)
     if name in COMPILE_EVENTS:
         return "compile", "compile"
-    if name in CHIP_EVENTS:
-        return "chips", "chips"
     if name.startswith(("search_", "sim_")):
         return "search", "search"
     if name.startswith(SERVE_EVENT_PREFIXES) or name == "fault_injected":

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import math
 import sys
 from typing import Any, Dict, List, Optional
 
@@ -104,7 +105,9 @@ def render_report(records: List[Dict[str, Any]], top_k: int = 8) -> str:
                          f"{first[0].get('dur', 0.0) * 1e3:.1f} ms")
         if steady:
             durs = sorted(float(s.get("dur", 0.0)) for s in steady)
-            mean = sum(durs) / len(durs)
+            # fsum: exact whatever the interpreter's sum() does (3.12
+            # made it compensated, which moved a golden mean by an ulp)
+            mean = math.fsum(durs) / len(durs)
             lines.append(
                 f"- steady-state over {len(durs)} steps: "
                 f"mean {mean * 1e3:.1f} ms · "
@@ -263,54 +266,6 @@ def render_report(records: List[Dict[str, Any]], top_k: int = 8) -> str:
             lines.append(f"| {e.get('attrs', {}).get('phase', '?')} | "
                          f"{float(e.get('ts', 0.0)):.2f} |")
         lines.append("")
-
-    # ---- measurement (chipwatch chip-session layer) -------------------
-    probes = events.get("chip_probe", [])
-    progress = events.get("measurement_progress", [])
-    windows = events.get("chip_window", [])
-    if probes or progress or windows:
-        lines.append("## Measurement")
-        lines.append("")
-        if probes:
-            ok = sum(1 for e in probes
-                     if e.get("attrs", {}).get("ok"))
-            lines.append(f"- chip probes: {len(probes)} "
-                         f"({ok} ok, {len(probes) - ok} failed)")
-            lines.append("")
-            lines.append("| ts s | attempt | ok | latency s | detail |")
-            lines.append("|---|---|---|---|---|")
-            for e in probes[-12:]:
-                a = e.get("attrs", {})
-                lines.append(
-                    "| {:.2f} | {} | {} | {} | {} |".format(
-                        float(e.get("ts", 0.0)), a.get("attempt", "?"),
-                        "yes" if a.get("ok") else "no",
-                        a.get("latency_s", "?"),
-                        a.get("device_kind") or a.get("detail") or ""))
-            lines.append("")
-        if progress:
-            a0 = progress[0].get("attrs", {})
-            a1 = progress[-1].get("attrs", {})
-            start = a0.get("entries", 0) - a0.get("new_entries", 0)
-            lines.append(
-                f"- measured-cache growth: {start} -> "
-                f"{a1.get('entries', '?')} entries "
-                f"(+{a1.get('new_entries', '?')}) over "
-                f"{a1.get('elapsed_s', '?')}s in "
-                f"{len(progress)} increments")
-            lines.append("")
-        for e in windows:
-            a = e.get("attrs", {})
-            verdict = "converted" if a.get("converted") else "NOT converted"
-            detail = f" — {a['detail']}" if a.get("detail") else ""
-            lines.append(
-                f"- window {verdict}: {a.get('entries_before', '?')} -> "
-                f"{a.get('entries_after', '?')} entries in "
-                f"{a.get('duration_s', '?')}s (measure rc "
-                f"{a.get('measure_rc')}, refit rc "
-                f"{a.get('refit_rc')}){detail}")
-        if windows:
-            lines.append("")
 
     # ---- search progress ----------------------------------------------
     prog = events.get("search_progress", [])

@@ -1,50 +1,80 @@
 """ctypes bindings for the native (C++) runtime components under native/.
 
-Loads lazily; every native path has a pure-Python fallback, so missing
-.so files degrade gracefully (and `make -C native` builds them).
+Loads lazily.  A library that is missing, or whose source changed since
+it was built, is (re)built with ``make -C native``; one that cannot be
+built leaves its callers on their Python implementation — with a
+warning, because for the search that is a different engine
+(simulator/native_search.py vs simulator/search.py).  ``status()`` says
+how each library was obtained.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
-from typing import Optional
+import warnings
+from typing import Dict, Optional
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "native")
 
-_libs = {}
+LIBRARIES = ("libffsim.so", "libffdata.so", "libffsearch.so")
+
+_libs: Dict[str, Optional[ctypes.CDLL]] = {}
+_status: Dict[str, str] = {}
+
+
+def _source_digest(path: str) -> str:
+    src = os.path.join(os.path.dirname(path),
+                       os.path.basename(path)[3:-3] + ".cpp")
+    with open(src, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
 
 
 def _stale(path: str) -> bool:
-    """A prebuilt .so older than its source must NOT be loaded: the C
+    """A prebuilt .so whose source has changed must NOT be loaded: the C
     ABI may have changed and a mismatched call corrupts arguments
-    silently (no crash — just wrong numbers)."""
-    src = path[:-3].replace("lib", "", 1) + ".cpp"
-    src = os.path.join(os.path.dirname(path), os.path.basename(src))
+    silently (no crash — just wrong numbers).  Judged by the digest of
+    the source recorded beside the library at build time, not by mtimes,
+    which a copy of the tree may flatten."""
     try:
-        return os.path.getmtime(src) > os.path.getmtime(path)
+        with open(path + ".src") as f:
+            return f.read().strip() != _source_digest(path)
     except OSError:
-        return False
+        return True
 
 
 def _load(name: str) -> Optional[ctypes.CDLL]:
     if name in _libs:
         return _libs[name]
     path = os.path.join(_NATIVE_DIR, name)
-    if not os.path.exists(path) or _stale(path):
-        try:  # (re)build if the toolchain is present
+    how = "prebuilt"
+    try:
+        if not os.path.exists(path) or _stale(path):
             subprocess.run(["make", "-C", _NATIVE_DIR, "-B", name],
                            check=True, capture_output=True, timeout=120)
-        except Exception:
-            _libs[name] = None
-            return None
-    try:
-        _libs[name] = ctypes.CDLL(path)
-    except OSError:
-        _libs[name] = None
-    return _libs[name]
+            with open(path + ".src", "w") as f:
+                f.write(_source_digest(path))
+            how = "built in this run"
+        lib = ctypes.CDLL(path)
+    except (OSError, subprocess.SubprocessError) as e:
+        lib, how = None, f"unavailable: {type(e).__name__}: {e}"
+        warnings.warn(f"native/{name} {how}; its callers use their Python "
+                      f"implementation")
+    _libs[name], _status[name] = lib, how
+    return lib
+
+
+def status(load_all: bool = False) -> Dict[str, str]:
+    """How each native library this process asked for was obtained:
+    "prebuilt", "built in this run" or "unavailable: <why>".
+    ``load_all`` asks for every library first."""
+    if load_all:
+        for name in LIBRARIES:
+            _load(name)
+    return dict(_status)
 
 
 def sim_lib() -> Optional[ctypes.CDLL]:

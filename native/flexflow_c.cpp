@@ -26,8 +26,8 @@ bool ensure_init() {
   if (!Py_IsInitialized()) {
     Py_InitializeEx(0);
   }
-  // FLEXFLOW_TPU_PLATFORM=cpu|tpu|... wins over any site-level backend
-  // selection (some environments force a platform from sitecustomize).
+  // FLEXFLOW_TPU_PLATFORM=cpu|tpu|... selects the JAX platform for an
+  // embedding host program that cannot set JAX_PLATFORMS itself.
   const char* plat = getenv("FLEXFLOW_TPU_PLATFORM");
   if (plat && *plat) {
     std::string code = "import jax\njax.config.update('jax_platforms', '";

@@ -30,7 +30,7 @@ def test_fused_sgd_matches_jnp(shape, momentum, nesterov):
     lr, wd = 0.05, 1e-4
 
     w2, v2 = fused_sgd_update(jnp.asarray(w), jnp.asarray(g), jnp.asarray(v),
-                              lr, wd, momentum, nesterov)
+                              lr, wd, momentum, nesterov, interpret=True)
     # jnp reference (optimizers.py formulas)
     gt = g + wd * w
     if momentum > 0.0:
@@ -56,7 +56,8 @@ def test_fused_adam_matches_jnp(shape):
 
     w2, m2, v2 = fused_adam_update(jnp.asarray(w), jnp.asarray(g),
                                    jnp.asarray(m), jnp.asarray(v),
-                                   alpha_t, wd, b1, b2, eps)
+                                   alpha_t, wd, b1, b2, eps,
+                                   interpret=True)
     gt = g + wd * w
     mr = b1 * m + (1 - b1) * gt
     vr = b2 * v + (1 - b2) * gt * gt

@@ -215,12 +215,14 @@ def test_train_iteration_emits_step_records(devices, tmp_path, monkeypatch):
     for s in steps:
         assert s["dur"] > 0
         assert s["attrs"]["samples_per_sec"] > 0
-        assert s["attrs"]["mfu"] >= 0
+        # a utilization is a share of a chip's peak: absent on the CPU
+        assert "mfu" not in s["attrs"]
     assert len(by_name["data_wait"]) == 3
     assert by_name["metric_drain"]
     gauges = {r["name"] for r in recs if r["t"] == "gauge"}
-    assert {"samples_per_sec", "mfu", "first_step_wall_s",
+    assert {"samples_per_sec", "first_step_wall_s",
             "est_collective_bytes_per_step"} <= gauges
+    assert "mfu" not in gauges
     counters = [r for r in recs if r["t"] == "counter"
                 and r["name"] == "samples"]
     assert counters[-1]["total"] == 3 * m.config.batch_size

@@ -156,9 +156,13 @@ def test_cadence_emits_events_and_corpus(devices, tmp_path, monkeypatch):
         last[(r["attrs"]["op"], r["attrs"]["which"])] = \
             r["attrs"]["measured_ms"]
     sum_ms = sum(last.values())
-    steps = sorted(r["dur"] for r in recs if r["t"] == "span"
-                   and r["name"] == "step" and not r["attrs"].get("first"))
-    step_ms = steps[len(steps) // 2] * 1e3
+    # the FASTEST later step: step 1 re-compiles once more (its inputs
+    # carry shardings step 0's lacked) and any step may pay a cache
+    # load, so a median of four can land on a compile; the minimum is a
+    # steady step whatever the compile cache held
+    step_ms = min(r["dur"] for r in recs if r["t"] == "span"
+                  and r["name"] == "step"
+                  and not r["attrs"].get("first")) * 1e3
     assert step_ms > 0 and sum_ms > 0
     assert step_ms / 100.0 < sum_ms < step_ms * 100.0
 

@@ -88,7 +88,8 @@ def test_ring_with_flash_kernel_interpret(devices):
     q, k, v = _qkv(5)
     for causal in (False, True):
         out = sequence_parallel_attention(q, k, v, mesh, "sp", batch_axes="dp",
-                                          causal=causal, use_flash=True)
+                                          causal=causal, use_flash=True,
+                                          interpret=True)
         ref = mha_reference(q, k, v, causal=causal)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
 

@@ -9,6 +9,7 @@ sidecar: round-trip, hash staleness, corrupt-sidecar tolerance, and the
 """
 
 import json
+import os
 import shutil
 import subprocess
 
@@ -149,7 +150,10 @@ def test_write_provenance_rebinds_hash(tmp_path):
     assert h1 != h2  # the sidecar follows the bytes it describes
 
 
-@pytest.mark.skipif(shutil.which("protoc") is None, reason="protoc not available")
+@pytest.mark.skipif(shutil.which("protoc") is None
+                    or not os.path.exists(REF_PROTO),
+                    reason="needs protoc and the reference checkout's "
+                           "strategy.proto, which is outside this repo")
 def test_wire_compatible_with_reference_proto(tmp_path):
     path = str(tmp_path / "strategy.pb")
     save_strategies_to_file(path, sample_strategies())

@@ -124,7 +124,6 @@ def test_export_synthetic_track_layout(tmp_path):
     log.span_at("step", 0.0, 0.5, step=0, trace_id="run" * 8)
     log.span_at("mcmc_search", 0.0, 0.2, budget=10)
     log.event("compile_done", op="all")
-    log.event("chip_probe", ok=True)
     log.gauge("serve_batch_occupancy", 1.5, replica="replica-0")
     log.gauge("mfu", 0.3)
     log.close()
@@ -135,8 +134,6 @@ def test_export_synthetic_track_layout(tmp_path):
     assert ("search", "search") in tracks
     assert [e["name"] for e in tracks[("compile", "compile")]] \
         == ["compile_done"]
-    assert [e["name"] for e in tracks[("chips", "chips")]] \
-        == ["chip_probe"]
     counters = [e for e in doc["traceEvents"] if e["ph"] == "C"]
     assert {e["name"] for e in counters} \
         == {"occupancy replica-0", "mfu"}

@@ -94,7 +94,7 @@ def test_doctor_cli(devices):
     """The install doctor passes on a healthy CPU environment."""
     from flexflow_tpu.tools.doctor import main
 
-    assert main(["--skip-accelerator"]) == 0
+    assert main([]) == 0
 
 
 def test_calibrate_host_transfer_measure_and_fit(tmp_path, devices):
