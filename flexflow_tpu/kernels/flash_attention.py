@@ -142,7 +142,7 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
     vr = v.reshape(bh, sk, d)
 
     grid = (bh, sq // bq, sk // bk)
-    out, lse = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk),
         grid=grid,
@@ -165,7 +165,10 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((bq, _LANES), jnp.float32),
         ],
         interpret=interpret,
-    )(qr, kr, vr)
+        name="flash_fwd",
+    )
+    with jax.named_scope("ff.kernel.flash_fwd"):
+        out, lse = call(qr, kr, vr)
     return (out.reshape(b, h, sq, d), lse[:, :, 0].reshape(b, h, sq))
 
 
@@ -290,7 +293,7 @@ def _flash_backward(scale, causal, block_q, block_k, interpret, res, grads):
         pl.BlockSpec((1, bq, _LANES), lambda bh_, a, qi: (bh_, qi, 0)),  # lse
         pl.BlockSpec((1, bq, _LANES), lambda bh_, a, qi: (bh_, qi, 0)),  # delta
     ]
-    dk, dv = pl.pallas_call(
+    dkv_call = pl.pallas_call(
         functools.partial(_bwd_dkdv_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk),
         grid=(bh, sk // bk, sq // bq),
@@ -308,9 +311,12 @@ def _flash_backward(scale, causal, block_q, block_k, interpret, res, grads):
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
-    )(qr, kr, vr, dor, lser, deltar)
+        name="flash_dkv",
+    )
+    with jax.named_scope("ff.kernel.flash_dkv"):
+        dk, dv = dkv_call(qr, kr, vr, dor, lser, deltar)
 
-    dq = pl.pallas_call(
+    dq_call = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk),
         grid=(bh, sq // bq, sk // bk),
@@ -326,7 +332,10 @@ def _flash_backward(scale, causal, block_q, block_k, interpret, res, grads):
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
-    )(qr, kr, vr, dor, lser, deltar)
+        name="flash_dq",
+    )
+    with jax.named_scope("ff.kernel.flash_dq"):
+        dq = dq_call(qr, kr, vr, dor, lser, deltar)
 
     return (dq.reshape(b, h, sq, d),
             dk.reshape(b, h, sk, d),

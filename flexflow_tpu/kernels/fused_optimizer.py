@@ -103,6 +103,7 @@ def fused_sgd_update(w, g, m, lr, wd=0.0, momentum=0.0, nesterov=False,
         ],
         input_output_aliases={1: 0, 3: 1},
         interpret=interpret,
+        name="fused_sgd",
     )(hp, wt, gt, mt)
     return (_from_tiles(w2, n, w.shape, w.dtype),
             _from_tiles(m2, n, m.shape, m.dtype))
@@ -158,6 +159,7 @@ def fused_adam_update(w, g, m, v, alpha_t, wd=0.0, beta1=0.9, beta2=0.999,
         ],
         input_output_aliases={1: 0, 3: 1, 4: 2},
         interpret=interpret,
+        name="fused_adam",
     )(hp, wt, gt, mt, vt)
     return (_from_tiles(w2, n, w.shape, w.dtype),
             _from_tiles(m2, n, m.shape, m.dtype),

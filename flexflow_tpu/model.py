@@ -53,6 +53,8 @@ from .ops.misc import (BatchNorm, Concat, Dropout, ElementBinary, ElementUnary,
                        Flat, MSELoss, Softmax)
 from .parallel.mesh import Machine
 from .parallel.strategy import load_strategies_from_file, save_strategies_to_file
+from .runtime.profiling import span as _ff_span
+from .runtime.profiling import step_enqueue as _ff_step_enqueue
 from .tensor import DataType, Parameter, Tensor
 
 
@@ -66,6 +68,12 @@ class LayerHandle:
 
     def init_inout(self, ffmodel: "FFModel", input_tensor: Tensor) -> Tensor:
         return self._build(ffmodel, input_tensor)
+
+
+def _op_scope(op: Op) -> str:
+    """The `jax.named_scope` of a graph op in the compiled step:
+    `ff.op.<op type>.<op name>`."""
+    return f"ff.op.{op._type.lower()}.{op.name}"
 
 
 def _copy_params_tree(tree):
@@ -691,7 +699,8 @@ class FFModel:
                 env[t.guid] = jnp.full(t.dims, val, fill_dtype)
             for op in stage_ops:
                 xs = [env[t.guid] for t in op.inputs]
-                ys = op.forward(resolve(params, op), xs, mctx)
+                with jax.named_scope(_op_scope(op)):
+                    ys = op.forward(resolve(params, op), xs, mctx)
                 for t, y in zip(op.outputs, ys):
                     env[t.guid] = y
             return self._bundle_pack(env, out_layout, pdtype)
@@ -815,20 +824,17 @@ class FFModel:
         self._nonfinite_guard = (
             _ff_resilience.NonFiniteGuard(self, _nf, self._telemetry)
             if _nf else None)
+        with _ff_span(self._telemetry, "compile",
+                      **self._compile_span_attrs()) as at:
+            self._compile_impl(optimizer, loss_type, metrics, machine)
+            at["num_devices"] = self.machine.num_devices
+            at["batch_size"] = self.config.batch_size
         if self._telemetry is None:
             self._stepstats = None
             self._health = None
             self._opprof = None
             self._memplane = None
-            return self._compile_impl(optimizer, loss_type, metrics, machine)
-        from .observability.reqtrace import run_trace_id as _ff_run_trace
-
-        with self._telemetry.span(
-                "compile", num_ops=len(self.ops),
-                trace_id=_ff_run_trace(self._telemetry.run_id)) as at:
-            self._compile_impl(optimizer, loss_type, metrics, machine)
-            at["num_devices"] = self.machine.num_devices
-            at["batch_size"] = self.config.batch_size
+            return
         from .observability.stepstats import StepStats
 
         self._stepstats = StepStats(self, self._telemetry)
@@ -853,6 +859,15 @@ class FFModel:
         self._memplane = _ff_memplane.maybe_plane(self._telemetry)
         _ff_memplane.emit_memory_prediction(self, self._telemetry)
         self._telemetry.flush()
+
+    def _compile_span_attrs(self) -> Dict[str, Any]:
+        """What the log's compile and recompile spans start with."""
+        if self._telemetry is None:
+            return {}
+        from .observability.reqtrace import run_trace_id
+
+        return dict(num_ops=len(self.ops),
+                    trace_id=run_trace_id(self._telemetry.run_id))
 
     def _compile_impl(self, optimizer=None,
                       loss_type: str = LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
@@ -1082,21 +1097,14 @@ class FFModel:
         if strategies is not None:
             cfg.strategies.update(strategies)
         tel = self._telemetry
-        if tel is not None:
-            from .observability.reqtrace import run_trace_id as _ff_run_trace
-
-            span = tel.span("recompile", num_ops=len(self.ops),
-                            trace_id=_ff_run_trace(tel.run_id))
-        else:
-            span = contextlib.nullcontext({})
         try:
-            with span as at:
+            with _ff_span(tel, "recompile",
+                          **self._compile_span_attrs()) as at:
                 self._compile_impl(
                     self.optimizer, self.loss.loss_type,
                     list(self.metrics.metrics),
                     machine=machine if machine is not None else self.machine)
-                if at is not None:
-                    at["num_devices"] = self.machine.num_devices
+                at["num_devices"] = self.machine.num_devices
         finally:
             (cfg.search_budget, cfg.import_strategy_file,
              cfg.export_strategy_file) = saved
@@ -1825,7 +1833,8 @@ class FFModel:
             if jnp.issubdtype(x.dtype, jnp.floating) and x.dtype != cdtype:
                 # Activations run in compute_dtype (bfloat16 on the MXU for
                 # benchmarks); params stay float32 and ops cast per-use.
-                x = x.astype(cdtype)
+                with jax.named_scope("ff.input_cast"):
+                    x = x.astype(cdtype)
             if multi:
                 deg = self._input_batch_degree(t)
                 if deg > 1:
@@ -1852,8 +1861,9 @@ class FFModel:
                 for hop in plan["head"]:
                     if hop.output.guid not in env:
                         hxs = [env[t.guid] for t in hop.inputs]
-                        hys = hop.forward(params.get(hop.param_key, {}),
-                                          hxs, ctx)
+                        with jax.named_scope(_op_scope(hop)):
+                            hys = hop.forward(
+                                params.get(hop.param_key, {}), hxs, ctx)
                         if multi:
                             if self._lowering is not None:
                                 hys = [self._lowering.constraint(y, hop)
@@ -1876,17 +1886,22 @@ class FFModel:
                 continue
             xs = [env[t.guid] for t in op.inputs]
             pvals = params.get(op.param_key, {})
-            if training and self.config.remat and op.weights \
-                    and not op.init_stats():
-                # Rematerialization: drop this op's internal activations
-                # from the residual set and recompute them in backward —
-                # FLOPs for HBM, the standard TPU memory lever.  Stateful
-                # ops (running stats) stay un-remat'ed.
-                ys = jax.checkpoint(
-                    lambda p_, xs_, op_=op: op_.forward(p_, list(xs_), ctx)
-                )(pvals, tuple(xs))
-            else:
-                ys = op.forward(pvals, xs, ctx)
+            # One scope an op names both phases: under value_and_grad its
+            # forward instructions carry jvp(ff.op...) and its backward
+            # ones transpose(jvp(ff.op...)) (runtime/profiling.step_scopes).
+            with jax.named_scope(_op_scope(op)):
+                if training and self.config.remat and op.weights \
+                        and not op.init_stats():
+                    # Rematerialization: drop this op's internal
+                    # activations from the residual set and recompute them
+                    # in backward — FLOPs for HBM, the standard TPU memory
+                    # lever.  Stateful ops (running stats) stay un-remat'ed.
+                    ys = jax.checkpoint(
+                        lambda p_, xs_, op_=op: op_.forward(p_, list(xs_),
+                                                            ctx)
+                    )(pvals, tuple(xs))
+                else:
+                    ys = op.forward(pvals, xs, ctx)
             if multi:
                 if self._lowering is not None:
                     # Whole-graph lowering: constraints come from the
@@ -2007,25 +2022,37 @@ class FFModel:
             return (sel(params, new_params), sel(stats, new_stats),
                     sel(opt_state, new_opt), out)
 
+        def finish(params, stats, opt_state, hparams, grads, new_stats, mvec,
+                   macc):
+            with jax.named_scope("ff.optimizer"):
+                new_params, new_opt = opt.apply(params, grads, opt_state,
+                                                hparams)
+            if guard_on:
+                with jax.named_scope("ff.guard"):
+                    return guard_finalize(params, stats, opt_state,
+                                          new_params, new_stats, new_opt,
+                                          mvec, macc)
+            with jax.named_scope("ff.metrics"):
+                return new_params, new_stats, new_opt, macc + mvec
+
         def step(params, stats, opt_state, hparams, batch, step_idx, macc):
             rng = jax.random.fold_in(base_key, step_idx)
             labels = batch["label"]
 
             def loss_fn(p):
                 env, new_stats = self._run_graph(p, stats, batch, True, rng)
-                loss = loss_fn_obj(env[loss_t.guid], labels)
+                with jax.named_scope("ff.loss"):
+                    loss = loss_fn_obj(env[loss_t.guid], labels)
                 return loss, (env[probs_t.guid], new_stats)
 
             (loss, (probs, new_stats)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
-            mvec = micro_metrics(loss, probs, labels)
-            if track_health:
-                mvec = mvec + health_metrics(loss, grads)
-            new_params, new_opt = opt.apply(params, grads, opt_state, hparams)
-            if guard_on:
-                return guard_finalize(params, stats, opt_state, new_params,
-                                      new_stats, new_opt, mvec, macc)
-            return new_params, new_stats, new_opt, macc + mvec
+            with jax.named_scope("ff.metrics"):
+                mvec = micro_metrics(loss, probs, labels)
+                if track_health:
+                    mvec = mvec + health_metrics(loss, grads)
+            return finish(params, stats, opt_state, hparams, grads,
+                          new_stats, mvec, macc)
 
         def step_accum(params, stats, opt_state, hparams, batch, step_idx,
                        macc):
@@ -2048,14 +2075,16 @@ class FFModel:
                 def loss_fn(p):
                     env, new_stats = self._run_graph(
                         p, stats_c, mb, True, jax.random.fold_in(rng, idx))
-                    loss = loss_fn_obj(env[loss_t.guid], mlabels)
+                    with jax.named_scope("ff.loss"):
+                        loss = loss_fn_obj(env[loss_t.guid], mlabels)
                     return loss, (env[probs_t.guid], new_stats)
 
                 (loss, (probs, new_stats)), g = jax.value_and_grad(
                     loss_fn, has_aux=True)(params)
                 g_acc = jax.tree.map(lambda a, b: a + b / accum, g_acc, g)
-                return (g_acc, mv_acc + micro_metrics(loss, probs, mlabels),
-                        new_stats), None
+                with jax.named_scope("ff.metrics"):
+                    mv_acc = mv_acc + micro_metrics(loss, probs, mlabels)
+                return (g_acc, mv_acc, new_stats), None
 
             (grads, mvec, new_stats), _ = jax.lax.scan(
                 body, (g0, m0, stats), jnp.arange(accum))
@@ -2065,17 +2094,15 @@ class FFModel:
             for name in ("loss", "steps"):
                 if name in mkeys:
                     fix = fix.at[mkeys.index(name)].set(1.0 / accum)
-            mvec = mvec * fix
-            if track_health:
-                # accumulated grads; the mean micro loss rides mvec and
-                # is NaN iff any micro's loss was
-                mvec = mvec + health_metrics(
-                    mvec[mkeys.index("loss")], grads)
-            new_params, new_opt = opt.apply(params, grads, opt_state, hparams)
-            if guard_on:
-                return guard_finalize(params, stats, opt_state, new_params,
-                                      new_stats, new_opt, mvec, macc)
-            return new_params, new_stats, new_opt, macc + mvec
+            with jax.named_scope("ff.metrics"):
+                mvec = mvec * fix
+                if track_health:
+                    # accumulated grads; the mean micro loss rides mvec and
+                    # is NaN iff any micro's loss was
+                    mvec = mvec + health_metrics(
+                        mvec[mkeys.index("loss")], grads)
+            return finish(params, stats, opt_state, hparams, grads,
+                          new_stats, mvec, macc)
 
         step_fn = step if accum == 1 else step_accum
         if self._lowering is not None:
@@ -2097,8 +2124,10 @@ class FFModel:
         def estep(params, stats, batch):
             env, _ = self._run_graph(params, stats, batch, False, None)
             labels = batch["label"]
-            loss = loss_fn_obj(env[loss_t.guid], labels)
-            msum = metrics.compute(env[probs_t.guid], labels)
+            with jax.named_scope("ff.loss"):
+                loss = loss_fn_obj(env[loss_t.guid], labels)
+            with jax.named_scope("ff.metrics"):
+                msum = metrics.compute(env[probs_t.guid], labels)
             msum["loss"] = loss
             return msum, env[probs_t.guid]
 
@@ -2177,11 +2206,12 @@ class FFModel:
         # fault never re-fires.
         if self._chaos is not None:
             self._chaos.fire("step", index=self._step_count, model=self)
-        # _stepstats is non-None only under telemetry; the disabled path
-        # is a single attribute test.
-        if self._stepstats is not None:
-            return self._stepstats.timed_update(self._update_impl)
-        self._update_impl()
+        # The log's form of this span is stepstats' post-hoc "step"
+        # record; _stepstats is non-None only under telemetry.
+        with _ff_span(None, "update"):
+            if self._stepstats is not None:
+                return self._stepstats.timed_update(self._update_impl)
+            self._update_impl()
 
     @staticmethod
     @contextlib.contextmanager
@@ -2203,12 +2233,37 @@ class FFModel:
 
     def _update_impl(self) -> None:
         assert self._batch is not None, "no batch loaded: call a DataLoader first"
-        compile_ctx = contextlib.nullcontext()
-        if self._train_step_fn is None:
+        if self._train_step_fn is not None:
+            return self._run_step(contextlib.nullcontext())
+        # first update() after a (re)build: build, trace and compile
+        with _ff_span(self._telemetry, "step_build"):
+            compile_ctx = contextlib.nullcontext()
             self._train_step_fn = self._build_train_step()
             if self._fresh_jit:
                 compile_ctx = self._bypass_compile_cache()
                 self._fresh_jit = False
+            self._run_step(compile_ctx)
+
+    def _run_step(self, compile_ctx) -> None:
+        tel = self._telemetry
+        with _ff_span(tel, "update.prepare"):
+            args, he_ctxs = self._step_args()
+        # the first call traces and compiles; later calls enqueue
+        with compile_ctx, _ff_step_enqueue(tel):
+            new_params, self._stats, new_opt, self._metric_acc = \
+                self._train_step_fn(*args)
+        with _ff_span(tel, "update.finish"):
+            if he_ctxs:
+                new_params, new_opt = self._host_embed_scatter_back(
+                    new_params, new_opt, he_ctxs)
+            self._params = self._offload_put(new_params, True)
+            self._opt_state = self._offload_put_state(new_opt, True)
+        self._step_count += 1
+        self._staged = False
+
+    def _step_args(self):
+        """The arguments of this step's call of the jitted step, and the
+        host-embedding contexts to scatter back after it."""
         if self._opt_state is None:
             self._opt_state = self._init_opt_state()
         if self._metric_acc is None:
@@ -2230,18 +2285,8 @@ class FFModel:
         if self._host_embed:
             params_in, opt_in, batch_in, he_ctxs = \
                 self._host_embed_swap_in(params_in, opt_in, self._batch)
-        with compile_ctx:  # first call traces+compiles; later calls no-op
-            new_params, self._stats, new_opt, self._metric_acc = \
-                self._train_step_fn(params_in, self._stats, opt_in,
-                                    hp, batch_in, jnp.uint32(self._step_count),
-                                    self._metric_acc)
-        if he_ctxs:
-            new_params, new_opt = self._host_embed_scatter_back(
-                new_params, new_opt, he_ctxs)
-        self._params = self._offload_put(new_params, True)
-        self._opt_state = self._offload_put_state(new_opt, True)
-        self._step_count += 1
-        self._staged = False
+        return (params_in, self._stats, opt_in, hp, batch_in,
+                jnp.uint32(self._step_count), self._metric_acc), he_ctxs
 
     def train_iteration(self) -> None:
         """Convenience: forward+backward+update in one fused call."""
@@ -2804,45 +2849,49 @@ class FFModel:
 
     def _drain_metrics(self) -> None:
         if self._metric_acc is not None:
-            if self._telemetry is not None:
-                with self._telemetry.span("metric_drain"):
-                    vec = jax.device_get(self._metric_acc)
-            else:
-                vec = jax.device_get(self._metric_acc)  # single small transfer
-            totals = dict(zip(self._metric_keys(), [float(v) for v in vec]))
-            steps = totals.pop("steps", 0.0)
-            loss_sum = totals.pop("loss", None)
-            if steps > 0 and loss_sum is not None:
-                self.last_loss = loss_sum / steps  # mean loss since last drain
-            guard = self._nonfinite_guard
-            guard_vals = None
-            if guard is not None:
-                guard_vals = {k: totals.pop(k, 0.0) for k in guard.METRIC_KEYS}
-            if self._health is not None:
-                from .observability.health import HEALTH_METRIC_KEYS
-                health_vals = {k: totals.pop(k) for k in
-                               HEALTH_METRIC_KEYS if k in totals}
-                self._health.on_drain(health_vals, steps, self._step_count)
-            elif guard is not None:
-                # Health entries rode the vector only for the guard's
-                # skip decision; pop so they don't leak into PerfMetrics.
-                from .observability.health import HEALTH_METRIC_KEYS
-                for k in HEALTH_METRIC_KEYS:
-                    totals.pop(k, None)
-            self.current_metrics.update(totals)
-            self._metric_acc = jnp.zeros_like(self._metric_acc)
-            if guard_vals is not None:
-                consec = guard_vals.get("consec_skipped", 0.0)
-                if consec > 0:
-                    # consec_skipped is a run length, not a window sum:
-                    # carry it through the accumulator reset so a NaN
-                    # streak spanning drains still escalates.
-                    ci = self._metric_keys().index("consec_skipped")
-                    self._metric_acc = self._metric_acc.at[ci].set(consec)
-                # Last: on_drain may raise NonFiniteEscalationError and
-                # the window's totals are already folded in above.
-                guard.on_drain(guard_vals.get("skipped_steps", 0.0),
-                               consec, steps, self._step_count)
+            with _ff_span(self._telemetry, "metric_drain"):
+                self._fold_metrics()
+
+    def _fold_metrics(self) -> None:
+        """The drain's work: one small transfer and the fold of its
+        totals into the host-side metrics."""
+        vec = jax.device_get(self._metric_acc)  # single small transfer
+        if self._stepstats is not None:
+            self._stepstats.on_drain()  # a sync point: the rate's interval
+        totals = dict(zip(self._metric_keys(), [float(v) for v in vec]))
+        steps = totals.pop("steps", 0.0)
+        loss_sum = totals.pop("loss", None)
+        if steps > 0 and loss_sum is not None:
+            self.last_loss = loss_sum / steps  # mean loss since last drain
+        guard = self._nonfinite_guard
+        guard_vals = None
+        if guard is not None:
+            guard_vals = {k: totals.pop(k, 0.0) for k in guard.METRIC_KEYS}
+        if self._health is not None:
+            from .observability.health import HEALTH_METRIC_KEYS
+            health_vals = {k: totals.pop(k) for k in
+                           HEALTH_METRIC_KEYS if k in totals}
+            self._health.on_drain(health_vals, steps, self._step_count)
+        elif guard is not None:
+            # Health entries rode the vector only for the guard's
+            # skip decision; pop so they don't leak into PerfMetrics.
+            from .observability.health import HEALTH_METRIC_KEYS
+            for k in HEALTH_METRIC_KEYS:
+                totals.pop(k, None)
+        self.current_metrics.update(totals)
+        self._metric_acc = jnp.zeros_like(self._metric_acc)
+        if guard_vals is not None:
+            consec = guard_vals.get("consec_skipped", 0.0)
+            if consec > 0:
+                # consec_skipped is a run length, not a window sum:
+                # carry it through the accumulator reset so a NaN
+                # streak spanning drains still escalates.
+                ci = self._metric_keys().index("consec_skipped")
+                self._metric_acc = self._metric_acc.at[ci].set(consec)
+            # Last: on_drain may raise NonFiniteEscalationError and
+            # the window's totals are already folded in above.
+            guard.on_drain(guard_vals.get("skipped_steps", 0.0),
+                           consec, steps, self._step_count)
 
     def get_metrics(self) -> PerfMetrics:
         self._drain_metrics()
@@ -2857,9 +2906,10 @@ class FFModel:
         output of the last step is ready when this returns."""
         if self._chaos is not None:
             self._chaos.fire("sync", model=self)
-        self._he_join()
-        jax.block_until_ready((self._params, self._stats, self._opt_state,
-                               self._metric_acc))
+        with _ff_span(self._telemetry, "sync"):
+            self._he_join()
+            jax.block_until_ready((self._params, self._stats,
+                                   self._opt_state, self._metric_acc))
 
     # ------------------------------------------------------------------
     # weight access (reference: Parameter::set_weights/get_weights,

@@ -12,14 +12,36 @@ watchdog killed: records are line-buffered to disk as they happen).
 
 One flag lights up the whole stack: ``FF_TELEMETRY=1`` in the
 environment or ``FFConfig.telemetry = True``.  Disabled (the default),
-the hot path performs ZERO event-log calls — every site guards on a
-``None`` handle resolved once at ``compile()``.
+the hot path performs ZERO event-log calls — every site opens its span
+through ``runtime/profiling.span``, given the handle resolved once at
+``compile()``, and with no handle that opens a profiler annotation only.
+
+The step's own timeline lives beside this package, in
+``runtime/profiling.py`` (docs/observability.md has the tables):
+
+  * host spans ``ff.compile``, ``ff.update`` (with ``ff.step_build``,
+    ``ff.update.prepare`` / ``.enqueue`` / ``.finish`` inside),
+    ``ff.sync``, ``ff.metric_drain``, ``ff.data_wait``,
+    ``ff.checkpoint_save`` / ``_restore``: ``TraceAnnotation``s on the
+    profiler's clock, and the log's spans of the same names without
+    ``ff.`` when telemetry is on (``ff.update`` is the log's ``step``),
+  * ``jax.named_scope``s in the compiled step: ``ff.op.<type>.<name>``
+    a graph op, ``ff.input_cast``, ``ff.loss``, ``ff.metrics``,
+    ``ff.optimizer``, ``ff.guard``, ``ff.kernel.flash_fwd`` / ``_dq`` /
+    ``_dkv``; ``profiling.step_scopes()`` maps every instruction of the
+    loaded step programs to its scope and phase (fwd / bwd / opt /
+    other), and ``profiling.trace(logdir)`` writes that map beside the
+    trace as ``ff_step_scopes.json``,
+  * ``profiling.counters()``: ``train_step_compiles`` /
+    ``train_step_compile_s``.
 
 ``events``    — the env/flag-gated structured event log (spans +
                 counters + gauges, thread-safe, JSONL sink).
-``stepstats`` — per-step instrumentation: wall time, first-step
-                compile time, samples/s/chip, analytic-FLOP MFU,
-                estimated collective bytes, device memory stats.
+``stepstats`` — per-step instrumentation: the enqueue's wall time,
+                first-step compile time, estimated collective bytes,
+                device memory stats; and once a DRAIN of the metrics
+                (a sync point), over the interval since the drain
+                before: samples/s, samples/s/chip, analytic-FLOP MFU.
 ``health``    — ``FF_HEALTH=1`` live monitor on top of the log:
                 non-finite loss/grad sampling, straggler detection
                 with phase attribution, data-starvation warnings, and

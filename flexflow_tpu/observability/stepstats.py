@@ -1,26 +1,31 @@
 """Per-step training instrumentation.
 
-Computes, per ``update()``:
+Records, per ``update()``:
 
-  * wall time (host-side; in steady state the dispatch blocks on the
-    previous step's donated buffers, so wall-between-updates converges
-    on true device step time — set ``FF_TELEMETRY_SYNC=1`` to force a
-    ``model.sync()`` inside each timed step for exact-but-serialized
-    numbers),
+  * the "step" span: the host's time in the call, which with
+    asynchronous dispatch is the time to ENQUEUE the step, not the
+    device's time to run it (the profiler's ``ff.update`` is the same
+    interval).  No rate is worked out of it,
   * first-step wall time separately (jit trace + XLA compile happen
     inside step 0 — the reference's epoch-0 Legion trace capture),
+  * estimated per-step collective bytes from each op's RESOLVED
+    ``ParallelConfig`` (gradient all-reduce of replicated weights over
+    the batch axis + activation redistribution for non-batch splits),
+  * device memory stats when the backend reports them (TPU HBM
+    ``bytes_in_use`` / ``peak_bytes_in_use``; CPU reports none).
+
+and, per drain of the metrics (``get_metrics()``), over the interval
+since the drain before it — a drain reads from the device, so it is a
+sync point, and steps since the last drain x batch over the time since
+it is all the work over all the time:
+
   * samples/s and samples/s/chip,
   * analytic-FLOP MFU, on a TPU only: train FLOPs estimated as 3x the
     graph's forward FLOPs (fwd + dgrad + wgrad — the same accounting
     bench.py and the reference's backward multiplier use) against the
     published peak of the chip the step ran on
     (``simulator/machine.py`` ``DEVICE_PEAKS``, keyed by
-    ``device_kind``).  On any other platform the field is absent,
-  * estimated per-step collective bytes from each op's RESOLVED
-    ``ParallelConfig`` (gradient all-reduce of replicated weights over
-    the batch axis + activation redistribution for non-batch splits),
-  * device memory stats when the backend reports them (TPU HBM
-    ``bytes_in_use`` / ``peak_bytes_in_use``; CPU reports none).
+    ``device_kind``).  On any other platform the gauge is absent.
 
 Everything here is reached ONLY through a non-None EventLog resolved at
 ``compile()`` — with telemetry off this module is never imported.
@@ -28,7 +33,6 @@ Everything here is reached ONLY through a non-None EventLog resolved at
 
 from __future__ import annotations
 
-import os
 import time
 from typing import Optional
 
@@ -124,7 +128,10 @@ class StepStats:
         # zero per-step state)
         self.trace_id = run_trace_id(log.run_id)
         self.steps = 0
-        self.sync_each_step = bool(os.environ.get("FF_TELEMETRY_SYNC"))
+        # the last drain: the log's clock when it read the device, and
+        # the steps taken by then (None until the first drain)
+        self._drain_at: Optional[float] = None
+        self._drain_steps = 0
         self._fwd_flops_per_sample: Optional[float] = None
         self._peak_flops: Optional[float] = None
         self._collective_bytes: Optional[int] = None
@@ -144,8 +151,7 @@ class StepStats:
         return self._fwd_flops_per_sample, self._peak_flops
 
     def timed_update(self, fn) -> None:
-        """Run one training step under a "step" span with throughput /
-        MFU counters."""
+        """Run one training step under a "step" span: the enqueue."""
         log = self.log
         first = self.steps == 0
         step_idx = self.model._step_count
@@ -154,28 +160,15 @@ class StepStats:
         write_heartbeat("step", step=step_idx)
         t0 = time.perf_counter()
         fn()
-        if self.sync_each_step:
-            self.model.sync()
         dur = time.perf_counter() - t0
         self.steps += 1
 
-        fwd_fps, peak = self._statics()
         bs = self.model.config.batch_size
-        nd = self.model.machine.num_devices if self.model.machine else 1
-        sps = bs / dur if dur > 0 else 0.0
-        attrs = dict(step=step_idx, first=first, trace_id=self.trace_id,
-                     batch_size=bs, samples_per_sec=round(sps, 2),
-                     samples_per_sec_per_chip=round(sps / nd, 2))
-        if peak:
-            # fwd + dgrad + wgrad ~= 3x forward (reference accounting)
-            attrs["mfu"] = round(3.0 * fwd_fps * sps / (nd * peak), 6)
-        log.span_at("step", t0, dur, **attrs)
+        log.span_at("step", t0, dur, step=step_idx, first=first,
+                    trace_id=self.trace_id, batch_size=bs)
         log.counter("samples", float(bs))
-        log.gauge("samples_per_sec", round(sps, 2))
-        log.gauge("samples_per_sec_per_chip", round(sps / nd, 2))
-        if peak:
-            log.gauge("mfu", attrs["mfu"])
         if first:
+            self._statics()
             # step 0 wall includes jit trace + XLA compile
             log.gauge("first_step_wall_s", round(dur, 6))
             log.gauge("est_collective_bytes_per_step",
@@ -201,3 +194,23 @@ class StepStats:
         opprof = getattr(self.model, "_opprof", None)
         if opprof is not None:
             opprof.on_step(step_idx)
+
+    def on_drain(self) -> None:
+        """The drain of the metrics has just read the device: gauge the
+        rate, and on a TPU the MFU, over the interval since the drain
+        before.  The first drain only starts the clock (its interval
+        holds the compilation)."""
+        now = self.log.now()
+        steps = self.steps - self._drain_steps
+        last, self._drain_at, self._drain_steps = \
+            self._drain_at, now, self.steps
+        if last is None or steps <= 0 or now <= last:
+            return
+        fwd_fps, peak = self._statics()
+        nd = self.model.machine.num_devices if self.model.machine else 1
+        sps = steps * self.model.config.batch_size / (now - last)
+        self.log.gauge("samples_per_sec", round(sps, 2))
+        self.log.gauge("samples_per_sec_per_chip", round(sps / nd, 2))
+        if peak:
+            # fwd + dgrad + wgrad ~= 3x forward (reference accounting)
+            self.log.gauge("mfu", round(3.0 * fwd_fps * sps / (nd * peak), 6))

@@ -26,6 +26,8 @@ from typing import Any, Dict, Optional
 import jax
 import numpy as np
 
+from .profiling import span
+
 
 def _unpack_tree(model, tree: Dict[str, Any]) -> Dict[str, Any]:
     """Canonicalize a params-shaped tree: expand a pipelined model's
@@ -169,12 +171,11 @@ def save_checkpoint(model, path: str, force: bool = True) -> None:
     write_heartbeat("checkpoint_save",
                     step=getattr(model, "_step_count", 0))
     tel = getattr(model, "_telemetry", None)
-    if tel is None:
-        return _save_checkpoint_impl(model, path, force)
-    with tel.span("checkpoint_save", path=path,
-                  step=getattr(model, "_step_count", 0)):
+    with span(tel, "checkpoint_save", path=path,
+              step=getattr(model, "_step_count", 0)):
         _save_checkpoint_impl(model, path, force)
-    tel.flush()
+    if tel is not None:
+        tel.flush()
 
 
 def _save_checkpoint_impl(model, path: str, force: bool = True) -> None:
@@ -211,11 +212,10 @@ def load_checkpoint(model, path: str) -> None:
 
     write_heartbeat("checkpoint_restore")
     tel = getattr(model, "_telemetry", None)
-    if tel is None:
-        return _load_checkpoint_impl(model, path)
-    with tel.span("checkpoint_restore", path=path):
+    with span(tel, "checkpoint_restore", path=path):
         _load_checkpoint_impl(model, path)
-    tel.flush()
+    if tel is not None:
+        tel.flush()
 
 
 def _load_checkpoint_impl(model, path: str) -> None:

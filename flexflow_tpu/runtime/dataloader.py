@@ -21,6 +21,7 @@ from typing import Dict, Optional, Sequence
 import numpy as np
 
 from ..tensor import DataType, Tensor
+from .profiling import span
 
 
 class DataLoader:
@@ -155,13 +156,11 @@ class DataLoader:
         from ..observability.health import write_heartbeat
 
         write_heartbeat("data_wait", step=getattr(ff, "_step_count", None))
-        tel = getattr(ff, "_telemetry", None)
-        if tel is None:
-            return self._next_batch_impl(ff)
         # "data_wait" = everything the step blocks on for input: the host
         # gather (~0 when the prefetch worker already has it) plus the
         # sharded device_put inside set_batch.
-        with tel.span("data_wait", batch_size=self.batch_size) as at:
+        with span(getattr(ff, "_telemetry", None), "data_wait",
+                  batch_size=self.batch_size) as at:
             at["prefetched"] = (
                 self._pending is not None
                 and self._pending[0] == self._start_of(self.next_index)

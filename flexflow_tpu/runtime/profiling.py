@@ -6,35 +6,266 @@ Reference instrumentation (SURVEY §5.1): per-op cudaEvent timers behind
 
   * ``trace(logdir)`` — context manager around ``jax.profiler`` traces:
     the XLA/TensorBoard profile is the ``-lg:prof`` analogue (kernel
-    timeline, HBM traffic, ICI collectives),
+    timeline, HBM traffic, ICI collectives).  It writes the scope map
+    of the loaded step programs beside the trace
+    (``ff_step_scopes.json``),
+  * ``span(log, name)`` — the program's host spans: always a
+    ``TraceAnnotation`` ``ff.<name>`` (on the profiler's clock while a
+    trace runs, an atomic load otherwise), and the ``EventLog`` span
+    ``<name>`` as well when a log is active,
+  * ``step_scopes()`` — which graph op and which phase (forward,
+    backward, optimizer) every instruction of the compiled step came
+    from, read from the optimized HLO of the loaded executables: the
+    join between a device trace and the ``jax.named_scope``s of
+    ``FFModel._build_train_step``,
+  * ``counters()`` — process-wide counters: how often the train step
+    was compiled, and for how long,
   * ``op_profile(model)`` — per-op forward/backward wall times, measured
     by compiling and timing each op standalone on the real device, the
     way the reference's ``measure_compute_time`` does per-op benchmarks;
-    printed like the reference's per-op ``--profiling`` printouts,
-  * ``annotate(name)`` — TraceAnnotation for custom regions.
+    printed like the reference's per-op ``--profiling`` printouts.
 """
 
 from __future__ import annotations
 
 import contextlib
-from typing import Dict, Optional
+import json
+import os
+import re
+from typing import Dict, List, Optional
 
 import jax
+
+SPAN_PREFIX = "ff."
+SCOPES_FILE = "ff_step_scopes.json"
 
 
 @contextlib.contextmanager
 def trace(logdir: str = "/tmp/flexflow_tpu_trace"):
-    """Capture an XLA profiler trace (view with TensorBoard)."""
+    """Capture an XLA profiler trace (view with TensorBoard), and write
+    the scope map of the step programs loaded at its end to
+    ``<logdir>/ff_step_scopes.json``, so that the trace's device
+    operations can be put down to graph ops and phases after the process
+    is gone."""
     jax.profiler.start_trace(logdir)
     try:
         yield logdir
     finally:
         jax.profiler.stop_trace()
+        with open(os.path.join(logdir, SCOPES_FILE), "w") as f:
+            json.dump(step_scopes(), f)
 
 
-def annotate(name: str):
-    """Named region in the profiler timeline."""
-    return jax.profiler.TraceAnnotation(name)
+# ----------------------------------------------------------------------
+# host spans
+# ----------------------------------------------------------------------
+@contextlib.contextmanager
+def span(log, name: str, **attrs):
+    """One of the program's host spans.  Opens the profiler annotation
+    ``ff.<name>`` always and, where ``log`` (an ``EventLog``) is not
+    None, the log's span ``<name>`` with ``attrs``.  Yields the
+    attribute dict, so that a caller can add what it learns inside."""
+    with jax.profiler.TraceAnnotation(SPAN_PREFIX + name):
+        if log is None:
+            yield attrs
+        else:
+            with log.span(name, **attrs) as at:
+                yield at
+
+
+# ----------------------------------------------------------------------
+# counters
+# ----------------------------------------------------------------------
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_counters = {"train_step_compiles": 0, "train_step_compile_s": 0.0}
+_enqueue_depth = 0
+_listening = False
+
+
+def _on_duration(event: str, secs: float, **_) -> None:
+    # fires for a compilation and for a fetch from the persistent cache
+    # alike, and only then: the steady step never reaches this
+    if _enqueue_depth and event == _BACKEND_COMPILE:
+        _counters["train_step_compiles"] += 1
+        _counters["train_step_compile_s"] += secs
+
+
+def counters() -> Dict[str, float]:
+    """Process-wide counters: ``train_step_compiles``, the XLA
+    compilations (or persistent-cache fetches) that happened inside a
+    train step's call, over every model of the process, and
+    ``train_step_compile_s``, their seconds."""
+    return dict(_counters)
+
+
+@contextlib.contextmanager
+def step_enqueue(log):
+    """The span ``update.enqueue`` around the call of the jitted train
+    step; a compilation that fires while it is open is the step's."""
+    global _enqueue_depth, _listening
+    if not _listening:
+        _listening = True
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    _enqueue_depth += 1
+    try:
+        with span(log, "update.enqueue"):
+            yield
+    finally:
+        _enqueue_depth -= 1
+
+
+# ----------------------------------------------------------------------
+# the scope map
+# ----------------------------------------------------------------------
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*->.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(ROOT\s+)?%?([\w.\-]+)\s+=\s+(.*)$")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_CALLED = re.compile(r"\b(calls|to_apply)=%?([\w.\-]+)")
+_FF_SCOPE = re.compile(r"ff\.[^/()\"]+")
+_KERNEL = SPAN_PREFIX + "kernel."
+_FWD_SCOPES = (SPAN_PREFIX + "op.", SPAN_PREFIX + "loss",
+               SPAN_PREFIX + "input_cast")
+_MATMULS = ("convolution", "dot")
+
+
+def _opcode(rest: str) -> str:
+    """The opcode of an instruction's text after `name = `: what stands
+    between the result's shape and the operands' parenthesis."""
+    if rest.startswith("("):  # a tuple shape: skip to its matching ")"
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                rest = rest[i + 1:]
+                break
+    else:
+        rest = rest.partition(" ")[2]
+    return rest.lstrip().partition("(")[0]
+
+
+def scope_of(op_name: str) -> Dict[str, Optional[str]]:
+    """Scope, phase and kernel of one instruction's ``op_name``.
+
+    ``bwd`` where the name holds ``transpose(`` (the recomputed forward
+    of a ``jax.checkpoint`` is there too, where its time is spent), else
+    ``opt`` under ``ff.optimizer``, else ``fwd`` under a graph op, the
+    loss or the input cast, else ``other``."""
+    names = _FF_SCOPE.findall(op_name)
+    kernel = next((n[len(_KERNEL):] for n in names if n.startswith(_KERNEL)),
+                  None)
+    scope = next((n for n in names if not n.startswith(_KERNEL)), None)
+    if "transpose(" in op_name:
+        phase = "bwd"
+    elif scope == SPAN_PREFIX + "optimizer":
+        phase = "opt"
+    elif scope is not None and scope.startswith(_FWD_SCOPES):
+        phase = "fwd"
+    else:
+        phase = "other"
+    return {"scope": scope, "phase": phase, "kernel": kernel}
+
+
+def parse_hlo_scopes(text: str) -> Dict[str, Dict[str, object]]:
+    """{instruction name: {"scope", "phase", "kernel", "mixed"}} for
+    every instruction of an optimized HLO module that the device runs
+    on its own: those of the entry computation and of loop bodies and
+    branches, not those inside fused computations or reducers.
+
+    A fusion is attributed by what is inside it: the scope and phase of
+    its convolution or dot where it has one (the optimizer update that
+    XLA fuses into a weight-gradient fusion does not turn the gradient's
+    time into the optimizer's), else of its root, else of the last
+    scoped instruction before the root; ``mixed`` where the scoped
+    instructions inside come from more than one phase.  An instruction
+    that carries no ``op_name`` at all (the compiler's own: the start
+    and done of an asynchronous copy or slice) takes the scope and phase
+    of the first instruction that uses it, where its wait is spent, or
+    failing that (a copy out to the program's result) of its operand."""
+    comps: Dict[str, List[tuple]] = {}
+    inner = set()  # computations that run inside another instruction
+    rows = None
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m and rows is not None:
+            rest = m.group(3)
+            called = _CALLED.findall(rest)
+            inner.update(c for _, c in called)
+            name = _OP_NAME.search(rest)
+            rows.append((m.group(2), _opcode(rest), bool(m.group(1)),
+                         name.group(1) if name else "",
+                         [c for _, c in called], _OPERAND.findall(rest)))
+            continue
+        m = _COMPUTATION.match(line)
+        if m:
+            rows = comps.setdefault(m.group(1), [])
+
+    def inside(comp, seen):
+        """(opcode, is root, op_name) of every instruction of a called
+        computation, and of those it calls in turn."""
+        for _, opcode, root, op_name, called, _ in comps.get(comp, ()):
+            yield opcode, root, op_name
+            for c in called:
+                if c not in seen:
+                    seen.add(c)
+                    yield from inside(c, seen)
+
+    out = {}
+    for comp, rows in comps.items():
+        if comp in inner:
+            continue
+        first_user: Dict[str, str] = {}
+        for name, _, _, _, _, operands in rows:
+            for operand in operands:
+                first_user.setdefault(operand, name)
+        for name, opcode, _, op_name, called, _ in reversed(rows):
+            entry = dict(scope_of(op_name), mixed=False)
+            if called:
+                body = [(oc, root, scope_of(on))
+                        for c in called for oc, root, on in inside(c, {c})]
+                scoped = [b for b in body if b[2]["scope"] is not None]
+                if scoped:
+                    pick = (next((b for b in scoped if b[0] in _MATMULS), None)
+                            or next((b for b in scoped if b[1]), None)
+                            or scoped[-1])
+                    entry = dict(pick[2], mixed=len(
+                        {b[2]["phase"] for b in scoped}) > 1)
+            elif not op_name and first_user.get(name) in out:
+                # users stand later in a scheduled computation, so in
+                # this reversed walk they are resolved already
+                entry = dict(out[first_user[name]], kernel=None, mixed=False)
+            out[name] = entry
+        for name, _, _, op_name, _, operands in rows:
+            if not op_name and out[name]["scope"] is None:
+                made = next((out[o] for o in operands
+                             if o in out and out[o]["scope"]), None)
+                if made is not None:
+                    out[name] = dict(made, kernel=None, mixed=False)
+    return out
+
+
+def step_scopes() -> Dict[str, List[Dict[str, Dict[str, object]]]]:
+    """The scope map of every step program this process has loaded:
+
+        {module name: [{instruction name: {"scope": "ff.op.conv2d.conv1",
+                                           "phase": "fwd"|"bwd"|"opt"|"other",
+                                           "kernel": "flash_fwd"|None,
+                                           "mixed": bool}}, ...]}
+
+    one entry of the list for each loaded program of that name (the
+    train step is loaded once per signature).  A step program is one
+    whose optimized HLO holds an ``ff.`` scope.  Read from the
+    executables the client holds: nothing is compiled or loaded."""
+    out: Dict[str, List[Dict[str, Dict[str, object]]]] = {}
+    for exe in jax.devices()[0].client.live_executables():
+        module = exe.hlo_modules()[0]
+        text = module.to_string()
+        if 'op_name="' not in text or SPAN_PREFIX not in text:
+            continue
+        scopes = parse_hlo_scopes(text)
+        if any(e["scope"] for e in scopes.values()):
+            out.setdefault(module.name, []).append(scopes)
+    return out
 
 
 def op_profile(model, which: str = "both") -> Dict[str, Dict[str, float]]:

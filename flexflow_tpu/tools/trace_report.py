@@ -108,21 +108,20 @@ def render_report(records: List[Dict[str, Any]], top_k: int = 8) -> str:
             # fsum: exact whatever the interpreter's sum() does (3.12
             # made it compensated, which moved a golden mean by an ulp)
             mean = math.fsum(durs) / len(durs)
+            # a step span times the enqueue of an asynchronous step;
+            # the rate and the MFU come from the per-drain gauges,
+            # each over the interval between two drains of the metrics
             lines.append(
-                f"- steady-state over {len(durs)} steps: "
+                f"- steady-state enqueue over {len(durs)} steps: "
                 f"mean {mean * 1e3:.1f} ms · "
                 f"p50 {percentile(durs, 50) * 1e3:.1f} ms · "
                 f"p95 {percentile(durs, 95) * 1e3:.1f} ms")
-            sps = [s["attrs"].get("samples_per_sec") for s in steady
-                   if s.get("attrs", {}).get("samples_per_sec") is not None]
-            if sps:
-                lines.append(f"- throughput (last steady step): "
-                             f"{sps[-1]:.1f} samples/s")
-            mfus = [s["attrs"].get("mfu") for s in steady
-                    if s.get("attrs", {}).get("mfu") is not None]
-            if mfus:
-                lines.append(f"- MFU (analytic FLOPs, last steady step): "
-                             f"{100.0 * mfus[-1]:.2f}%")
+        if gauges.get("samples_per_sec"):
+            lines.append(f"- throughput (last drain interval): "
+                         f"{gauges['samples_per_sec'][-1][0]:.1f} samples/s")
+        if gauges.get("mfu"):
+            lines.append(f"- MFU (analytic FLOPs, last drain interval): "
+                         f"{100.0 * gauges['mfu'][-1][0]:.2f}%")
         lines.append("")
 
     # ---- phase breakdown ----------------------------------------------

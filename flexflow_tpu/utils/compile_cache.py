@@ -11,6 +11,7 @@ directory, a pid or a time — and two processes of one checkout share it.
 from __future__ import annotations
 
 import os
+import re
 
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -18,11 +19,21 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 def enable_compile_cache() -> str:
     """Turn JAX's persistent compilation cache on; returns its directory."""
+    import jax
+
+    # An executable carries the metadata it was compiled with: the
+    # `ff.` scopes that runtime/profiling.step_scopes reads back.  JAX
+    # leaves metadata out of the key by default, so a program that
+    # differs from a cached one only in its scopes would be handed the
+    # cached one, with the other's names (or none): keep it in the key.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    # ... and metadata holds source files: name them from the checkout's
+    # root, so that the key does not follow the checkout's path.
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      re.escape(_CHECKOUT + os.sep))
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    import jax
-
     path = os.path.join(_CHECKOUT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path

@@ -38,6 +38,12 @@ from flexflow_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
 # code changes re-compile as needed.
 enable_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+# The helper keys the cache by metadata too, for the sake of profiles.
+# Tests build the same tiny models from many call sites, and a key that
+# holds the call site would compile each anew; the tests that read
+# metadata back (test_step_scopes.py, benchmark/test_benchmark_tracing.py)
+# turn it on for themselves.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
 
 # The cache's put() writes the entry straight to its final name
 # (LRUCache.put -> Path.write_bytes).  A test process killed mid-write —

@@ -200,6 +200,8 @@ def test_train_iteration_emits_step_records(devices, tmp_path, monkeypatch):
     assert m._telemetry is not None and m._stepstats is not None
     m.init_layers()
     _train_steps(m, inp, 3)
+    m.get_metrics()  # the first drain starts the rate's clock
+    _train_steps(m, inp, 2)
     m.get_metrics()
     events.reset_active()
 
@@ -210,22 +212,33 @@ def test_train_iteration_emits_step_records(devices, tmp_path, monkeypatch):
             by_name.setdefault(r["name"], []).append(r)
     assert len(by_name["compile"]) == 1
     steps = by_name["step"]
-    assert len(steps) == 3
+    assert len(steps) == 5
     assert steps[0]["attrs"]["first"] and not steps[1]["attrs"]["first"]
     for s in steps:
         assert s["dur"] > 0
-        assert s["attrs"]["samples_per_sec"] > 0
-        # a utilization is a share of a chip's peak: absent on the CPU
+        # the span times an enqueue: no rate and no utilization is
+        # worked out of it
+        assert "samples_per_sec" not in s["attrs"]
         assert "mfu" not in s["attrs"]
-    assert len(by_name["data_wait"]) == 3
-    assert by_name["metric_drain"]
-    gauges = {r["name"] for r in recs if r["t"] == "gauge"}
-    assert {"samples_per_sec", "first_step_wall_s",
-            "est_collective_bytes_per_step"} <= gauges
+    assert len(by_name["data_wait"]) == 5
+    assert len(by_name["metric_drain"]) == 2
+    # the step's inner spans are logged under the names the profiler has
+    for name in ("step_build", "update.prepare", "update.enqueue",
+                 "update.finish"):
+        assert by_name[name], name
+    assert len(by_name["step_build"]) == 1
+    assert len(by_name["update.enqueue"]) == 5
+    gauges = [r["name"] for r in recs if r["t"] == "gauge"]
+    assert {"samples_per_sec", "samples_per_sec_per_chip",
+            "first_step_wall_s", "est_collective_bytes_per_step"} \
+        <= set(gauges)
+    # once a drain, from the second on: one record for two drains
+    assert gauges.count("samples_per_sec") == 1
+    # a utilization is a share of a chip's peak: absent on the CPU
     assert "mfu" not in gauges
     counters = [r for r in recs if r["t"] == "counter"
                 and r["name"] == "samples"]
-    assert counters[-1]["total"] == 3 * m.config.batch_size
+    assert counters[-1]["total"] == 5 * m.config.batch_size
 
 
 def test_checkpoint_spans(devices, tmp_path, monkeypatch):

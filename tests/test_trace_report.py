@@ -26,17 +26,10 @@ def synthetic_records():
     for i, d in enumerate(durs):
         recs.append({"t": "span", "name": "step", "id": 2 + i,
                      "parent": None, "ts": round(ts, 6), "dur": d,
-                     "attrs": {"step": i, "first": i == 0, "batch_size": 64,
-                               "samples_per_sec": round(64 / d, 2),
-                               "samples_per_sec_per_chip":
-                                   round(64 / d / 8, 2),
-                               "mfu": round(0.002 / d, 6)}})
+                     "attrs": {"step": i, "first": i == 0,
+                               "batch_size": 64}})
         recs.append({"t": "counter", "name": "samples", "v": 64.0,
                      "total": 64.0 * (i + 1), "ts": round(ts + d, 6)})
-        recs.append({"t": "gauge", "name": "samples_per_sec",
-                     "v": round(64 / d, 2), "ts": round(ts + d, 6)})
-        recs.append({"t": "gauge", "name": "mfu", "v": round(0.002 / d, 6),
-                     "ts": round(ts + d, 6)})
         recs.append({"t": "span", "name": "data_wait", "id": 100 + i,
                      "parent": None, "ts": round(ts - 0.001, 6),
                      "dur": 0.001, "attrs": {"batch_size": 64,
@@ -46,8 +39,18 @@ def synthetic_records():
                  "ts": 4.0})
     recs.append({"t": "gauge", "name": "est_collective_bytes_per_step",
                  "v": 1572864.0, "ts": 4.0})
+    # two drains of the metrics: the rate and the MFU are gauged once a
+    # drain, over the interval since the drain before (here 2 steps of
+    # 64 samples in 0.04 s), whatever the enqueues above took
+    recs.append({"t": "span", "name": "metric_drain", "id": 49,
+                 "parent": None, "ts": 7.96, "dur": 0.003, "attrs": {}})
     recs.append({"t": "span", "name": "metric_drain", "id": 50,
                  "parent": None, "ts": 8.0, "dur": 0.003, "attrs": {}})
+    recs.append({"t": "gauge", "name": "samples_per_sec", "v": 3200.0,
+                 "ts": 8.001})
+    recs.append({"t": "gauge", "name": "samples_per_sec_per_chip",
+                 "v": 400.0, "ts": 8.001})
+    recs.append({"t": "gauge", "name": "mfu", "v": 0.1, "ts": 8.001})
     recs.append({"t": "span", "name": "checkpoint_save", "id": 51,
                  "parent": None, "ts": 9.0, "dur": 0.5,
                  "attrs": {"path": "/tmp/ckpt.npz", "step": 5}})
@@ -93,8 +96,25 @@ def test_report_sections(tmp_path):
         assert section in report, f"missing {section}"
     # first step reported separately; steady stats over the other 4
     assert "first step (incl. compile): 2000.0 ms" in report
-    assert "steady-state over 4 steps" in report
+    assert "steady-state enqueue over 4 steps" in report
     assert "golden-run" in report
+
+
+def test_rate_and_mfu_come_from_the_per_drain_gauges(tmp_path):
+    """The summary's throughput and MFU are the last per-drain gauges;
+    a step span's duration (the enqueue) yields neither, and attributes
+    an older log put on its step spans are not read."""
+    recs = synthetic_records()
+    report = trace_report.render_report(recs)
+    assert "throughput (last drain interval): 3200.0 samples/s" in report
+    assert "MFU (analytic FLOPs, last drain interval): 10.00%" in report
+    no_gauges = [r for r in recs if r.get("t") != "gauge"]
+    for r in no_gauges:
+        if r.get("name") == "step":
+            r["attrs"].update(samples_per_sec=6400.0, mfu=0.5)
+    report = trace_report.render_report(no_gauges)
+    assert "steady-state enqueue over 4 steps" in report
+    assert "throughput" not in report and "MFU" not in report
 
 
 def test_corrupt_tail_tolerated(tmp_path):
