@@ -9,9 +9,43 @@ backward.  The kernel also returns the per-row logsumexp, which is what
 lets ring attention (parallel/sequence.py) merge partial results across
 sequence shards.
 
-Layout: (batch, heads, seq, head_dim), f32 or bf16 in / f32 accumulate.
-Grid is (batch*heads, q_blocks, k_blocks) with the k dimension innermost
-so the accumulator lives in VMEM scratch across the k sweep.
+Layout: (batch, heads, seq, head_dim) in and out.  Three kernels, each a
+``pallas_call`` named as its scope: ``flash_fwd`` (grid batch*heads x
+q blocks x k blocks, k innermost), ``flash_dq`` (the same grid) and
+``flash_dkv`` (batch*heads x k blocks x q blocks, q innermost), so each
+accumulator lives in VMEM scratch across its inner sweep.
+
+Dtypes.  Every matmul takes its operands in the dtype the caller gave
+(bf16 operands for a bf16 model, f32 for an f32 one; ``p`` and ``ds``
+are cast to the dtype of the operand they meet) and accumulates in f32.
+Scores, running max and sum, ``exp``, ``lse``, ``delta`` and the
+accumulators are f32.  (Mosaic feeds the MXU an f32 operand in one bf16
+pass, so on the chip an f32 call's error is a bf16 call's: PERF.md
+section 6, PR 26.)
+
+Orientation.  All three kernels hold the scores transposed, keys down
+the sublanes and queries along the lanes: ``(k q^T)``, (block_k,
+block_q).  What there is one of per query (running max and sum, ``lse``,
+``delta``) is then a lane-dense (1, block_q) row: a reduction over keys
+is elementwise between vregs, a broadcast is a sublane broadcast, and no
+kernel needs a (block_q, 1) column or a cross-lane reduce.  The forward
+and ``dq`` accumulate transposed too, (d, block_q), and turn the result
+once a q block.
+
+Row statistics.  ``lse`` leaves the forward, and ``lse`` and ``delta``
+enter the backward kernels, one f32 a row in HBM, shaped (batch*heads,
+q blocks, 1, block_q) so that a kernel's block is whole in its two minor
+dimensions whatever block_q is.
+
+Causal.  A block wholly above the diagonal runs no body and its index
+maps name a block already held, so it fetches nothing; a block wholly
+under it skips the mask; a block on the diagonal of square blocks is cut
+into bands of keys that leave out the queries before them (``_tiles``).
+A scale that is a power of two goes on the query, where it is exact.
+
+Blocks are a function of (seq_q, seq_k, head_dim) alone
+(``_block_sizes``, chosen by a sweep on the v5e), the bands of the
+kernel; ``tiling()`` says what each kernel does with a shape.
 
 The kernels compile through Mosaic and run on a TPU only.  Callers pick
 the implementation by platform (ops/attention.py); ``interpret=True``
@@ -23,7 +57,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,17 +65,36 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-# Lane width of the VPU; m/l scratch rows are replicated across it.
-_LANES = 128
+KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
+# A sequence of up to this many rows can be one block whatever its
+# length; a longer one is cut into divisors that are multiples of 8.
+_WHOLE = 512
+# Keys in a band of a diagonal block (``_tiles``), a multiple of the 128
+# lanes because a band's first key is also its first query column.  The
+# fewer matmuls a kernel has, the dearer a band's fixed cost against the
+# masked work it leaves out (sweep on the v5e, PERF.md section 6, PR 26).
+_DIAGONAL_BAND = {"flash_fwd": 512, "flash_dq": 256, "flash_dkv": 128}
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b^T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+_TN = (((0,), (0,)), ((), ()))   # a^T @ b
+
+
+def _max_block(head_dim: int) -> int:
+    """Rows of the largest block.  One grid step a (batch, head) up to
+    sequence 1024 beat every finer grid in all three kernels at head 64
+    and 128 (PERF.md section 6, PR 26); a wider head halves it to keep
+    the operand blocks and the (block_k, block_q) f32 tiles in VMEM."""
+    return 1024 if head_dim <= 128 else 512
 
 
 def _largest_block(seq: int, cap: int) -> Optional[int]:
-    """The whole sequence when it fits under ``cap``; otherwise its
-    largest divisor that is <= ``cap`` and a multiple of 8 rows; None
-    when it has none."""
-    if seq <= cap:
+    """The whole sequence when it has at most ``_WHOLE`` rows and fits
+    under ``cap``; otherwise its largest divisor that is <= ``cap`` and
+    a multiple of 8 rows; None when it has none."""
+    if seq <= min(cap, _WHOLE):
         return seq
-    for b in range(cap // 8 * 8, 0, -8):
+    for b in range(min(cap, seq) // 8 * 8, 0, -8):
         if seq % b == 0:
             return b
     return None
@@ -60,22 +113,159 @@ def unsupported_reason(seq_q: int, seq_k: int,
     That leaves out a sequence longer than 512 with no divisor that is
     a multiple of 8 (1009, 1018) — the caller's cue for the XLA path."""
     for name, seq, want in (("q", seq_q, block_q), ("k", seq_k, block_k)):
-        if _largest_block(seq, want or 512) is None:
+        if _largest_block(seq, want or seq) is None:
             return (f"flash_attention: {name} sequence length {seq} has no "
-                    f"divisor <= {want or 512} that is a multiple of 8 rows")
+                    f"divisor{f' <= {want}' if want else ''} that is a "
+                    f"multiple of 8 rows")
     return None
 
 
-def _block_sizes(seq_q: int, seq_k: int,
-                 block_q: Optional[int] = None, block_k: Optional[int] = None):
-    """(block_q, block_k) the kernels tile the two sequences with;
-    ValueError naming the sequence ``unsupported_reason`` rejects."""
+def _block_sizes(seq_q: int, seq_k: int, head_dim: int,
+                 block_q: Optional[int] = None, block_k: Optional[int] = None
+                 ) -> Tuple[int, int]:
+    """(block_q, block_k) all three kernels tile the two sequences with:
+    a function of the shape alone (``block_q`` / ``block_k`` are a
+    test's or a sweep's cap; whether the call is causal did not move
+    the best choice in any kernel, so it is not asked).  ValueError
+    naming the sequence ``unsupported_reason`` rejects."""
     why = unsupported_reason(seq_q, seq_k, block_q, block_k)
     if why is not None:
         raise ValueError(why)
-    return (_largest_block(seq_q, block_q or 512),
-            _largest_block(seq_k, block_k or 512))
+    return (_largest_block(seq_q, block_q or _max_block(head_dim)),
+            _largest_block(seq_k, block_k or _max_block(head_dim)))
 
+
+def _runs(qi, ki, block_q: int, block_k: int):
+    """Causal: does block (qi, ki) hold a pair with k <= q?  Python
+    ints or traced scalars."""
+    return qi * block_q + block_q - 1 >= ki * block_k
+
+
+def _on_diagonal(qi, ki, block_q: int, block_k: int):
+    """Causal: does block (qi, ki) hold a pair with k > q, so that it
+    needs the mask?  A block that does not, runs."""
+    return ki * block_k + block_k - 1 > qi * block_q
+
+
+def tiling(seq_q: int, seq_k: int, head_dim: int, causal: bool,
+           block_q: Optional[int] = None, block_k: Optional[int] = None
+           ) -> Dict[str, Dict[str, int]]:
+    """What each kernel does with one (batch, head) of this shape: its
+    blocks, the steps its grid has and those of them that run a body
+    (the rest lie wholly above the causal diagonal and fetch nothing),
+    and the bands a block on the diagonal is cut into (``_tiles``)."""
+    bq, bk = _block_sizes(seq_q, seq_k, head_dim, block_q, block_k)
+    nq, nk = seq_q // bq, seq_k // bk
+    body = sum(1 for qi in range(nq) for ki in range(nk)
+               if not causal or _runs(qi, ki, bq, bk))
+    return {kernel: dict(block_q=bq, block_k=bk, grid_steps=nq * nk,
+                         body_steps=body, diagonal_bands=len(
+                             _tiles(kernel, causal, 0, 0, bq, bk)))
+            for kernel in KERNELS}
+
+
+def _folds(scale: float) -> bool:
+    """A power of two scales a bf16 or f32 query exactly, so it goes on
+    the (block_q, d) query and not on the (block_k, block_q) scores."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _scores_t(q, k, scale: float):
+    """(k q^T) * scale, (bk, bq) in f32: the scores transposed, keys
+    down the sublanes and queries along the lanes, so that what there is
+    one of a query (max, sum, lse, delta) is a lane-dense (1, bq) row."""
+    if _folds(scale):
+        q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    st = jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
+    return st if _folds(scale) else st * scale
+
+
+def _dscores_t(q, k, v, do, lse, delta, scale, mask_at):
+    """p^T and (p * (dp - delta))^T of one block, (bk, bq) in f32; the
+    scale of ds is left to the caller's accumulator."""
+    st = _causal_mask(_scores_t(q, k, scale), mask_at)
+    pt = jnp.exp(st - lse)
+    dpt = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
+    return pt, pt * (dpt - delta)
+
+
+def _causal_mask(st, mask_at):
+    """Transposed scores with every pair k > q at NEG_INF; ``mask_at``
+    is the (q, k) position of the tile's first pair, or None for a tile
+    that needs no mask."""
+    if mask_at is None:
+        return st
+    qs = mask_at[0] + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
+    ks = mask_at[1] + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
+    return jnp.where(qs >= ks, st, NEG_INF)
+
+
+def _tiles(kernel: str, masked: bool, qi, ki, block_q: int, block_k: int):
+    """[(k rows, q columns, mask_at)] that cover what block (qi, ki) has
+    to compute: two slices of the block and what ``_causal_mask`` takes.
+    A masked block of square blocks starts on the diagonal (qi == ki),
+    so which of its pairs are masked is known here: it is cut into bands
+    of ``_DIAGONAL_BAND`` keys, and a band leaves out the queries before
+    its first key."""
+    if not masked:
+        return [(slice(0, block_k), slice(0, block_q), None)]
+    band = _DIAGONAL_BAND[kernel]
+    if block_q != block_k or block_k % band:
+        band = block_k                      # one band: the whole block
+    return [(slice(lo, lo + band), slice(lo, block_q),
+             (qi * block_q + lo, ki * block_k + lo))
+            for lo in range(0, block_k, band)]
+
+
+def _when_causal(causal: bool, qi, ki, block_q: int, block_k: int, body):
+    """Run ``body(masked)`` as block (qi, ki) needs it: not at all above
+    the diagonal, masked on it, plain under it or without ``causal``."""
+    if not causal:
+        body(False)
+        return
+    diag = _on_diagonal(qi, ki, block_q, block_k)
+    pl.when(jnp.logical_and(diag, _runs(qi, ki, block_q, block_k)))(
+        lambda: body(True))
+    pl.when(jnp.logical_not(diag))(lambda: body(False))
+
+
+def _last_k(qi, block_q: int, block_k: int):
+    """The last k block a causal q block visits."""
+    return (qi * block_q + block_q - 1) // block_k
+
+
+def _first_q(ki, block_q: int, block_k: int):
+    """The first q block a causal k block visits."""
+    return (ki * block_k) // block_q
+
+
+def _kv_map(causal: bool, block_q: int, block_k: int):
+    """Index map of a k or v block on a (batch*heads, q, k) grid; a step
+    above the causal diagonal names the block already held."""
+    def index(bh_, qi, ki):
+        if causal:
+            ki = jnp.minimum(ki, _last_k(qi, block_q, block_k))
+        return (bh_, ki, 0)
+    return index
+
+
+def _stats_rows(x, block_q: int):
+    """(bh, seq_q) row statistics as (bh, q blocks, 1, block_q): one
+    value a row in HBM, and a kernel's block is whole in its two minor
+    dimensions whatever block_q is."""
+    return x.reshape(x.shape[0], -1, 1, block_q)
+
+
+_SEMANTICS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+# Each call below is jitted on its own: a model's step calls a kernel once
+# a layer, and without it every call site traces the kernel body and
+# lowers it to Mosaic again (5 s of a 24-layer step's lowering against
+# 1 s, PERF.md section 6, PR 26).  The scopes are the call sites', so
+# that a cached trace holds no name.
+_STATIC = dict(static_argnames=("scale", "causal", "bq", "bk", "interpret"))
 
 # ---------------------------------------------------------------------------
 # Forward kernel
@@ -93,83 +283,81 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_sc[:] = jnp.full_like(m_sc, NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
 
-    def _body():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            mask = (qi * block_q + rows) >= (ki * block_k + cols)
-            s = jnp.where(mask, s, NEG_INF)
+    def _body(masked):
+        for ks, qs, mask_at in _tiles("flash_fwd", masked, qi, ki,
+                                      block_q, block_k):
+            st = _causal_mask(                       # (keys, queries)
+                _scores_t(q_ref[0, qs, :], k_ref[0, ks, :], scale), mask_at)
+            v = v_ref[0, ks, :]
+            m_prev = m_sc[:, qs]                     # (1, queries)
+            m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
+            pt = jnp.exp(st - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_sc[:, qs] = alpha * l_sc[:, qs] + jnp.sum(pt, axis=0,
+                                                        keepdims=True)
+            # acc^T (d, queries) += v^T p^T: the small operand is turned
+            acc_sc[:, qs] = acc_sc[:, qs] * alpha + jax.lax.dot_general(
+                v, pt.astype(v.dtype), _TN,
+                preferred_element_type=jnp.float32)
+            m_sc[:, qs] = m_new
 
-        m_prev = m_sc[:, :1]                       # (bq, 1)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)  # (bq, 1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                      # (bq, bk)
-        alpha = jnp.exp(m_prev - m_new)             # (bq, 1)
-        l_new = alpha * l_sc[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_sc[:] = acc_sc[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
-        l_sc[:] = jnp.broadcast_to(l_new, l_sc.shape)
-
-    if causal:
-        # Skip blocks whose every (q, k) pair has k > q.
-        pl.when(qi * block_q + block_q - 1 >= ki * block_k)(_body)
-    else:
-        _body()
+    _when_causal(causal, qi, ki, block_q, block_k, _body)
 
     @pl.when(ki == nk - 1)
     def _finish():
-        l = l_sc[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_sc[:] / l_safe).astype(o_ref.dtype)
-        m = m_sc[:, :1]
-        lse = jnp.where(l == 0.0, NEG_INF, m + jnp.log(l_safe))
-        lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
+        l = l_sc[...]
+        empty = l == 0.0
+        l_safe = jnp.where(empty, 1.0, l)
+        o_ref[0] = (acc_sc[...] / l_safe).T.astype(o_ref.dtype)
+        lse_ref[...] = jnp.where(empty, NEG_INF, m_sc[...] + jnp.log(l_safe))
+
+
+@functools.partial(jax.jit, **_STATIC)
+def _fwd_call(qr, kr, vr, scale, causal, bq, bk, interpret):
+    """o (bh, sq, d) and lse (bh, sq) of (bh, s, d) operands."""
+    bh, sq, d = qr.shape
+    sk = kr.shape[1]
+
+    call = pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, causal=causal,
+                          block_q=bq, block_k=bk),
+        grid=(bh, sq // bq, sk // bk),
+        in_specs=[
+            pl.BlockSpec((1, bq, d), lambda bh_, qi, ki: (bh_, qi, 0)),
+            pl.BlockSpec((1, bk, d), _kv_map(causal, bq, bk)),
+            pl.BlockSpec((1, bk, d), _kv_map(causal, bq, bk)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, bq, d), lambda bh_, qi, ki: (bh_, qi, 0)),
+            pl.BlockSpec((None, None, 1, bq),
+                         lambda bh_, qi, ki: (bh_, qi, 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, sq, d), qr.dtype),
+            jax.ShapeDtypeStruct((bh, sq // bq, 1, bq), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((d, bq), jnp.float32),
+            pltpu.VMEM((1, bq), jnp.float32),
+            pltpu.VMEM((1, bq), jnp.float32),
+        ],
+        interpret=interpret,
+        name="flash_fwd",
+        compiler_params=_SEMANTICS,
+    )
+    out, lse = call(qr, kr, vr)
+    return out, lse.reshape(bh, sq)
 
 
 def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    bq, bk = _block_sizes(sq, sk, block_q, block_k)
-    bh = b * h
-    qr = q.reshape(bh, sq, d)
-    kr = k.reshape(bh, sk, d)
-    vr = v.reshape(bh, sk, d)
-
-    grid = (bh, sq // bq, sk // bk)
-    call = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh_, qi, ki: (bh_, qi, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh_, qi, ki: (bh_, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh_, qi, ki: (bh_, ki, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh_, qi, ki: (bh_, qi, 0)),
-            pl.BlockSpec((1, bq, _LANES), lambda bh_, qi, ki: (bh_, qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, sq, _LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-        ],
-        interpret=interpret,
-        name="flash_fwd",
-    )
+    bq, bk = _block_sizes(sq, sk, d, block_q, block_k)
     with jax.named_scope("ff.kernel.flash_fwd"):
-        out, lse = call(qr, kr, vr)
-    return (out.reshape(b, h, sq, d), lse[:, :, 0].reshape(b, h, sq))
+        out, lse = _fwd_call(q.reshape(b * h, sq, d), k.reshape(b * h, sk, d),
+                             v.reshape(b * h, sk, d), scale, causal, bq, bk,
+                             interpret)
+    return out.reshape(b, h, sq, d), lse.reshape(b, h, sq)
 
 
 # ---------------------------------------------------------------------------
@@ -188,41 +376,25 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_sc[:] = jnp.zeros_like(dk_sc)
         dv_sc[:] = jnp.zeros_like(dv_sc)
 
-    def _body():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, :1]                     # (bq, 1)
-        delta = delta_ref[0][:, :1]                 # (bq, 1)
+    def _body(masked):
+        for ks, qs, mask_at in _tiles("flash_dkv", masked, qi, ki,
+                                      block_q, block_k):
+            q, do = q_ref[0, qs, :], do_ref[0, qs, :]
+            pt, dst = _dscores_t(
+                q, k_ref[0, ks, :], v_ref[0, ks, :], do, lse_ref[:, qs],
+                delta_ref[:, qs], scale, mask_at)
+            dv_sc[ks, :] += jax.lax.dot_general(
+                pt.astype(do.dtype), do, _NN,
+                preferred_element_type=jnp.float32)
+            dk_sc[ks, :] += jax.lax.dot_general(
+                dst.astype(q.dtype), q, _NN,
+                preferred_element_type=jnp.float32)
 
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            mask = (qi * block_q + rows) >= (ki * block_k + cols)
-            s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse)                        # (bq, bk)
-        # dv += p^T @ do
-        dv_sc[:] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
-        # dp = do @ v^T ; ds = p * (dp - delta) * scale
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        # dk += ds^T @ q
-        dk_sc[:] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
-
-    if causal:
-        pl.when(qi * block_q + block_q - 1 >= ki * block_k)(_body)
-    else:
-        _body()
+    _when_causal(causal, qi, ki, block_q, block_k, _body)
 
     @pl.when(qi == nq - 1)
     def _finish():
-        dk_ref[0] = dk_sc[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_sc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
 
 
@@ -236,75 +408,50 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_sc[:] = jnp.zeros_like(dq_sc)
 
-    def _body():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, :1]
-        delta = delta_ref[0][:, :1]
+    def _body(masked):
+        for ks, qs, mask_at in _tiles("flash_dq", masked, qi, ki,
+                                      block_q, block_k):
+            k = k_ref[0, ks, :]
+            dst = _dscores_t(
+                q_ref[0, qs, :], k, v_ref[0, ks, :], do_ref[0, qs, :],
+                lse_ref[:, qs], delta_ref[:, qs], scale, mask_at)[1]
+            # dq^T (d, queries) += k^T ds^T
+            dq_sc[:, qs] += jax.lax.dot_general(
+                k, dst.astype(k.dtype), _TN,
+                preferred_element_type=jnp.float32)
 
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            mask = (qi * block_q + rows) >= (ki * block_k + cols)
-            s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        dq_sc[:] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
-
-    if causal:
-        pl.when(qi * block_q + block_q - 1 >= ki * block_k)(_body)
-    else:
-        _body()
+    _when_causal(causal, qi, ki, block_q, block_k, _body)
 
     @pl.when(ki == nk - 1)
     def _finish():
-        dq_ref[0] = dq_sc[:].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_sc[...] * scale).T.astype(dq_ref.dtype)
 
 
-def _flash_backward(scale, causal, block_q, block_k, interpret, res, grads):
-    q, k, v, out, lse = res
-    do, _ = grads
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
-    bq, bk = _block_sizes(sq, sk, block_q, block_k)
-    bh = b * h
+@functools.partial(jax.jit, **_STATIC)
+def _dkv_call(qr, kr, vr, dor, lse, delta, scale, causal, bq, bk, interpret):
+    """dk, dv (bh, sk, d); ``lse`` and ``delta`` are (bh, sq)."""
+    bh, sq, d = qr.shape
+    sk = kr.shape[1]
+    nq = sq // bq
 
-    delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+    def q_of(ki, qi):
+        if causal:   # a skipped step names the first block that runs
+            qi = jnp.minimum(jnp.maximum(qi, _first_q(ki, bq, bk)), nq - 1)
+        return qi
 
-    qr = q.reshape(bh, sq, d)
-    kr = k.reshape(bh, sk, d)
-    vr = v.reshape(bh, sk, d)
-    dor = do.reshape(bh, sq, d)
-    lser = jnp.broadcast_to(lse.reshape(bh, sq, 1), (bh, sq, _LANES))
-    deltar = jnp.broadcast_to(delta.reshape(bh, sq, 1), (bh, sq, _LANES))
-
-    common_specs = [
-        pl.BlockSpec((1, bq, d), lambda bh_, a, qi: (bh_, qi, 0)),      # q
-        pl.BlockSpec((1, bk, d), lambda bh_, a, qi: (bh_, a, 0)),       # k
-        pl.BlockSpec((1, bk, d), lambda bh_, a, qi: (bh_, a, 0)),       # v
-        pl.BlockSpec((1, bq, d), lambda bh_, a, qi: (bh_, qi, 0)),      # do
-        pl.BlockSpec((1, bq, _LANES), lambda bh_, a, qi: (bh_, qi, 0)),  # lse
-        pl.BlockSpec((1, bq, _LANES), lambda bh_, a, qi: (bh_, qi, 0)),  # delta
-    ]
-    dkv_call = pl.pallas_call(
+    rows = pl.BlockSpec((1, bq, d), lambda bh_, ki, qi: (bh_, q_of(ki, qi), 0))
+    cols = pl.BlockSpec((1, bk, d), lambda bh_, ki, qi: (bh_, ki, 0))
+    stat = pl.BlockSpec((None, None, 1, bq),
+                        lambda bh_, ki, qi: (bh_, q_of(ki, qi), 0, 0))
+    call = pl.pallas_call(
         functools.partial(_bwd_dkdv_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk),
-        grid=(bh, sk // bk, sq // bq),
-        in_specs=common_specs,
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda bh_, ki, qi: (bh_, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh_, ki, qi: (bh_, ki, 0)),
-        ],
+        grid=(bh, sk // bk, nq),
+        in_specs=[rows, cols, cols, rows, stat, stat],
+        out_specs=[cols, cols],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, sk, d), kr.dtype),
+            jax.ShapeDtypeStruct((bh, sk, d), vr.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
@@ -312,31 +459,52 @@ def _flash_backward(scale, causal, block_q, block_k, interpret, res, grads):
         ],
         interpret=interpret,
         name="flash_dkv",
+        compiler_params=_SEMANTICS,
     )
-    with jax.named_scope("ff.kernel.flash_dkv"):
-        dk, dv = dkv_call(qr, kr, vr, dor, lser, deltar)
+    return call(qr, kr, vr, dor, _stats_rows(lse, bq), _stats_rows(delta, bq))
 
-    dq_call = pl.pallas_call(
+
+@functools.partial(jax.jit, **_STATIC)
+def _dq_call(qr, kr, vr, dor, lse, delta, scale, causal, bq, bk, interpret):
+    """dq (bh, sq, d); ``lse`` and ``delta`` are (bh, sq)."""
+    bh, sq, d = qr.shape
+    sk = kr.shape[1]
+
+    rows = pl.BlockSpec((1, bq, d), lambda bh_, qi, ki: (bh_, qi, 0))
+    cols = pl.BlockSpec((1, bk, d), _kv_map(causal, bq, bk))
+    stat = pl.BlockSpec((None, None, 1, bq),
+                        lambda bh_, qi, ki: (bh_, qi, 0, 0))
+    call = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk),
         grid=(bh, sq // bq, sk // bk),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh_, qi, ki: (bh_, qi, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh_, qi, ki: (bh_, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh_, qi, ki: (bh_, ki, 0)),
-            pl.BlockSpec((1, bq, d), lambda bh_, qi, ki: (bh_, qi, 0)),
-            pl.BlockSpec((1, bq, _LANES), lambda bh_, qi, ki: (bh_, qi, 0)),
-            pl.BlockSpec((1, bq, _LANES), lambda bh_, qi, ki: (bh_, qi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda bh_, qi, ki: (bh_, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        in_specs=[rows, cols, cols, rows, stat, stat],
+        out_specs=rows,
+        out_shape=jax.ShapeDtypeStruct((bh, sq, d), qr.dtype),
+        scratch_shapes=[pltpu.VMEM((d, bq), jnp.float32)],
         interpret=interpret,
         name="flash_dq",
+        compiler_params=_SEMANTICS,
     )
-    with jax.named_scope("ff.kernel.flash_dq"):
-        dq = dq_call(qr, kr, vr, dor, lser, deltar)
+    return call(qr, kr, vr, dor, _stats_rows(lse, bq), _stats_rows(delta, bq))
 
+
+def _flash_backward(scale, causal, block_q, block_k, interpret, res, grads):
+    q, k, v, out, lse = res
+    do, _ = grads
+    b, h, sq, d = q.shape
+    sk = k.shape[2]
+    bq, bk = _block_sizes(sq, sk, d, block_q, block_k)
+    bh = b * h
+
+    delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+    args = (q.reshape(bh, sq, d), k.reshape(bh, sk, d), v.reshape(bh, sk, d),
+            do.reshape(bh, sq, d), lse.reshape(bh, sq), delta.reshape(bh, sq),
+            scale, causal, bq, bk, interpret)
+    with jax.named_scope("ff.kernel.flash_dkv"):
+        dk, dv = _dkv_call(*args)
+    with jax.named_scope("ff.kernel.flash_dq"):
+        dq = _dq_call(*args)
     return (dq.reshape(b, h, sq, d),
             dk.reshape(b, h, sk, d),
             dv.reshape(b, h, sk, d))

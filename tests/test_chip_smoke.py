@@ -279,12 +279,14 @@ def test_flash_kernel_never_interprets_by_default():
 
 
 def test_untileable_sequence_is_rejected_in_python():
-    assert _block_sizes(512, 4096) == (512, 512)
-    assert _block_sizes(100, 100) == (100, 100)
-    assert _block_sizes(1000, 1000) == (200, 200)
+    assert _block_sizes(512, 4096, 64) == (512, 1024)
+    assert _block_sizes(100, 100, 64) == (100, 100)
+    assert _block_sizes(1000, 1000, 64) == (1000, 1000)
+    assert _block_sizes(1040, 1040, 64) == (520, 520)
+    assert _block_sizes(4096, 4096, 256) == (512, 512)
     for seq in (1009, 1018):                  # prime; 2 * 509
         with pytest.raises(ValueError, match=f"sequence length {seq}"):
-            _block_sizes(seq, seq)
+            _block_sizes(seq, seq, 64)
         assert str(seq) in unsupported_reason(seq, 512)
     q, k, v, _ = _qkvw((1, 1, 1018, 8), jnp.float32)
     with pytest.raises(ValueError, match="1018"):
