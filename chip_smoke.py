@@ -19,7 +19,11 @@ out by the repo's own means:
   transformer      the bench transformer (4 layers x 512, 8 heads of 64,
                    seq 512, batch 16, bf16) for three train steps with
                    its attention in the compiled Pallas kernels (the
-                   step's HLO carries the tpu_custom_call); then
+                   step's HLO carries the tpu_custom_call) and its
+                   accuracy read from the logits (no instruction under
+                   the final Softmax's scope; the line gives the device
+                   time a step under ff.metrics and under that scope,
+                   from a profile of three more steps); then
                    serving.InferenceEngine over the same model answers
                    four requests of mixed prompt length and its tokens
                    are compared with FFModel.generate().
@@ -260,6 +264,45 @@ def _reference_probs(model, tokens):
     return run(model._decode_params(), model._stats, toks)
 
 
+def _tail_of_the_step(model, steps=3):
+    """What the train step spends on the metric vector and on the final
+    Softmax: instructions under `ff.metrics` and under the Softmax's
+    forward scope in the loaded step programs (the most over the step's
+    signatures), and device ms a step under each from a profile of
+    `steps` steps, joined to the scope map as the benchmark joins it.
+    The times are None where the trace holds no TPU's operations."""
+    import glob
+    import tempfile
+    import types
+
+    from benchmark import reduce
+    from benchmark.readers import device_scope
+    from flexflow_tpu.model import _op_scope
+    from flexflow_tpu.runtime import profiling
+
+    softmax, head = _op_scope(model.ops[-1]), _op_scope(model.ops[-2])
+    with tempfile.TemporaryDirectory() as logdir:
+        with profiling.trace(logdir):
+            for _ in range(steps):
+                model.train_iteration()
+            model.sync()
+        mine = [m for m in profiling.step_scopes().get("jit_step", [])
+                if any(e["scope"] == head for e in m.values())]
+        check(mine, f"no loaded step program holds the scope {head}")
+        path, = glob.glob(os.path.join(logdir, "plugins", "profile", "*",
+                                       "*.xplane.pb"))
+        joined = device_scope.join(types.SimpleNamespace(
+            trace=reduce.load(path), trace_window=None, trace_steps=steps,
+            say=say))
+    out = {}
+    for key, name in (("metrics", "ff.metrics"), ("final_softmax", softmax)):
+        out[f"{key}_instructions"] = max(
+            sum(e["scope"] == name for e in m.values()) for m in mine)
+        out[f"{key}_ms_per_step"] = None if joined is None else round(
+            1e3 * sum(s for (sc, _, _), s in joined.items() if sc == name), 4)
+    return out
+
+
 def phase_transformer(sz, dev, stats):
     import numpy as np
 
@@ -303,9 +346,14 @@ def phase_transformer(sz, dev, stats):
     check(all(math.isfinite(x) for x in losses),
           f"transformer loss not finite: {losses}")
     check(losses[-1] < losses[0], f"transformer loss not falling: {losses}")
+    tail = _tail_of_the_step(model)
+    check(tail["final_softmax_instructions"] == 0,
+          f"accuracy alone was asked for, yet the train step runs the final "
+          f"Softmax: {tail}")
     result("transformer", layers=sz["layers"], embed=sz["embed"],
            heads=sz["heads"], seq=seq, batch=b, dtype="bfloat16",
-           attention=want, tpu_custom_calls_in_step=calls, losses=losses)
+           attention=want, tpu_custom_calls_in_step=calls, losses=losses,
+           **tail)
 
     # the second surface on the same graph: the serving engine
     rng = np.random.default_rng(7)

@@ -15,6 +15,7 @@ fold), returned as a small dict of scalars, and accumulated on host in a
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict, Sequence
 
 import jax
@@ -82,61 +83,94 @@ class PerfMetrics:
         print(self.to_string())
 
 
+# What is a function of the logits alone: the arg-max of a softmax is the
+# arg-max of its logits, and a cross-entropy reads the log-softmax.  The
+# error metrics below need the probabilities themselves.
+_FROM_LOGITS = frozenset({
+    MetricsType.ACCURACY,
+    MetricsType.CATEGORICAL_CROSSENTROPY,
+    MetricsType.SPARSE_CATEGORICAL_CROSSENTROPY,
+})
+
+
 class Metrics:
     """Jit-side per-batch metric sums (reference compute kernels:
-    metrics_functions.cu:57-175).  ``probs`` is the softmax output (or raw
-    final activation when the model has no softmax); ``labels`` is int
-    (B,)/(B,1) when ``sparse`` else one-hot/regression targets (B, C)."""
+    metrics_functions.cu:57-175).
+
+    ``compute(preds, labels)`` reads the model's final activation: the
+    trailing Softmax's output, or the raw final tensor where the graph
+    has no softmax.  ``compute(preds, labels, from_logits=True)`` reads
+    the Softmax's *input*, the tensor a cross-entropy loss reads
+    (``Loss.wants_logits``), and gives the same sums without the
+    probabilities; the step takes that path whenever ``logits_suffice``
+    (``FFModel._loss_head``), so that a train step computes no softmax
+    for a metric.  ``labels`` is int (B,)/(B,1) [or (B,T)] when
+    ``sparse`` else one-hot/regression targets of ``preds``' shape.
+    Sequence outputs (B,T,C) count per token; ``preds`` is read in the
+    dtype and shape it has (no f32 copy, no fold of the leading
+    dimensions) except where a sum over classes needs f32."""
 
     def __init__(self, loss_type: str, metrics: Sequence[str]):
         self.metrics = list(metrics)
         self.sparse = "sparse" in loss_type
         self.loss_type = loss_type
 
-    def compute(self, probs: jax.Array, labels: jax.Array) -> Dict[str, jax.Array]:
-        probs = probs.astype(jnp.float32)
-        if probs.ndim > 2:  # sequence outputs: per-token metrics
-            probs = probs.reshape(-1, probs.shape[-1])
-            labels = labels.reshape(probs.shape[0], -1) \
-                if self.sparse else labels.reshape(probs.shape)
-        batch, num_classes = probs.shape[0], probs.shape[-1]
-        out: Dict[str, jax.Array] = {"train_all": jnp.int32(batch)}
+    @property
+    def logits_suffice(self) -> bool:
+        """Every metric asked for is a function of the logits."""
+        return _FROM_LOGITS.issuperset(self.metrics)
+
+    def compute(self, preds: jax.Array, labels: jax.Array,
+                from_logits: bool = False) -> Dict[str, jax.Array]:
+        """The batch's sums.  ``preds`` is the final activation, or with
+        ``from_logits`` the trailing Softmax's input (``logits_suffice``
+        must hold then)."""
+        assert not from_logits or self.logits_suffice, self.metrics
+        rows, num_classes = math.prod(preds.shape[:-1]), preds.shape[-1]
+        out: Dict[str, jax.Array] = {"train_all": jnp.int32(rows)}
         m = self.metrics
         if self.sparse:
-            sl = labels.reshape(batch).astype(jnp.int32)
-            if MetricsType.ACCURACY in m:
-                pred = jnp.argmax(probs, axis=-1).astype(jnp.int32)
-                out["train_correct"] = jnp.sum(pred == sl).astype(jnp.int32)
-            if MetricsType.SPARSE_CATEGORICAL_CROSSENTROPY in m:
-                p = jnp.take_along_axis(probs, sl[:, None], axis=-1)
-                out["sparse_cce_loss"] = jnp.sum(-jnp.log(jnp.maximum(p, LOG_MIN_VALUE)))
-            if (MetricsType.MEAN_SQUARED_ERROR in m
-                    or MetricsType.ROOT_MEAN_SQUARED_ERROR in m
-                    or MetricsType.MEAN_ABSOLUTE_ERROR in m):
-                onehot = jax.nn.one_hot(sl, num_classes, dtype=jnp.float32)
-                diff = probs - onehot
-                mse = jnp.sum(diff * diff, axis=-1)
-                if MetricsType.MEAN_SQUARED_ERROR in m:
-                    out["mse_loss"] = jnp.sum(mse)
-                if MetricsType.ROOT_MEAN_SQUARED_ERROR in m:
-                    out["rmse_loss"] = jnp.sum(jnp.sqrt(mse))
-                if MetricsType.MEAN_ABSOLUTE_ERROR in m:
-                    out["mae_loss"] = jnp.sum(jnp.abs(diff))
+            labels = labels.reshape(preds.shape[:-1]).astype(jnp.int32)
         else:
-            labels = labels.astype(jnp.float32)
-            if MetricsType.ACCURACY in m:
-                if num_classes == 1:
-                    # accuracy is meaningless for 1 output; reference returns
-                    # 100% (metrics_functions.cu:121-126)
-                    out["train_correct"] = jnp.int32(batch)
-                else:
-                    pred = jnp.argmax(probs, axis=-1)
-                    true = jnp.argmax(labels, axis=-1)
-                    out["train_correct"] = jnp.sum(pred == true).astype(jnp.int32)
-            if MetricsType.CATEGORICAL_CROSSENTROPY in m:
-                cce = -labels * jnp.log(jnp.maximum(probs, LOG_MIN_VALUE))
-                out["cce_loss"] = jnp.sum(jnp.where(labels > 0.0, cce, 0.0))
-            diff = probs - labels
+            labels = labels.reshape(preds.shape).astype(jnp.float32)
+
+        if MetricsType.ACCURACY in m:
+            if not self.sparse and num_classes == 1:
+                # accuracy is meaningless for 1 output; reference returns
+                # 100% (metrics_functions.cu:121-126)
+                out["train_correct"] = jnp.int32(rows)
+            else:
+                # first maximal index, of logits and probabilities alike
+                true = labels if self.sparse else jnp.argmax(labels, axis=-1)
+                out["train_correct"] = jnp.sum(
+                    jnp.argmax(preds, axis=-1) == true, dtype=jnp.int32)
+
+        if self.sparse:
+            cce, cce_sum = (MetricsType.SPARSE_CATEGORICAL_CROSSENTROPY,
+                            "sparse_cce_loss")
+        else:
+            cce, cce_sum = MetricsType.CATEGORICAL_CROSSENTROPY, "cce_loss"
+        if cce in m:
+            # -log(max(p, LOG_MIN_VALUE)), from whichever tensor is read
+            if from_logits:
+                nlogp = jnp.minimum(
+                    -jax.nn.log_softmax(preds.astype(jnp.float32), axis=-1),
+                    -math.log(LOG_MIN_VALUE))
+            else:
+                nlogp = -jnp.log(jnp.maximum(preds.astype(jnp.float32),
+                                             LOG_MIN_VALUE))
+            if self.sparse:
+                picked = jnp.take_along_axis(nlogp, labels[..., None], axis=-1)
+            else:
+                picked = jnp.where(labels > 0.0, labels * nlogp, 0.0)
+            out[cce_sum] = jnp.sum(picked)
+
+        if (MetricsType.MEAN_SQUARED_ERROR in m
+                or MetricsType.ROOT_MEAN_SQUARED_ERROR in m
+                or MetricsType.MEAN_ABSOLUTE_ERROR in m):
+            target = jax.nn.one_hot(labels, num_classes, dtype=jnp.float32) \
+                if self.sparse else labels
+            diff = preds.astype(jnp.float32) - target
             mse = jnp.sum(diff * diff, axis=-1)
             if MetricsType.MEAN_SQUARED_ERROR in m:
                 out["mse_loss"] = jnp.sum(mse)

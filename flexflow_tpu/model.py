@@ -29,6 +29,7 @@ update``) is preserved: the calls stage work and the fused step executes at
 from __future__ import annotations
 
 import contextlib
+import functools
 import itertools
 import os
 import time
@@ -1201,6 +1202,32 @@ class FFModel:
             return last.inputs[0]
         return last.output
 
+    def _loss_head(self):
+        """What every step builder ends in, as a pair of functions:
+        ``loss_of(env, labels) -> (loss, read)`` under the scope
+        ``ff.loss``, and ``metrics_of(read, labels)``, the metric sums
+        (the caller opens ``ff.metrics``).
+
+        ``read`` is the tensor the metrics read.  Where the loss takes
+        the logits of a trailing Softmax and every metric asked for is a
+        function of the logits (``Metrics.logits_suffice``), that is the
+        tensor the loss reads: a step that returns nothing else of the
+        Softmax's output leaves XLA no use for its forward, so no
+        probabilities are computed for a metric.  Otherwise it is the
+        final tensor, as the reference's metrics read it."""
+        loss_t = self._loss_input_tensor()
+        probs_t = self.final_tensor()
+        loss_fn, metrics = self.loss, self.metrics
+        from_logits = loss_t is not probs_t and metrics.logits_suffice
+        read_t = loss_t if from_logits else probs_t
+
+        def loss_of(env, labels):
+            with jax.named_scope("ff.loss"):
+                return loss_fn(env[loss_t.guid], labels), env[read_t.guid]
+
+        return loss_of, functools.partial(metrics.compute,
+                                          from_logits=from_logits)
+
     # ------------------------------------------------------------------
     # parameter/state initialization (≈ FFModel::init_layers + initializer
     # tasks, src/runtime/initializer.cc)
@@ -1951,12 +1978,9 @@ class FFModel:
     # the fused SPMD train step
     # ------------------------------------------------------------------
     def _build_train_step(self):
-        loss_t = self._loss_input_tensor()
-        probs_t = self.final_tensor()
+        loss_of, metrics_of = self._loss_head()
         base_key = jax.random.key(self.config.seed + 7919)
         opt = self.optimizer
-        metrics = self.metrics
-        loss_fn_obj = self.loss
 
         mkeys = self._metric_keys()
 
@@ -1982,8 +2006,8 @@ class FFModel:
                 jnp.where(jnp.isfinite(gnorm), gnorm, 0.0))
             return vec
 
-        def micro_metrics(loss, probs, labels):
-            msum = metrics.compute(probs, labels)
+        def micro_metrics(loss, read, labels):
+            msum = metrics_of(read, labels)
             msum["loss"] = loss
             msum["steps"] = 1.0
             # On-device metric accumulation: one small vector rides along
@@ -2041,14 +2065,13 @@ class FFModel:
 
             def loss_fn(p):
                 env, new_stats = self._run_graph(p, stats, batch, True, rng)
-                with jax.named_scope("ff.loss"):
-                    loss = loss_fn_obj(env[loss_t.guid], labels)
-                return loss, (env[probs_t.guid], new_stats)
+                loss, read = loss_of(env, labels)
+                return loss, (read, new_stats)
 
-            (loss, (probs, new_stats)), grads = jax.value_and_grad(
+            (loss, (read, new_stats)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
             with jax.named_scope("ff.metrics"):
-                mvec = micro_metrics(loss, probs, labels)
+                mvec = micro_metrics(loss, read, labels)
                 if track_health:
                     mvec = mvec + health_metrics(loss, grads)
             return finish(params, stats, opt_state, hparams, grads,
@@ -2075,15 +2098,14 @@ class FFModel:
                 def loss_fn(p):
                     env, new_stats = self._run_graph(
                         p, stats_c, mb, True, jax.random.fold_in(rng, idx))
-                    with jax.named_scope("ff.loss"):
-                        loss = loss_fn_obj(env[loss_t.guid], mlabels)
-                    return loss, (env[probs_t.guid], new_stats)
+                    loss, read = loss_of(env, mlabels)
+                    return loss, (read, new_stats)
 
-                (loss, (probs, new_stats)), g = jax.value_and_grad(
+                (loss, (read, new_stats)), g = jax.value_and_grad(
                     loss_fn, has_aux=True)(params)
                 g_acc = jax.tree.map(lambda a, b: a + b / accum, g_acc, g)
                 with jax.named_scope("ff.metrics"):
-                    mv_acc = mv_acc + micro_metrics(loss, probs, mlabels)
+                    mv_acc = mv_acc + micro_metrics(loss, read, mlabels)
                 return (g_acc, mv_acc, new_stats), None
 
             (grads, mvec, new_stats), _ = jax.lax.scan(
@@ -2116,19 +2138,18 @@ class FFModel:
         return fn
 
     def _build_eval_step(self):
-        loss_t = self._loss_input_tensor()
+        loss_of, metrics_of = self._loss_head()
         probs_t = self.final_tensor()
-        metrics = self.metrics
-        loss_fn_obj = self.loss
 
         def estep(params, stats, batch):
             env, _ = self._run_graph(params, stats, batch, False, None)
             labels = batch["label"]
-            with jax.named_scope("ff.loss"):
-                loss = loss_fn_obj(env[loss_t.guid], labels)
+            loss, read = loss_of(env, labels)
             with jax.named_scope("ff.metrics"):
-                msum = metrics.compute(env[probs_t.guid], labels)
+                msum = metrics_of(read, labels)
             msum["loss"] = loss
+            # the caller gets the probabilities, so here the trailing
+            # Softmax runs whatever the metrics read
             return msum, env[probs_t.guid]
 
         if self._lowering is not None:

@@ -45,7 +45,13 @@ class Softmax(Op):
     """Reference: src/ops/softmax.cu:166 (CUDNN_SOFTMAX_ACCURATE — i.e. the
     max-subtracted stable form, which is jax.nn.softmax).  When a CE loss
     follows, the executor feeds the loss from this op's *input* so the
-    fused log-softmax path is used (see losses.py)."""
+    fused log-softmax path is used (see losses.py), and the metrics read
+    that input too where they are functions of the logits
+    (``FFModel._loss_head``).  As the graph's last op it then runs only
+    where its output is used: in ``eval``/``predict`` and decoding, which
+    return it, and in a train step whose metrics need the probabilities
+    themselves (the error metrics).  Otherwise XLA drops it from the
+    train step."""
 
     _type = "Softmax"
 

@@ -65,6 +65,15 @@ def test_smoke_tiny_mode_runs_every_phase():
                   "fused_optimizer", "multichip"):
         assert any(ln.startswith(f"platform=cpu phase {phase}: ok ")
                    for ln in lines), (phase, r.stdout[-2000:])
+    # the transformer's line says which tensor the accuracy read: no
+    # instruction under the final Softmax's scope, and the two device
+    # times a step, which a CPU has not
+    line = next(ln for ln in lines if " phase transformer: ok " in ln)
+    said = json.loads(line.split(" ok ", 1)[1])
+    assert said["final_softmax_instructions"] == 0
+    assert said["metrics_instructions"] > 0
+    assert said["final_softmax_ms_per_step"] is None
+    assert said["metrics_ms_per_step"] is None
     # every line the script prints says where it ran (the package's own
     # notices start "flexflow_tpu:"), and none of them is a result line
     assert all("platform=cpu" in ln for ln in lines

@@ -1,23 +1,29 @@
 """Metrics-plane tests: Prometheus text well-formedness under a live
 scrape, percentile agreement with the trace_report reference math,
 counter totals under concurrent writer threads, and the zero-observer
-guarantee when FF_METRICS_PORT is unset.
+guarantee when FF_METRICS_PORT is unset.  That part drives
+observability/metrics.py with the stdlib alone.
 
-Pure stdlib — no jax import, so this file also proves metrics.py stays
-safe on the pre-jax import path (bench.py starts the exporter before
-the backend initializes).
+Last, the training metrics (flexflow_tpu/metrics.py): the sums the
+compiled step takes from the logits against those it takes from the
+probabilities.
 """
 
 import json
+import math
 import re
 import sys
 import threading
 import urllib.request
 
+import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 sys.path.insert(0, ".")
 
+from flexflow_tpu.metrics import LOG_MIN_VALUE, Metrics, MetricsType
 from flexflow_tpu.observability import events, metrics
 
 
@@ -295,3 +301,104 @@ def test_broken_backend_never_breaks_scrape():
 
 if __name__ == "__main__":
     sys.exit(pytest.main([__file__, "-v"]))
+
+
+# ---------------------------------------------------------------------------
+# the training metrics: from the logits as from the probabilities
+# ---------------------------------------------------------------------------
+
+SPARSE, DENSE = "sparse_categorical_crossentropy", "categorical_crossentropy"
+CCE_OF = {SPARSE: (MetricsType.SPARSE_CATEGORICAL_CROSSENTROPY,
+                   "sparse_cce_loss"),
+          DENSE: (MetricsType.CATEGORICAL_CROSSENTROPY, "cce_loss")}
+
+
+def _logits_and_labels(shape, loss_type, seed=0):
+    """Seeded logits of `shape` with, planted in the first rows: a tie
+    for the maximum between classes 3 and 7 whose label is the first of
+    them, the same tie whose label is the second, and a label whose
+    probability is under LOG_MIN_VALUE."""
+    rng = np.random.default_rng(seed)
+    classes = shape[-1]
+    logits = rng.standard_normal(shape).astype(np.float32)
+    labels = rng.integers(0, classes, shape[:-1])
+    rows, lab = logits.reshape(-1, classes), labels.reshape(-1)
+    rows[0, [3, 7]] = rows[1, [3, 7]] = 9.0
+    lab[0], lab[1] = 3, 7
+    rows[2, 5], lab[2] = -60.0, 5
+    assert math.exp(-60.0) < LOG_MIN_VALUE
+    if loss_type == DENSE:
+        labels = np.eye(classes, dtype=np.float32)[labels]
+    elif len(shape) == 2:
+        labels = labels[:, None]           # (B, 1), as the loaders give it
+    return jnp.asarray(logits), jnp.asarray(labels)
+
+
+@pytest.mark.parametrize("shape", [(16, 12), (4, 6, 12)], ids=["BC", "BTC"])
+@pytest.mark.parametrize("loss_type", [SPARSE, DENSE])
+@pytest.mark.parametrize("which", ["accuracy", "crossentropy"])
+def test_sums_from_logits_equal_those_from_softmax(which, loss_type, shape):
+    cce, cce_sum = CCE_OF[loss_type]
+    asked = [MetricsType.ACCURACY] if which == "accuracy" else [cce]
+    m = Metrics(loss_type, asked)
+    assert m.logits_suffice
+    logits, labels = _logits_and_labels(shape, loss_type)
+    got = jax.jit(lambda x, y: m.compute(x, y, from_logits=True))(
+        logits, labels)
+    want = jax.jit(m.compute)(jax.nn.softmax(logits, axis=-1), labels)
+    assert set(got) == set(want)
+    rows = math.prod(shape[:-1])
+    assert int(got["train_all"]) == int(want["train_all"]) == rows
+    if which == "accuracy":
+        assert got["train_correct"].dtype == jnp.int32
+        assert int(got["train_correct"]) == int(want["train_correct"])
+        # the first maximal index wins: row 0's tie counts, row 1's not
+        flat = np.asarray(logits).reshape(rows, -1)
+        lab = np.asarray(labels).reshape(rows, -1)
+        true = lab.argmax(-1) if loss_type == DENSE else lab[:, 0]
+        hits = flat.argmax(-1) == true
+        assert hits[0] and not hits[1]
+        assert int(got["train_correct"]) == int(hits.sum())
+    else:
+        np.testing.assert_allclose(got[cce_sum], want[cce_sum], rtol=1e-5)
+        # row 2 reads the clamp, not 60
+        clamp = -math.log(LOG_MIN_VALUE)
+        one = m.compute(
+            logits.reshape(rows, -1)[2:3], labels.reshape(rows, -1)[2:3],
+            from_logits=True)[cce_sum]
+        assert float(one) == pytest.approx(clamp, rel=1e-6)
+
+
+@pytest.mark.parametrize("asked,suffice", [
+    ([MetricsType.ACCURACY], True),
+    ([MetricsType.ACCURACY, MetricsType.SPARSE_CATEGORICAL_CROSSENTROPY],
+     True),
+    ([MetricsType.CATEGORICAL_CROSSENTROPY], True),
+    ([], True),
+    ([MetricsType.ACCURACY, MetricsType.MEAN_SQUARED_ERROR], False),
+    ([MetricsType.ROOT_MEAN_SQUARED_ERROR], False),
+    ([MetricsType.MEAN_ABSOLUTE_ERROR], False),
+])
+def test_which_metrics_the_logits_suffice_for(asked, suffice):
+    m = Metrics(SPARSE, asked)
+    assert m.logits_suffice is suffice
+    if not suffice:  # an error metric needs the probabilities themselves
+        logits, labels = _logits_and_labels((4, 12), SPARSE)
+        with pytest.raises(AssertionError):
+            m.compute(logits, labels, from_logits=True)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_accuracy_reads_the_logits_as_they_are(dtype):
+    """No f32 copy of the (B, T, C) tensor and no fold of its leading
+    dimensions (on the TPU that reshape was a relayout of 823 MB)."""
+    shape = (4, 6, 12)
+    m = Metrics(SPARSE, [MetricsType.ACCURACY])
+    jaxpr = jax.make_jaxpr(lambda x, y: m.compute(x, y, from_logits=True))(
+        jnp.zeros(shape, dtype), jnp.zeros(shape[:-1], jnp.int32))
+    reads = {eqn.primitive.name for eqn in jaxpr.eqns
+             if any(getattr(v.aval, "shape", None) == shape
+                    for v in eqn.invars)}
+    assert reads == {"argmax"}, jaxpr
+    assert not any(v.aval.shape == shape for eqn in jaxpr.eqns
+                   for v in eqn.outvars), jaxpr

@@ -46,14 +46,16 @@ def _no_telemetry(monkeypatch):
     events.reset_active()
 
 
-def _conv_net(batch=4):
+ACCURACY = (ff.MetricsType.ACCURACY,)
+
+
+def _conv_net(batch=4, metrics=ACCURACY):
     cfg = ff.FFConfig(batch_size=batch, compute_dtype="float32")
     cfg.parse_args(["-ll:tpu", "1"])
     m = ff.FFModel(cfg)
     build_alexnet(m, batch, num_classes=10, height=67, width=67)
     m.compile(ff.SGDOptimizer(m, lr=0.001),
-              ff.LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
-              [ff.MetricsType.ACCURACY])
+              ff.LossType.SPARSE_CATEGORICAL_CROSSENTROPY, list(metrics))
     m.init_layers(seed=0)
     rng = np.random.default_rng(0)
     m.set_batch({m.input_tensors[0]:
@@ -62,7 +64,7 @@ def _conv_net(batch=4):
     return m
 
 
-def _transformer(batch=2, seq=128):
+def _transformer(batch=2, seq=128, metrics=ACCURACY):
     cfg = ff.FFConfig(batch_size=batch, compute_dtype="float32")
     cfg.parse_args(["-ll:tpu", "1"])
     m = ff.FFModel(cfg)
@@ -72,8 +74,7 @@ def _transformer(batch=2, seq=128):
         if op._type == "MultiHeadAttention":
             op.impl = "pallas_interpret"  # the kernels' scopes, on the CPU
     m.compile(ff.AdamOptimizer(m, alpha=1e-4),
-              ff.LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
-              [ff.MetricsType.ACCURACY])
+              ff.LossType.SPARSE_CATEGORICAL_CROSSENTROPY, list(metrics))
     m.init_layers(seed=0)
     rng = np.random.default_rng(0)
     toks = rng.integers(0, 128, (batch, seq), dtype=np.int32)
@@ -360,6 +361,110 @@ def test_scopes_change_nothing_that_is_computed(devices, monkeypatch, kind):
     assert "ff." not in bare and bare != scoped
     assert _canonical(bare) == _canonical(scoped)
     assert with_scopes.last_loss == without.last_loss
+
+
+# ---------------------------------------------------------------------------
+# the step reads its logits once
+# ---------------------------------------------------------------------------
+
+SPARSE_CCE = ff.MetricsType.SPARSE_CATEGORICAL_CROSSENTROPY
+MSE = ff.MetricsType.MEAN_SQUARED_ERROR
+MAE = ff.MetricsType.MEAN_ABSOLUTE_ERROR
+
+
+def _under(scopes, scope):
+    return [name for name, e in scopes.items() if e["scope"] == scope]
+
+
+@pytest.mark.parametrize("kind,metrics,softmax_runs", [
+    ("transformer", ACCURACY, False),
+    ("conv_net", ACCURACY, False),
+    ("transformer", ACCURACY + (SPARSE_CCE,), False),
+    ("transformer", ACCURACY + (MSE,), True),   # needs the probabilities
+    ("transformer", (MAE,), True),
+])
+def test_train_step_runs_the_final_softmax_only_for_a_metric_that_needs_it(
+        devices, kind, metrics, softmax_runs):
+    m = BUILDERS[kind](metrics=metrics)
+    _steps(m, 1)
+    # compiled anew for the live arguments: models of the same op names
+    # may still be loaded, and this one's program must be among them
+    scopes = profiling.parse_hlo_scopes(_compiled_step(m))
+    assert any(scopes == loaded
+               for loaded in profiling.step_scopes()["jit_step"])
+    final = _op_scope(m.ops[-1])
+    assert final.startswith("ff.op.softmax.")
+    assert bool(_under(scopes, final)) is softmax_runs
+    assert _under(scopes, "ff.metrics") and _under(scopes, "ff.loss")
+
+
+def test_eval_step_returns_the_probabilities_so_its_softmax_runs(devices):
+    m = _transformer()
+    m.eval_batch()
+    params, batch = m._eval_inputs()
+    text = m._eval_step_fn.lower(params, m._stats, batch).compile().as_text()
+    scopes = profiling.parse_hlo_scopes(text)
+    assert _under(scopes, _op_scope(m.ops[-1]))
+    assert _under(scopes, "ff.metrics") and _under(scopes, "ff.loss")
+
+
+# The first step's loss at the parent of the PR that moved the metrics to
+# the logits (PR 28; CPU backend, float32): the loss's arithmetic is not
+# touched by what the metrics read.
+FIRST_LOSS = {"conv_net": 2.3102827072143555,
+              "transformer": 5.051053524017334}
+
+
+@pytest.mark.parametrize("kind", sorted(BUILDERS))
+def test_first_loss_is_what_it_was_before_the_metrics_moved(devices, kind):
+    m = BUILDERS[kind]()
+    _steps(m, 1)
+    assert m.last_loss == FIRST_LOSS[kind]
+
+
+def _seq_mlp(accum=1, metrics=ACCURACY + (SPARSE_CCE,)):
+    cfg = ff.FFConfig(batch_size=8, compute_dtype="float32")
+    cfg.parse_args(["-ll:tpu", "1", "--grad-accum", str(accum)])
+    m = ff.FFModel(cfg)
+    x = m.create_tensor((8, 6, 16), nchw=False)
+    t = m.dense(x, 32, activation=ff.ActiMode.RELU, name="fc1")
+    m.softmax(m.dense(t, 12, name="fc2"))
+    m.compile(ff.SGDOptimizer(m, lr=0.1),
+              ff.LossType.SPARSE_CATEGORICAL_CROSSENTROPY, list(metrics))
+    m.init_layers(seed=0)
+    rng = np.random.default_rng(0)
+    m.set_batch({x: rng.standard_normal((8, 6, 16), np.float32)},
+                rng.integers(0, 12, (8, 6), dtype=np.int32))
+    return m
+
+
+@pytest.mark.parametrize("metrics", [ACCURACY + (SPARSE_CCE,),
+                                     ACCURACY + (SPARSE_CCE, MSE)],
+                         ids=["from_logits", "from_probabilities"])
+@pytest.mark.parametrize("how", ["step_accum", "eval"])
+def test_accumulation_and_eval_count_what_the_step_counts(devices, how,
+                                                          metrics):
+    m = _seq_mlp(metrics=metrics)
+    _steps(m, 1)
+    want = m.current_metrics
+    assert want.train_all == 48 and 0 < want.train_correct < 48
+    if how == "eval":  # before any update: the step's own forward pass
+        got = _seq_mlp(metrics=metrics).eval_batch()
+        got_loss = got["loss"]
+    else:
+        other = _seq_mlp(accum=2, metrics=metrics)
+        _steps(other, 1)
+        got, got_loss = vars(other.current_metrics), other.last_loss
+    assert got["train_all"] == want.train_all
+    assert got["train_correct"] == want.train_correct
+    assert got["sparse_cce_loss"] == pytest.approx(want.sparse_cce_loss,
+                                                   rel=1e-5)
+    if MSE in metrics:
+        assert want.mse_loss > 0
+        assert got["mse_loss"] == pytest.approx(want.mse_loss, rel=1e-5)
+    assert got_loss == pytest.approx(m.last_loss, rel=1e-6)
+    # the metric and the loss are one quantity, summed and averaged
+    assert want.sparse_cce_loss / 48 == pytest.approx(m.last_loss, rel=1e-5)
 
 
 # ---------------------------------------------------------------------------
