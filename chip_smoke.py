@@ -264,13 +264,15 @@ def _reference_probs(model, tokens):
     return run(model._decode_params(), model._stats, toks)
 
 
-def _tail_of_the_step(model, steps=3):
-    """What the train step spends on the metric vector and on the final
-    Softmax: instructions under `ff.metrics` and under the Softmax's
-    forward scope in the loaded step programs (the most over the step's
-    signatures), and device ms a step under each from a profile of
-    `steps` steps, joined to the scope map as the benchmark joins it.
-    The times are None where the trace holds no TPU's operations."""
+def _tail_of_the_step(model, classes, steps=3):
+    """What the train step spends on the loss, on the metric vector and
+    on the final Softmax: instructions under `ff.loss`, `ff.metrics` and
+    the Softmax's forward scope in the loaded step programs (the most
+    over the step's signatures), and from a profile of `steps` steps,
+    joined to the scope map as the benchmark joins it: device ms a step
+    under each scope (every phase), and of the instructions under
+    `ff.loss` those whose result is f32 and `classes` wide.  These are
+    None where the trace holds no TPU's operations."""
     import glob
     import tempfile
     import types
@@ -286,8 +288,9 @@ def _tail_of_the_step(model, steps=3):
             for _ in range(steps):
                 model.train_iteration()
             model.sync()
-        mine = [m for m in profiling.step_scopes().get("jit_step", [])
-                if any(e["scope"] == head for e in m.values())]
+        mine = [(text, m) for name, text, m in profiling.step_programs()
+                if name == "jit_step"
+                and any(e["scope"] == head for e in m.values())]
         check(mine, f"no loaded step program holds the scope {head}")
         path, = glob.glob(os.path.join(logdir, "plugins", "profile", "*",
                                        "*.xplane.pb"))
@@ -295,12 +298,32 @@ def _tail_of_the_step(model, steps=3):
             trace=reduce.load(path), trace_window=None, trace_steps=steps,
             say=say))
     out = {}
-    for key, name in (("metrics", "ff.metrics"), ("final_softmax", softmax)):
+    for key, name in (("loss", "ff.loss"), ("metrics", "ff.metrics"),
+                      ("final_softmax", softmax)):
         out[f"{key}_instructions"] = max(
-            sum(e["scope"] == name for e in m.values()) for m in mine)
+            sum(e["scope"] == name for e in m.values()) for _, m in mine)
         out[f"{key}_ms_per_step"] = None if joined is None else round(
             1e3 * sum(s for (sc, _, _), s in joined.items() if sc == name), 4)
+    # a CPU's fusions say nothing of the chip's
+    out["loss_classwide_f32_instructions"] = None if joined is None else max(
+        _classwide_f32(text, m, "ff.loss", classes) for text, m in mine)
     return out
+
+
+def _classwide_f32(text, scopes, scope, classes):
+    """How many instructions of a step program under `scope` give an f32
+    result `classes` wide.  `scopes` is the program's scope map, which
+    holds the instructions the device runs on their own; in the HLO
+    `text` each stands as `name = type opcode(...)`, the type a tuple for
+    a fusion with several results."""
+    import re
+
+    results = re.findall(  # a tuple's end is the ")" before the opcode
+        r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s+(\(.*?\)|\S+)\s+[\w\-]+\(",
+        text, re.M)
+    wide = re.compile(rf"\bf32\[(?:\d+,)*{classes}(?:,\d+)*\]")
+    return sum(scopes.get(name, {}).get("scope") == scope
+               and bool(wide.search(result)) for name, result in results)
 
 
 def phase_transformer(sz, dev, stats):
@@ -346,10 +369,12 @@ def phase_transformer(sz, dev, stats):
     check(all(math.isfinite(x) for x in losses),
           f"transformer loss not finite: {losses}")
     check(losses[-1] < losses[0], f"transformer loss not falling: {losses}")
-    tail = _tail_of_the_step(model)
+    tail = _tail_of_the_step(model, vocab)
     check(tail["final_softmax_instructions"] == 0,
           f"accuracy alone was asked for, yet the train step runs the final "
           f"Softmax: {tail}")
+    check(not tail["loss_classwide_f32_instructions"],
+          f"the loss writes f32 as wide as the {vocab} classes: {tail}")
     result("transformer", layers=sz["layers"], embed=sz["embed"],
            heads=sz["heads"], seq=seq, batch=b, dtype="bfloat16",
            attention=want, tpu_custom_calls_in_step=calls, losses=losses,

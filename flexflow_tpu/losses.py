@@ -10,14 +10,21 @@ region and scales by ``1/batch_size`` (loss_functions.cu:141-150):
 
 Here losses are scalar-valued pure functions differentiated by ``jax.grad``
 — chosen so the autodiff gradient is *identical* to the reference kernels:
-  * sparse/dense CCE is computed from the **pre-softmax** activations via
-    ``log_softmax`` (the fused softmax+CE form: d/dlogits = (probs-onehot)/B
-    — exactly the reference's fused pair of softmax-forward + CE-backward).
+  * sparse/dense CCE is computed from the **pre-softmax** activations
+    (the fused softmax+CE form: d/dlogits = (probs-onehot)/B — exactly
+    the reference's fused pair of softmax-forward + CE-backward), by
+    ``neg_log_prob``: a row's log-sum-exp and its label's logit, read
+    from the logits in the dtype, shape and layout the last op wrote
+    them in, f32 inside the reductions only.  Nothing as wide as the
+    classes is written out, and nothing is gathered from.
   * MSE-avg uses 0.5·mean over samples of the squared error, whose gradient
     is (logit-label)/B.
 """
 
 from __future__ import annotations
+
+import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +46,32 @@ def _canon(loss_type: str) -> str:
     if loss_type not in aliases:
         raise ValueError(f"Unrecognized loss type: {loss_type}")
     return aliases[loss_type]
+
+
+def neg_log_prob(logits: jax.Array,
+                 labels: Optional[jax.Array] = None) -> jax.Array:
+    """``-log(softmax(logits))`` over the last axis, in f32: of each
+    row's label where int ``labels`` of shape ``logits.shape[:-1]`` are
+    given (one value a row), else of every class.
+
+    The one cross-entropy from logits of the repo (the loss and the
+    metrics).  ``logits`` is read as it is: no cast, fold or relayout of
+    the tensor; ``x - max``, the exponent and the sums are f32, element
+    for element what ``jax.nn.log_softmax`` computes.  The label's logit
+    is a masked sum over the classes, not a gather: it fuses into the
+    pass that sums the exponents, needs no buffer of the classes' width,
+    and splits with a class axis that is sharded.  The maximum takes no
+    gradient; d/dlogits is ``softmax - onehot`` in the logits' dtype."""
+    top = jax.lax.stop_gradient(jnp.max(logits, axis=-1, keepdims=True))
+    shifted = logits.astype(jnp.float32) - top.astype(jnp.float32)
+    lse = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1, keepdims=True))
+    if labels is None:
+        return lse - shifted
+    classes = jax.lax.broadcasted_iota(jnp.int32, logits.shape,
+                                       logits.ndim - 1)
+    picked = jnp.sum(jnp.where(classes == labels[..., None], shifted, 0.0),
+                     axis=-1)
+    return lse[..., 0] - picked
 
 
 class Loss:
@@ -64,20 +97,16 @@ class Loss:
         or (B, T, C) for sequence models (NMT), reduced per-token.
         labels: (B,)/(B,1) [or (B,T)] int for sparse CE; matching shape
         otherwise."""
-        preds = preds.astype(jnp.float32)
-        if preds.ndim > 2:  # sequence logits: fold time into the batch dim
-            preds = preds.reshape(-1, preds.shape[-1])
-            labels = labels.reshape(preds.shape[0], -1) \
-                if labels.ndim > 1 and labels.size != preds.shape[0] else labels
-        batch = preds.shape[0]
+        # a vector of outputs is a batch of scalars
+        rows = math.prod(preds.shape[:-1] or preds.shape)
         if self.loss_type == LossType.SPARSE_CATEGORICAL_CROSSENTROPY:
-            labels = labels.reshape(batch).astype(jnp.int32)
-            logp = jax.nn.log_softmax(preds, axis=-1)
-            nll = -jnp.take_along_axis(logp, labels[:, None], axis=-1)
-            return jnp.sum(nll) / batch
+            labels = labels.reshape(preds.shape[:-1]).astype(jnp.int32)
+            # the rows' values as one vector (16 KB for GPT-2's 4096), so
+            # that they are summed in the order they always were
+            return jnp.sum(neg_log_prob(preds, labels).reshape(rows)) / rows
+        labels = labels.reshape(preds.shape).astype(jnp.float32)
         if self.loss_type == LossType.CATEGORICAL_CROSSENTROPY:
-            logp = jax.nn.log_softmax(preds, axis=-1)
-            return jnp.sum(-labels.astype(jnp.float32) * logp) / batch
+            return jnp.sum(labels * neg_log_prob(preds)) / rows
         # MSE avg-reduce: grad must be (pred-label)/B per element
-        diff = preds - labels.astype(jnp.float32)
-        return 0.5 * jnp.sum(diff * diff) / batch
+        diff = preds.astype(jnp.float32) - labels
+        return 0.5 * jnp.sum(diff * diff) / rows

@@ -21,6 +21,8 @@ from typing import Dict, Sequence
 import jax
 import jax.numpy as jnp
 
+from .losses import neg_log_prob
+
 LOG_MIN_VALUE = 1e-20
 
 
@@ -151,18 +153,20 @@ class Metrics:
         else:
             cce, cce_sum = MetricsType.CATEGORICAL_CROSSENTROPY, "cce_loss"
         if cce in m:
-            # -log(max(p, LOG_MIN_VALUE)), from whichever tensor is read
+            # -log(max(p, LOG_MIN_VALUE)), from whichever tensor is read;
+            # where labels are sparse, of each row's label alone
             if from_logits:
                 nlogp = jnp.minimum(
-                    -jax.nn.log_softmax(preds.astype(jnp.float32), axis=-1),
+                    neg_log_prob(preds, labels if self.sparse else None),
                     -math.log(LOG_MIN_VALUE))
             else:
                 nlogp = -jnp.log(jnp.maximum(preds.astype(jnp.float32),
                                              LOG_MIN_VALUE))
-            if self.sparse:
-                picked = jnp.take_along_axis(nlogp, labels[..., None], axis=-1)
-            else:
-                picked = jnp.where(labels > 0.0, labels * nlogp, 0.0)
+                if self.sparse:
+                    nlogp = jnp.take_along_axis(nlogp, labels[..., None],
+                                                axis=-1)
+            picked = nlogp if self.sparse else \
+                jnp.where(labels > 0.0, labels * nlogp, 0.0)
             out[cce_sum] = jnp.sum(picked)
 
         if (MetricsType.MEAN_SQUARED_ERROR in m

@@ -32,7 +32,7 @@ import contextlib
 import json
 import os
 import re
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax
 
@@ -244,6 +244,23 @@ def parse_hlo_scopes(text: str) -> Dict[str, Dict[str, object]]:
     return out
 
 
+def step_programs() -> List[Tuple[str, str, Dict[str, Dict[str, object]]]]:
+    """(module name, optimized HLO text, scope map as ``parse_hlo_scopes``
+    gives it) of every step program this process has loaded.  A step
+    program is one whose optimized HLO holds an ``ff.`` scope.  Read from
+    the executables the client holds: nothing is compiled or loaded."""
+    out = []
+    for exe in jax.devices()[0].client.live_executables():
+        module = exe.hlo_modules()[0]
+        text = module.to_string()
+        if 'op_name="' not in text or SPAN_PREFIX not in text:
+            continue
+        scopes = parse_hlo_scopes(text)
+        if any(e["scope"] for e in scopes.values()):
+            out.append((module.name, text, scopes))
+    return out
+
+
 def step_scopes() -> Dict[str, List[Dict[str, Dict[str, object]]]]:
     """The scope map of every step program this process has loaded:
 
@@ -253,18 +270,10 @@ def step_scopes() -> Dict[str, List[Dict[str, Dict[str, object]]]]:
                                            "mixed": bool}}, ...]}
 
     one entry of the list for each loaded program of that name (the
-    train step is loaded once per signature).  A step program is one
-    whose optimized HLO holds an ``ff.`` scope.  Read from the
-    executables the client holds: nothing is compiled or loaded."""
+    train step is loaded once per signature)."""
     out: Dict[str, List[Dict[str, Dict[str, object]]]] = {}
-    for exe in jax.devices()[0].client.live_executables():
-        module = exe.hlo_modules()[0]
-        text = module.to_string()
-        if 'op_name="' not in text or SPAN_PREFIX not in text:
-            continue
-        scopes = parse_hlo_scopes(text)
-        if any(e["scope"] for e in scopes.values()):
-            out.setdefault(module.name, []).append(scopes)
+    for name, _, scopes in step_programs():
+        out.setdefault(name, []).append(scopes)
     return out
 
 

@@ -74,6 +74,10 @@ def test_smoke_tiny_mode_runs_every_phase():
     assert said["metrics_instructions"] > 0
     assert said["final_softmax_ms_per_step"] is None
     assert said["metrics_ms_per_step"] is None
+    # and what the loss costs: counted here, timed and judged on the chip
+    assert said["loss_instructions"] > 0
+    assert said["loss_ms_per_step"] is None
+    assert said["loss_classwide_f32_instructions"] is None
     # every line the script prints says where it ran (the package's own
     # notices start "flexflow_tpu:"), and none of them is a result line
     assert all("platform=cpu" in ln for ln in lines
@@ -82,6 +86,31 @@ def test_smoke_tiny_mode_runs_every_phase():
     hdr = lines[0]
     assert re.search(r"jax \S+ libtpu \S+ platform=cpu "
                      r"device_kind='cpu' devices=8", hdr), hdr
+
+
+def test_smoke_counts_the_f32_the_loss_writes_as_wide_as_the_classes():
+    """On the parent's GPT-2 step the count was 2 (the relayouted copy
+    and `log_softmax` written out); a row's statistics, bf16 logits and
+    an instruction of another scope do not count."""
+    import chip_smoke as smoke
+    text = """HloModule jit_step
+ENTRY %main (p: bf16[4,1024,50257]) -> f32[] {
+  %p = bf16[4,1024,50257]{1,2,0:T(8,128)(2,1)} parameter(0)
+  %copy.3047 = f32[4,1024,50257]{2,1,0:T(8,128)} copy(%p), metadata={op_name="jit(step)/jvp(ff.loss)/convert_element_type"}
+  %subtract_subtract_fusion = f32[4096,50257]{1,0} fusion(%copy.3047), kind=kLoop, calls=%f, metadata={op_name="jit(step)/jvp(ff.loss)/sub"}
+  %stats = (f32[4,1024]{1,0:T(4,128)S(1)}, f32[4,1024]{1,0:T(4,128)S(1)}) fusion(%p), kind=kLoop, calls=%g, metadata={op_name="jit(step)/jvp(ff.loss)/reduce_sum"}
+  %pair = (f32[4,1024]{1,0:T(4,128)S(1)}, f32[4,1024,50257]{2,1,0:T(8,128)}) fusion(%p), kind=kLoop, calls=%g, metadata={op_name="jit(step)/jvp(ff.loss)/exp"}
+  %probs = f32[4,1024,50257]{2,1,0} fusion(%p), kind=kLoop, calls=%h, metadata={op_name="jit(step)/ff.metrics/exp"}
+  ROOT %loss = f32[] reduce(%stats), metadata={op_name="jit(step)/jvp(ff.loss)/reduce_sum"}
+}
+"""
+    from flexflow_tpu.runtime import profiling
+
+    scopes = profiling.parse_hlo_scopes(text)
+    assert scopes["stats"]["scope"] == "ff.loss"
+    assert smoke._classwide_f32(text, scopes, "ff.loss", 50257) == 3
+    assert smoke._classwide_f32(text, scopes, "ff.metrics", 50257) == 1
+    assert smoke._classwide_f32(text, scopes, "ff.loss", 257) == 0
 
 
 def test_smoke_prints_the_multichip_skip():

@@ -389,12 +389,17 @@ def test_train_step_runs_the_final_softmax_only_for_a_metric_that_needs_it(
     _steps(m, 1)
     # compiled anew for the live arguments: models of the same op names
     # may still be loaded, and this one's program must be among them
-    scopes = profiling.parse_hlo_scopes(_compiled_step(m))
+    text = _compiled_step(m)
+    scopes = profiling.parse_hlo_scopes(text)
     assert any(scopes == loaded
                for loaded in profiling.step_scopes()["jit_step"])
     final = _op_scope(m.ops[-1])
     assert final.startswith("ff.op.softmax.")
-    assert bool(_under(scopes, final)) is softmax_runs
+    # anywhere in the program, inside a fusion too: on f32 logits the
+    # Softmax's exponent and sum are the loss's own (losses.neg_log_prob
+    # does the same arithmetic, so XLA computes them once), and what is
+    # left of it, the division, fuses into the metric that reads it
+    assert (final in text) is softmax_runs
     assert _under(scopes, "ff.metrics") and _under(scopes, "ff.loss")
 
 
