@@ -214,12 +214,6 @@ class FFConfig:
     # forces lazy per-touched-row updates under momentum/Adam; False
     # always streams the full table.
     sparse_host_embeddings: Optional[bool] = None
-    # Whole-graph lowering (parallel/lowering.py): compile the resolved
-    # SOAP strategy into ONE jitted step with per-op sharding
-    # constraints instead of per-op dispatch.  None = auto (on exactly
-    # when the run spans nodes/processes); the FF_LOWERED env knob
-    # (1/0/auto, loud ValueError on garbage) fills in when this is None.
-    lowered: Optional[bool] = None
     # Structured telemetry (observability/): step spans, phase spans,
     # throughput/MFU counters to a JSONL trace.  ``FF_TELEMETRY=1`` in
     # the environment enables it too; ``telemetry_file`` (or
@@ -325,10 +319,6 @@ class FFConfig:
                 self.sparse_host_embeddings = True
             elif a == "--no-sparse-host-embeddings":
                 self.sparse_host_embeddings = False
-            elif a == "--lowered":
-                self.lowered = True
-            elif a == "--no-lowered":
-                self.lowered = False
             elif a == "--telemetry":
                 self.telemetry = True
             elif a == "--telemetry-file":

@@ -52,7 +52,7 @@ from .ops.embedding import AggrMode, Embedding
 from .ops.linear import Linear
 from .ops.misc import (BatchNorm, Concat, Dropout, ElementBinary, ElementUnary,
                        Flat, MSELoss, Softmax)
-from .parallel.mesh import Machine
+from .parallel.mesh import Machine, dim_roles
 from .parallel.strategy import load_strategies_from_file, save_strategies_to_file
 from .runtime.profiling import span as _ff_span
 from .runtime.profiling import step_enqueue as _ff_step_enqueue
@@ -131,9 +131,6 @@ class FFModel:
         self._staged = False
         self._train_step_fn = None
         self._eval_step_fn = None
-        # Whole-graph lowering plan (parallel/lowering.GraphLowering);
-        # None = per-op dispatch.  Resolved by _compile_impl.
-        self._lowering = None
         self._fresh_jit = False  # next train-step build bypasses the
         #                          persistent compile cache (recompile)
         self._compiled = False
@@ -997,14 +994,6 @@ class FFModel:
         # overrides the pipelined ops' configs with no-split placeholders.
         self._plan_pipeline()
 
-        # Whole-graph lowering (parallel/lowering.py): resolve the knob
-        # (FFConfig.lowered > FF_LOWERED > auto-on for multi-node runs,
-        # loud on garbage) and precompute each op's logical-axis sharding
-        # spec.  None = today's per-op dispatch; the step builders below
-        # route constraints and jit through the plan when it's set.
-        from .parallel import lowering as _ff_lowering
-        self._lowering = _ff_lowering.maybe_lowering(self)
-
         # Fused Pallas optimizer kernels: on a multi-device machine each
         # parameter's update runs inside a per-leaf shard_map with its
         # own PartitionSpec (optimizers.Optimizer._shardwise) —
@@ -1454,7 +1443,7 @@ class FFModel:
                 # wire traffic.  Eval/predict (opt_in None) still sizes
                 # THIS call's pad correctly but must not inflate the
                 # train bucket (extra retrace) or the per-train-step
-                # telemetry bench.py reports.
+                # telemetry.
                 info["u_hwm"] = u
                 info["uniq_rows_total"] = info.get("uniq_rows_total", 0) + n
                 info["uniq_rows_steps"] = info.get("uniq_rows_steps", 0) + 1
@@ -1877,6 +1866,16 @@ class FFModel:
         use_pipe = (plan is not None and multi and plan["degree"] > 1)
         head_ids = ({id(op) for op in plan["head"]}
                     if use_pipe and plan.get("head") else set())
+
+        def constrain(op, ys):
+            # the op's output partition; its dims' roles pick the class of
+            # mesh axis each may take (parallel/mesh.py)
+            if not multi:
+                return ys
+            cpc = op.constraint_pc()
+            return [self.machine.constraint(y, cpc, dim_roles(op, y.ndim))
+                    for y in ys]
+
         i = 0
         while i < len(self.ops):
             if use_pipe and i == plan["i0"]:
@@ -1891,14 +1890,7 @@ class FFModel:
                         with jax.named_scope(_op_scope(hop)):
                             hys = hop.forward(
                                 params.get(hop.param_key, {}), hxs, ctx)
-                        if multi:
-                            if self._lowering is not None:
-                                hys = [self._lowering.constraint(y, hop)
-                                       for y in hys]
-                            else:
-                                hys = [self.machine.constraint(
-                                    y, hop.constraint_pc()) for y in hys]
-                        for t, y in zip(hop.outputs, hys):
+                        for t, y in zip(hop.outputs, constrain(hop, hys)):
                             env[t.guid] = y
                 # Pipelined segment: GPipe microbatch schedule over the
                 # pipe mesh axes (parallel/pipeline.py), replacing the
@@ -1929,16 +1921,7 @@ class FFModel:
                     )(pvals, tuple(xs))
                 else:
                     ys = op.forward(pvals, xs, ctx)
-            if multi:
-                if self._lowering is not None:
-                    # Whole-graph lowering: constraints come from the
-                    # logical-axis rules (sample/attribute/parameter →
-                    # mesh axis classes) instead of the raw greedy map.
-                    ys = [self._lowering.constraint(y, op) for y in ys]
-                else:
-                    cpc = op.constraint_pc()
-                    ys = [self.machine.constraint(y, cpc) for y in ys]
-            for t, y in zip(op.outputs, ys):
+            for t, y in zip(op.outputs, constrain(op, ys)):
                 env[t.guid] = y
             i += 1
         new_stats = dict(stats)
@@ -2127,12 +2110,7 @@ class FFModel:
                           new_stats, mvec, macc)
 
         step_fn = step if accum == 1 else step_accum
-        if self._lowering is not None:
-            # ONE whole-graph pjit'd step (CPU fallback = the identical
-            # jax.jit call below, so tier-1 parity is by construction).
-            fn = self._lowering.jit_step(step_fn, donate_argnums=(0, 1, 2, 6))
-        else:
-            fn = jax.jit(step_fn, donate_argnums=(0, 1, 2, 6))
+        fn = jax.jit(step_fn, donate_argnums=(0, 1, 2, 6))
         if self._memplane is not None:
             fn = self._memplane.wrap("train_step", fn)
         return fn
@@ -2152,10 +2130,7 @@ class FFModel:
             # Softmax runs whatever the metrics read
             return msum, env[probs_t.guid]
 
-        if self._lowering is not None:
-            fn = self._lowering.jit_step(estep)
-        else:
-            fn = jax.jit(estep)
+        fn = jax.jit(estep)
         if self._memplane is not None:
             fn = self._memplane.wrap("eval_step", fn)
         return fn
@@ -2705,8 +2680,7 @@ class FFModel:
                     carry0, (feed, use))
                 return outs                                   # (P+N-1, B)
 
-            run = (self._lowering.jit_step(run)
-                   if self._lowering is not None else jax.jit(run))
+            run = jax.jit(run)
             if self._memplane is not None:
                 run = self._memplane.wrap(f"generate:{B}x{P}x{N}", run)
             cache[ckey] = run
@@ -2829,8 +2803,7 @@ class FFModel:
                     carry0, (feed, use, do_exp))
                 return buf.reshape(B, K, N), scores
 
-            run = (self._lowering.jit_step(run)
-                   if self._lowering is not None else jax.jit(run))
+            run = jax.jit(run)
             if self._memplane is not None:
                 run = self._memplane.wrap(
                     f"beam_search:{B}x{P}x{N}x{K}", run)

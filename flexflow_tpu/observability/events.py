@@ -1,9 +1,7 @@
 """Structured event log: spans, counters, gauges over a JSONL sink.
 
-STDLIB-ONLY on purpose: ``bench.py`` emits phase heartbeats through this
-module before jax (or the rest of the framework) has initialized, and
-``tools/trace_report.py`` reads the records back on hosts with no
-accelerator — neither may drag in the heavy imports.
+STDLIB-ONLY on purpose: ``tools/trace_report.py`` reads the records back
+on hosts with no accelerator, and must not drag in the heavy imports.
 
 Record schema (one JSON object per line; ``ts``/``dur`` are seconds on a
 monotonic clock relative to the log's creation):

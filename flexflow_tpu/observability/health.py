@@ -21,12 +21,10 @@ PR-1 event log from a flight recorder into a live monitor:
     window; a ratio above ``FF_HEALTH_DATA_WAIT_RATIO`` warns,
   * **heartbeat file** — ``FF_HEARTBEAT_PATH`` names a JSON file
     atomically rewritten at every phase entry and step, so an external
-    watchdog (bench.py's included) can report *which phase* wedged
-    instead of a bare "killed".
+    watchdog can report *which phase* wedged instead of a bare "killed".
 
-STDLIB-ONLY on purpose, like ``events.py``: bench.py writes heartbeats
-before jax initializes, and the monitor itself touches no arrays — the
-device-side work lives in the jitted step.
+STDLIB-ONLY on purpose, like ``events.py``: the monitor itself touches no
+arrays — the device-side work lives in the jitted step.
 
 Enable with ``FF_HEALTH=1`` on top of ``FF_TELEMETRY=1``.  With
 telemetry off the monitor is never constructed and the hot path makes

@@ -7,9 +7,8 @@ gauges, and rolling-window histograms as it is written, plus a stdlib
 HTTP server exposing them as Prometheus text format at ``/metrics`` and
 expvar-style JSON at ``/debug/vars``.
 
-STDLIB-ONLY on purpose, like ``events.py``: ``bench.py`` starts the
-exporter before jax initializes, and the serving ``api.py`` mounts the
-same renderer without new dependencies.
+STDLIB-ONLY on purpose, like ``events.py``: the serving ``api.py``
+mounts the same renderer without new dependencies.
 
 Record folding:
 

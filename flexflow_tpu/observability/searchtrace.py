@@ -263,7 +263,7 @@ def per_op_attribution(model, strategies,
     ``search_report --diff`` can name the simulated cost impact (and
     the resolved sharding-spec change) of each changed op."""
     from ..config import ParallelConfig
-    from ..parallel import lowering as _lowering
+    from ..parallel import mesh as _mesh
     from ..simulator.cost_model import CostModel
     from ..simulator.machine import TPUMachineModel
 
@@ -272,10 +272,10 @@ def per_op_attribution(model, strategies,
     mm = machine_model or TPUMachineModel.calibrated(num_devices=nd)
     cm = CostModel(mm, measure=False,
                    compute_dtype=compute_dtype or model.config.compute_dtype)
-    # Pure shadow of the mesh the lowering pass would target for this
-    # device count: spec strings are derivable offline, so sidecars
-    # written by search tools carry them even when no model compiled.
-    names, sizes = _lowering.hybrid_axis_layout(
+    # Layout of the mesh a run on this device count would build: spec
+    # strings are derivable offline, so sidecars written by search tools
+    # carry them even when no model compiled.
+    names, sizes = _mesh.hybrid_axis_layout(
         nd, mm.num_hosts if nd % mm.chips_per_host == 0 else 1)
     rows: Dict[str, Dict[str, Any]] = {}
     for op in model.ops:
@@ -284,10 +284,9 @@ def per_op_attribution(model, strategies,
         pc = model._legalize_pc(op, pc) if hasattr(model, "_legalize_pc") \
             else pc
         try:
-            groups, _ = _lowering.assign_axes(
-                names, sizes, pc.dims,
-                _lowering.dim_roles(op, len(pc.dims)))
-            spec = _lowering.spec_string(groups)
+            groups, _ = _mesh.assign_axes(
+                names, sizes, pc.dims, _mesh.dim_roles(op, len(pc.dims)))
+            spec = _mesh.spec_string(groups)
         except ValueError:
             spec = "?"  # degree the mesh cannot express; advisory only
         rows[op.name] = {
@@ -326,14 +325,11 @@ def build_provenance(model, strategies, engine: str, budget: int,
         meta["best_ms"] = round(float(best_s) * 1e3, 4)
     if dp_s is not None:
         meta["dp_ms"] = round(float(dp_s) * 1e3, 4)
-    # Whole-graph lowering stamp: was this strategy compiled into ONE
-    # pjit'd step (parallel/lowering.py), and what did each op's spec
-    # resolve to (including any dcn spill the search failed to avoid)?
-    low = getattr(model, "_lowering", None)
-    meta["lowered"] = low is not None
-    if low is not None:
+    # What each compiled op's output spec resolved to on the model's own
+    # mesh (including any dcn spill the search failed to avoid).
+    if getattr(model, "machine", None) is not None:
         try:
-            meta["lowering"] = low.plan()
+            meta["lowering"] = model.machine.plan(model.ops)
         except Exception as e:  # advisory; never block export
             meta["lowering_error"] = repr(e)
     log = active_log()

@@ -22,7 +22,7 @@ it is all the work over all the time:
   * samples/s and samples/s/chip,
   * analytic-FLOP MFU, on a TPU only: train FLOPs estimated as 3x the
     graph's forward FLOPs (fwd + dgrad + wgrad — the same accounting
-    bench.py and the reference's backward multiplier use) against the
+    the reference's backward multiplier uses) against the
     published peak of the chip the step ran on
     (``simulator/machine.py`` ``DEVICE_PEAKS``, keyed by
     ``device_kind``).  On any other platform the gauge is absent.

@@ -30,7 +30,7 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding
 
-from .mesh import Machine, _prime_factors
+from .mesh import Machine, hybrid_axis_layout
 
 _initialized = False
 
@@ -115,10 +115,10 @@ def hybrid_machine(dcn_degree: Optional[int] = None,
     ``dcn_degree`` defaults to the number of processes (one slice per
     host group).  The DCN axis is the leading mesh axis named ``dcn``;
     the per-slice device count is prime-factored into ICI axes
-    ``m0, m1, ...`` exactly like the single-slice Machine, so every
-    strategy-lowering path works unchanged.  Degree composition
-    (Machine.axes_for_degrees) is greedy over leading axes first, which
-    lands the batch dim on DCN — gradient all-reduce is the only
+    ``m0, m1, ...`` exactly like the single-slice Machine
+    (``mesh.hybrid_axis_layout`` describes both).  An op output's sample
+    dim claims axes first and only it may take ``dcn`` by rule
+    (``mesh.assign_axes``), so the gradient all-reduce is the only
     DCN-crossing collective, matching how the reference maps sample-dim
     parallelism across nodes (DataParallelShardingFunctor,
     model.cc:1361-1370).
@@ -129,10 +129,7 @@ def hybrid_machine(dcn_degree: Optional[int] = None,
         dcn_degree = jax.process_count()
     if dcn_degree <= 1 or n % dcn_degree != 0:
         return Machine(devices)
-    per = n // dcn_degree
-    ici_factors = tuple(_prime_factors(per)) if per > 1 else (1,)
-    shape = (dcn_degree,) + ici_factors
-    names = ("dcn",) + tuple(f"m{i}" for i in range(len(ici_factors)))
+    names, shape = hybrid_axis_layout(n, dcn_degree)
     # Host-major device order: contiguous blocks per process so the dcn
     # axis cuts exactly on host boundaries.
     order = sorted(range(n), key=lambda i: (

@@ -27,7 +27,7 @@ CALIBRATION_PATH = os.path.join(os.path.dirname(__file__), "machine_v5e.json")
 
 # Published peaks of ONE chip, keyed by ``device_kind`` as JAX reports
 # it.  The only place a peak is written down: utilization figures
-# (bench.py, observability/stepstats.py) divide by these, and the
+# (observability/stepstats.py) divide by these, and the
 # simulator's defaults below are the v5e row.
 # Source: Google Cloud documentation, "TPU v5e" (system architecture):
 # 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s per chip.
@@ -148,30 +148,30 @@ class TPUMachineModel:
             bw = self.dcn_bandwidth
         return 2.0 * (n - 1) / n * num_bytes / bw
 
-    # -- hierarchical-mesh placement (whole-graph lowering) ----------------
+    # -- hierarchical-mesh placement ----------------------------------------
     @property
     def num_hosts(self) -> int:
         return max(1, -(-self.num_devices // self.chips_per_host))
 
     def dcn_spill(self, degrees) -> Tuple[Tuple[int, int], ...]:
-        """Non-sample dims of a partition-degree vector that the lowering
-        pass (parallel/lowering.py) would have to place on the ``dcn``
-        axis of this machine's hybrid mesh — ``((dim, dcn_share), ...)``,
-        empty on a single-host machine or when every non-sample degree
-        fits the ICI axes.  Pure shadow of ``GraphLowering``'s assignment:
-        lowering.py is jax-free at module scope precisely so the
-        simulator can ask this without an accelerator runtime."""
+        """Non-sample dims of a partition-degree vector that the executor
+        (``parallel/mesh.assign_axes``, with an op output's roles) would
+        have to place on the ``dcn`` axis of this machine's hybrid mesh —
+        ``((dim, dcn_share), ...)``, empty on a single-host machine or
+        when every non-sample degree fits the ICI axes."""
         if self.num_hosts <= 1 or self.num_devices % self.chips_per_host:
             return ()
         key = tuple(degrees)
         hit = self._spill_cache.get(key)
         if hit is not None:
             return hit
-        from ..parallel.lowering import assign_axes, hybrid_axis_layout
+        from ..parallel.mesh import (assign_axes, dim_roles,
+                                     hybrid_axis_layout)
 
         names, sizes = hybrid_axis_layout(self.num_devices, self.num_hosts)
         try:
-            _, spill = assign_axes(names, sizes, key)
+            _, spill = assign_axes(names, sizes, key,
+                                   dim_roles(None, len(key)))
         except ValueError:
             # inexpressible degrees never reach execution (legalize_pc
             # clamps first) — charge nothing rather than guess
@@ -184,7 +184,7 @@ class TPUMachineModel:
         non-sample dim crossed hosts: each spilled dim reshards the
         part's bytes over the ``dcn`` axis (ring factor), instead of the
         gradient all-reduce being the only DCN-crossing collective.
-        This is the search pressure that keeps lowered strategies
+        This is the search pressure that keeps searched strategies
         pod-shaped."""
         t = 0.0
         for _dim, share in self.dcn_spill(degrees):

@@ -432,16 +432,12 @@ def _search():
 
 
 def _perf():
-    # How much of the cost model is grounded in real measurements, and
-    # how stale is the last good bench number in the program's perf log.
+    # How much of the cost model is grounded in real measurements.
     import json as _json
-    import time as _time
 
     from ..simulator import cost_model as cm
-    from . import perf_ledger
     from .report_configs import CALIBRATION_TARGET_ENTRIES
 
-    bits = []
     fams = {}
     n_measured = 0
     try:
@@ -454,77 +450,23 @@ def _perf():
                         k.split(":", 1)[0], 0) + 1
     except (OSError, ValueError):
         pass
-    if n_measured:
-        by_fam = ", ".join(f"{k}:{fams[k]}"
-                           for k in sorted(fams, key=fams.get, reverse=True))
-        cov = n_measured / CALIBRATION_TARGET_ENTRIES
-        bits.append(f"measured cache: {n_measured} tpu entries "
-                    f"({by_fam}; {cov:.0%} of the "
-                    f"{CALIBRATION_TARGET_ENTRIES}-entry target — "
-                    "the rest costs analytically)")
-    else:
-        bits.append("measured cache: EMPTY — every op costs analytically")
-
-    lg = perf_ledger.last_good()
-    if lg:
-        age = (_time.time() - lg.get("unix_time", 0)) / 86400.0
-        bits.append(f"last good bench: {lg.get('value'):.0f} "
-                    f"{lg.get('unit', '')} @ {lg.get('commit') or '?'} "
-                    f"({age:.1f}d ago)")
-    else:
-        bits.append("last good bench: none in ledger "
-                    f"({perf_ledger.default_path()})")
-    return ", ".join(bits)
+    if not n_measured:
+        return "measured cache: EMPTY — every op costs analytically"
+    by_fam = ", ".join(f"{k}:{fams[k]}"
+                       for k in sorted(fams, key=fams.get, reverse=True))
+    cov = n_measured / CALIBRATION_TARGET_ENTRIES
+    return (f"measured cache: {n_measured} tpu entries "
+            f"({by_fam}; {cov:.0%} of the "
+            f"{CALIBRATION_TARGET_ENTRIES}-entry target — "
+            "the rest costs analytically)")
 
 
-def _lowering_check():
-    # Whole-graph lowering (parallel/lowering.py): loud FF_LOWERED parse,
-    # a probe-lower of a tiny seeded model (bitwise against per-op
-    # dispatch), and a WARN whenever a strategy would put
-    # a non-sample dim on the hybrid mesh's ``dcn`` axis — the placement
-    # the search's DCN surcharge exists to prevent.
-    import jax
-    import numpy as np
-
-    import flexflow_tpu as ff
-    from ..parallel import lowering as low
-
-    env = low.lowered_from_env()  # ValueError on garbage — required-loud
-    eff = low.resolve_lowered(None, 1, jax.process_count())
-    bits = [f"FF_LOWERED={'auto' if env is None else env} "
-            f"(effective {'on' if eff else 'off'} on this host)"]
-
-    def probe(flag):
-        cfg = ff.FFConfig(batch_size=8, lowered=flag)
-        m = ff.FFModel(cfg)
-        inp = m.create_tensor((8, 8), nchw=False, name="x")
-        t = m.dense(inp, 16, activation="relu", name="fc1")
-        t = m.dense(t, 4, name="fc2")
-        m.softmax(t, name="sm")
-        m.compile(ff.SGDOptimizer(lr=0.1), "sparse_categorical_crossentropy",
-                  ["accuracy"])
-        m.init_layers(seed=0)
-        rng = np.random.default_rng(1)
-        x = rng.standard_normal((8, 8), dtype=np.float32)
-        y = rng.integers(0, 4, size=(8, 1), dtype=np.int32)
-        m.set_batch({inp: x}, y)
-        m.train_iteration()
-        m.sync()
-        return m
-
-    ml = probe(True)
-    assert ml._lowering is not None, "probe model did not lower"
-    md = probe(False)
-    a = np.asarray(jax.device_get(ml.get_parameter("fc2", "kernel")))
-    b = np.asarray(jax.device_get(md.get_parameter("fc2", "kernel")))
-    assert np.array_equal(a, b), "lowered probe diverged from dispatch"
-    bits.append("probe-lower: 1-step train bitwise == per-op dispatch")
-    spill = ml._lowering.dcn_spill
-    if spill:
-        bits.append(f"WARN: dcn axis carries non-sample dims here: {spill}")
-
-    # Shipped strategies audited against the pod-shaped mesh shadow for
-    # their recorded device count (2+ hosts at 8 chips/host).
+def _placement():
+    # Shipped strategies against the pod-shaped mesh layout for their
+    # recorded device count (2+ hosts at 8 chips/host): a WARN whenever
+    # one would put a non-sample dim on the ``dcn`` axis
+    # (docs/lowering.md) — the placement the search's DCN surcharge
+    # exists to prevent.
     from ..parallel.strategy import (DEFAULT_STRATEGY_DIR,
                                      load_strategies_from_file,
                                      read_provenance)
@@ -549,12 +491,10 @@ def _lowering_check():
             if spilled:
                 warns.append(f"{fn}: {', '.join(spilled)}")
     if warns:
-        bits.append("WARN: non-sample dims would land on the dcn axis "
-                    "(a lowered pod run reshards these over DCN every "
-                    "step): " + "; ".join(warns))
-    else:
-        bits.append("shipped strategies: no non-sample dcn placement")
-    return ", ".join(bits)
+        return ("WARN: non-sample dims would land on the dcn axis (a pod "
+                "run reshards these over DCN every step): "
+                + "; ".join(warns))
+    return "shipped strategies: no non-sample dcn placement"
 
 
 def _train():
@@ -603,7 +543,7 @@ def main(argv: Optional[List[str]] = None) -> int:
              ("reconfiguration", _reconfiguration, False),
              ("serving", _serving, False),
              ("autoscaler", _autoscaler, False),
-             ("lowering", _lowering_check, False),
+             ("placement", _placement, False),
              ("training", _train, True)]
 
     # print each line as its check completes — the slow checks (the

@@ -307,16 +307,6 @@ def render_report(records: List[Dict[str, Any]]) -> str:
                          f"{a.get('error', '?')})")
         lines.append("")
 
-    # ---- heartbeat / phases -------------------------------------------
-    bench = events.get("bench_phase", [])
-    if bench:
-        last = bench[-1]
-        lines.append("## Last phase")
-        lines.append("")
-        lines.append(f"- bench phase `{last.get('attrs', {}).get('phase', '?')}`"
-                     f" at ts {float(last.get('ts', 0.0)):.2f} s")
-        lines.append("")
-
     return "\n".join(lines)
 
 

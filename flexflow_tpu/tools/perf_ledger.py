@@ -6,9 +6,6 @@ measures each PR, and no code here reads or writes it.  This log is
 ``ff_perf_log.jsonl`` at the checkout root (git-ignored), or wherever
 ``FF_PERF_LEDGER`` points.
 
-* ``bench.py`` appends one entry per emitted result — measured numbers,
-  failed runs and watchdog kills alike — so a run that died still leaves
-  a record of *what died where*.
 * ``calibrate.py`` appends one entry per measurement/fit session;
   ``search_bench`` and ``fleet_bench`` append their host-side metrics.
 * ``report`` renders the trajectory with regression detection: each
@@ -19,8 +16,7 @@ measures each PR, and no code here reads or writes it.  This log is
 Entries are one JSON object per line.  Appends are crash-tolerant: if a
 previous writer died mid-line, the next append starts on a fresh line so
 one truncated record never poisons the file (readers skip unparseable
-lines).  Stdlib-only — bench.py loads this module by file path *before*
-jax is importable.
+lines).  Stdlib-only.
 
 Entry fields (``schema`` 1):
     kind        "bench" | "calibration"

@@ -41,8 +41,7 @@ REPORT_DEVICES = {
     "inception": 8,
 }
 
-# single-chip bench config (bench.py's AlexNet phase) — also the
-# simulated-vs-measured agreement config
+# single-chip AlexNet batch of the simulated-vs-measured agreement check
 BENCH_SINGLE_CHIP_BATCH = 256
 
 # Compute dtype the committed reports (and their measured-cache keys /

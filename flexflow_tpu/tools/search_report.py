@@ -190,8 +190,8 @@ def _op_ms(meta: Optional[Dict[str, Any]], op: str) -> Optional[float]:
 
 def _op_spec(meta: Optional[Dict[str, Any]], op: str) -> Optional[str]:
     """Resolved sharding spec for an op: the attribution row's ``spec``
-    (stamped by every new sidecar), else the lowering plan's entry when
-    the sidecar came from a lowered compile."""
+    (stamped by every new sidecar), else the compiled model's plan
+    (``Machine.plan``) where the sidecar has one."""
     for section in ("ops", "lowering"):
         rows = (meta or {}).get(section)
         if isinstance(rows, dict) and isinstance(rows.get(op), dict):
@@ -468,8 +468,6 @@ def render_diff(a_path: str, b_path: str) -> str:
                 bits.append(f"{key} {meta[key]}")
         if "best_ms" in meta:
             bits.append(f"best {_ms(meta['best_ms'])} ms")
-        if meta.get("lowered"):
-            bits.append("lowered")
         lines.append("- " + " · ".join(bits))
     lines.append("")
 
@@ -522,7 +520,7 @@ def render_diff(a_path: str, b_path: str) -> str:
                 spec_rows.append((op, sa or "—", sb or "—"))
         if spec_rows:
             lines.append("")
-            lines.append("## Sharding-spec changes (lowered mesh axes)")
+            lines.append("## Sharding-spec changes (mesh axes)")
             lines.append("")
             for op, sa, sb in spec_rows:
                 lines.append(f"- {op}: `{sa}` -> `{sb}`")

@@ -46,8 +46,8 @@ def main(argv: Optional[List[str]] = None):
     p.add_argument("--export", default=None)
     p.add_argument("--out", default="REPORT_SOAP.md")
     p.add_argument("--measured-single-chip-ms", type=float, default=None,
-                   help="wall-clock ms/step for the single-chip bench "
-                        "config (bench.py), for the agreement check")
+                   help="wall-clock ms/step of the single-chip config on "
+                        "the chip, for the agreement check")
     from .report_configs import BENCH_SINGLE_CHIP_BATCH
 
     p.add_argument("--single-chip-batch", type=int,

@@ -254,18 +254,6 @@ def render_report(records: List[Dict[str, Any]], top_k: int = 8) -> str:
                              f"(t={float(e.get('ts', 0.0)):.2f}s)")
             lines.append("")
 
-    # ---- bench phases -------------------------------------------------
-    bench = events.get("bench_phase", [])
-    if bench:
-        lines.append("## Bench phases")
-        lines.append("")
-        lines.append("| phase | ts s |")
-        lines.append("|---|---|")
-        for e in bench:
-            lines.append(f"| {e.get('attrs', {}).get('phase', '?')} | "
-                         f"{float(e.get('ts', 0.0)):.2f} |")
-        lines.append("")
-
     # ---- search progress ----------------------------------------------
     prog = events.get("search_progress", [])
     if prog:

@@ -1,7 +1,7 @@
 """Where compiled programs are kept between processes.
 
-One rule, applied by every entry point (chip_smoke.py, bench.py, the
-example drivers, tests/conftest.py): where ``JAX_COMPILATION_CACHE_DIR``
+One rule, applied by every entry point (chip_smoke.py, benchmark/run.py,
+the example drivers, tests/conftest.py): where ``JAX_COMPILATION_CACHE_DIR``
 is set, JAX reads it itself and nothing here sets another directory;
 where it is not, the cache is ``<checkout>/.jax_cache`` (git-ignored).
 The path is part of every cache key, so it is fixed — never a temporary

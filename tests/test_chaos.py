@@ -12,9 +12,11 @@ checkpoint write is retried and never leaves a partial file.
 import glob
 import json
 import os
+import select
 import signal
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -315,9 +317,33 @@ rng = np.random.default_rng(3)
 x = rng.standard_normal((48, 8), dtype=np.float32)
 y = rng.integers(0, 4, size=(48, 1), dtype=np.int32)
 dl = ff.DataLoader(m, {{inp: x}}, y, seed=5)
-print("READY", flush=True)
-elastic_train(m, dl, epochs=40, checkpoint_dir={ckpt!r})
+
+
+def first_epoch_done(epoch, _metrics):
+    # one line once the first steps have run; the parent kills on it, so
+    # the epochs below only have to outlast the parent
+    if epoch == 0:
+        print("STEPPED", flush=True)
+
+
+elastic_train(m, dl, epochs=1_000_000, checkpoint_dir={ckpt!r},
+              on_epoch=first_epoch_done)
 """
+
+
+def _wait_for(proc, token: bytes, timeout: float) -> None:
+    """Read the child's stdout until ``token``; fail after ``timeout`` s
+    or when the child exits first."""
+    fd = proc.stdout.fileno()
+    seen = b""
+    deadline = time.monotonic() + timeout
+    while token not in seen:
+        left = deadline - time.monotonic()
+        assert left > 0 and select.select([fd], [], [], left)[0], \
+            f"child printed {seen!r}, not {token!r}, in {timeout} s"
+        chunk = os.read(fd, 4096)
+        assert chunk, f"child exited (rc {proc.wait()}) before {token!r}"
+        seen += chunk
 
 
 def test_kill_term_subprocess_then_rerun_matches_uninterrupted(
@@ -334,13 +360,15 @@ def test_kill_term_subprocess_then_rerun_matches_uninterrupted(
         env.pop(k, None)
     code = _CHILD.format(root=root, ckpt=ckpt)
     proc = subprocess.Popen([sys.executable, "-c", code], env=env,
-                            stdout=subprocess.PIPE, text=True)
-    assert proc.stdout.readline().strip() == "READY"
-    # mid-epoch: give it time to get a few steps in, then kill
-    import time
-    time.sleep(3.0)
-    proc.send_signal(signal.SIGTERM)
-    assert proc.wait(timeout=120) == 0  # clean exit after the save
+                            stdout=subprocess.PIPE)
+    try:
+        # the handler is installed and steps have run: kill mid-training
+        _wait_for(proc, b"STEPPED", timeout=300)
+        proc.send_signal(signal.SIGTERM)
+        assert proc.wait(timeout=120) == 0  # clean exit after the save
+    finally:
+        proc.kill()
+        proc.wait()
 
     meta = resilience.read_resume_meta(ckpt)
     assert meta is not None and meta["step"] > 0

@@ -1,6 +1,6 @@
-"""The device boundary, checked on the CPU: chip_smoke.py and bench.py
-refuse to run without a TPU, the smoke's tiny mode runs every phase
-function, and the rules they rest on — one compile-cache directory, no
+"""The device boundary, checked on the CPU: chip_smoke.py refuses to run
+without a TPU, its tiny mode runs every phase function, and the rules it
+rests on — one compile-cache directory, no
 silent device-count clamp, one sourced peaks table, explicit kernel
 interpretation — hold."""
 
@@ -136,21 +136,6 @@ def test_smoke_rejects_an_unknown_phase():
     r = _run([os.path.join(ROOT, "chip_smoke.py"), "--cpu-tiny",
               "--phases", "alexnet,warp"])
     assert r.returncode != 0 and "unknown phase" in r.stderr
-
-
-def test_bench_without_tpu_fails_with_a_parseable_line(tmp_path):
-    env = {"FF_PERF_LEDGER": str(tmp_path / "log.jsonl"),
-           "FF_BENCH_EXTRA_PATH": str(tmp_path / "extra.json"),
-           "FF_HEARTBEAT_PATH": str(tmp_path / "hb.json")}
-    r = _run([os.path.join(ROOT, "bench.py")], env=env, cwd=str(tmp_path))
-    assert r.returncode != 0
-    last = json.loads(r.stdout.strip().splitlines()[-1])
-    assert last["value"] is None and "mfu" not in last
-    assert "no TPU" in last["error"]
-    assert last["device"] == {"platform": "cpu", "kind": "cpu", "count": 8}
-    # the failure is in the program's own log, under the CPU's name
-    entry = json.loads((tmp_path / "log.jsonl").read_text().splitlines()[-1])
-    assert entry["status"] == "error" and entry["backend"] == "cpu"
 
 
 # ---------------------------------------------------------------------------

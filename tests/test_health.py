@@ -313,10 +313,6 @@ def synthetic_records():
     recs.append({"t": "event", "name": "op_runtime_pass", "ts": 3.6,
                  "attrs": {"step": 4, "ops_measured": 2, "ops_total": 6,
                            "elapsed_s": 0.42}})
-    recs.append({"t": "event", "name": "bench_phase", "ts": 0.0,
-                 "attrs": {"phase": "preflight"}})
-    recs.append({"t": "event", "name": "bench_phase", "ts": 1.9,
-                 "attrs": {"phase": "alexnet"}})
     return recs
 
 
@@ -334,8 +330,7 @@ def test_report_sections(tmp_path):
     for section in ["## Health findings", "## Step health",
                     "## Data pipeline",
                     "## Simulator agreement (predicted vs measured)",
-                    "## Op runtime (in-training attribution)",
-                    "## Last phase"]:
+                    "## Op runtime (in-training attribution)"]:
         assert section in report, f"missing {section}"
     # agreement rows carry both sides' provenance
     assert "| measured | standalone |" in report

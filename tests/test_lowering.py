@@ -1,180 +1,164 @@
-"""Whole-graph lowering (parallel/lowering.py).
+"""Partition degrees → mesh axes (parallel/mesh.py, docs/lowering.md).
 
-Contract under test: compiling a resolved SOAP strategy into ONE jitted
-step with per-op ``with_sharding_constraint``s must be *bitwise*
-identical to the per-op dispatch path — strategy changes placement, not
-math, and on the CPU test mesh the lowered constraints must degenerate
-to exactly ``Machine.axes_for_degrees``'s assignment.  Also pinned here:
-the loud FF_LOWERED knob, the CPU pjit fallback, one-compile-per-step-fn
-through the memplane ledger, the provenance sidecar's lowering stamp,
-and the DCN surcharge that keeps searched strategies from putting
-parameter dims on the cross-host axis.
+Contract under test: there is one walk from an op's partition degrees to
+mesh-axis groups.  With an op's roles it keeps non-sample dims off the
+``dcn`` axis of a hybrid mesh; without roles, and on every mesh that has
+no ``dcn`` axis, it is the plain greedy walk ``Machine`` always had — kept
+below as the reference.  Also pinned here: one compile per step function
+through the memplane ledger, the provenance sidecar's plan stamp, and the
+DCN surcharge that keeps searched strategies from putting parameter dims
+on the cross-host axis.
 """
 
 import json
 import os
-import subprocess
-import sys
+import re
 
 import numpy as np
 import pytest
 
-import jax
 from jax.sharding import PartitionSpec
 
 import flexflow_tpu as ff
-from flexflow_tpu.parallel import lowering as low
+from flexflow_tpu.parallel import mesh
+from flexflow_tpu.parallel.distributed import hybrid_machine
 from flexflow_tpu.parallel.mesh import Machine
+from flexflow_tpu.parallel.strategy import load_strategies_from_file
 from flexflow_tpu.simulator.machine import TPUMachineModel
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run16(code: str, timeout=900):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
-    prologue = "import jax; jax.config.update('jax_platforms','cpu')\n"
-    return subprocess.run([sys.executable, "-c", prologue + code],
-                          cwd=_ROOT, env=env, timeout=timeout,
-                          capture_output=True, text=True)
+# ---------------------------------------------------------------------------
+# the reference: the greedy walk as Machine.axes_for_degrees had it before
+# roles, and the spec Machine.spec_for_config built from it
+# ---------------------------------------------------------------------------
+
+def _greedy(axis_names, axis_sizes, degrees):
+    remaining = list(zip(axis_names, axis_sizes))
+    result = []
+    for deg in degrees:
+        group = []
+        need = deg
+        for i in range(len(remaining)):
+            name, size = remaining[i]
+            if name is None:
+                continue
+            if need % size == 0:
+                group.append(name)
+                need //= size
+                remaining[i] = (None, 0)
+                if need == 1:
+                    break
+        if need != 1:
+            raise ValueError(f"degree {deg} of {list(degrees)} does not fit")
+        result.append(tuple(group))
+    return result
+
+
+def _greedy_spec(mach, degrees, rank=None):
+    degrees = list(degrees)
+    if rank is not None:
+        degrees = (degrees + [1] * (rank - len(degrees)))[:rank]
+    groups = _greedy(mach.axis_names, mach.axis_sizes, degrees)
+    entries = [g if len(g) > 1 else (g[0] if g else None) for g in groups]
+    while entries and entries[-1] is None:
+        entries.pop()
+    return PartitionSpec(*entries)
+
+
+def _layout_machine(num_devices, num_hosts=1):
+    """A Machine that knows its axes and holds no devices: what
+    ``spec_for_config`` reads, for layouts wider than the test mesh."""
+    mach = Machine.__new__(Machine)
+    mach.axis_names, mach.axis_sizes = mesh.hybrid_axis_layout(
+        num_devices, num_hosts)
+    return mach
 
 
 # ---------------------------------------------------------------------------
-# pure helpers: layout shadow, role-aware assignment vs the mesh greedy
+# pure helpers: the layout, the walk against the reference
 # ---------------------------------------------------------------------------
 
 def test_hybrid_axis_layout_shadow():
     # 2-host v5e slice: dcn leads, ICI axes are the per-host factorization
-    assert low.hybrid_axis_layout(16, 2) == (("dcn", "m0", "m1", "m2"),
-                                             (2, 2, 2, 2))
+    assert mesh.hybrid_axis_layout(16, 2) == (("dcn", "m0", "m1", "m2"),
+                                              (2, 2, 2, 2))
     # single host: plain prime-factored mesh, larger factors first
-    assert low.hybrid_axis_layout(8, 1) == (("m0", "m1", "m2"), (2, 2, 2))
-    assert low.hybrid_axis_layout(12, 1) == (("m0", "m1", "m2"), (3, 2, 2))
+    assert mesh.hybrid_axis_layout(8, 1) == (("m0", "m1", "m2"), (2, 2, 2))
+    assert mesh.hybrid_axis_layout(12) == (("m0", "m1", "m2"), (3, 2, 2))
     # host count that does not divide the device count: no dcn axis
-    assert low.hybrid_axis_layout(12, 5)[0][0] != "dcn"
-    assert low.hybrid_axis_layout(1, 1) == (("m0",), (1,))
+    assert mesh.hybrid_axis_layout(12, 5)[0][0] != "dcn"
+    assert mesh.hybrid_axis_layout(1, 1) == (("m0",), (1,))
 
 
-def test_assign_axes_matches_machine_greedy(devices):
-    """On a non-hybrid mesh (no dcn axis — this one) the role-aware
-    assignment must be step-for-step the Machine greedy: the bitwise
-    anchor for lowered-vs-dispatch parity on every CPU test."""
+@pytest.mark.parametrize("degs", [
+    (8, 1), (1, 8), (2, 4), (4, 2), (2, 2, 2), (4, 1, 2, 1), (1, 1), (8,),
+    (2, 1, 2, 2), (1, 4, 2),
+    (3,), (2, 8),  # inexpressible on 2x2x2: both sides refuse
+], ids=lambda d: "x".join(map(str, d)))
+def test_assign_axes_matches_machine_greedy(devices, degs):
+    """On a mesh without a dcn axis (this one) the walk is the reference
+    greedy, with an op's roles and without: the constraints of every
+    strategy the CPU tests and the benchmark's cells compile."""
     mach = Machine(devices)
-    sweep = [(8, 1), (1, 8), (2, 4), (4, 2), (2, 2, 2), (4, 1, 2, 1),
-             (1, 1), (8,), (2, 1, 2, 2), (1, 4, 2)]
-    for degs in sweep:
-        groups, spill = low.assign_axes(mach.axis_names, mach.axis_sizes,
-                                        degs)
-        assert spill == (), (degs, spill)
-        assert [tuple(g) for g in groups] == \
-            [tuple(g) for g in mach.axes_for_degrees(degs)], degs
-        assert PartitionSpec(*low.spec_entries(groups)) == \
-            mach.spec_for_config(ff.ParallelConfig(dims=degs)), degs
-    # inexpressible degree: same refusal, same message shape
-    with pytest.raises(ValueError, match="not expressible"):
-        low.assign_axes(mach.axis_names, mach.axis_sizes, (3,))
-    with pytest.raises(ValueError):
-        mach.axes_for_degrees([3])
+    roles = mesh.dim_roles(None, len(degs))
+    try:
+        want = _greedy(mach.axis_names, mach.axis_sizes, degs)
+    except ValueError:
+        for r in (None, roles):
+            with pytest.raises(ValueError, match="not expressible"):
+                mach.axes_for_degrees(degs, r)
+        return
+    pc = ff.ParallelConfig(dims=degs)
+    for r in (None, roles):
+        assert mach.axes_for_degrees(degs, r) == want, r
+        assert mach.spec_for_config(pc, roles=r) == \
+            _greedy_spec(mach, degs), r
+        # a rank the config does not have: padded and truncated alike
+        for rank in (1, len(degs) + 1):
+            assert mach.spec_for_config(pc, rank=rank, roles=r) == \
+                _greedy_spec(mach, degs, rank), (r, rank)
+    _, spill = mesh.assign_axes(mach.axis_names, mach.axis_sizes, degs,
+                                roles)
+    assert spill == ()
 
 
 def test_assign_axes_dcn_rules():
-    """On the hybrid 16-dev/2-host shadow: batch takes dcn first; a
+    """On the hybrid 16-dev/2-host layout: batch takes dcn first; a
     non-sample degree stays on ICI when it can and spills (recorded)
     only when inexpressible intra-host."""
-    names, sizes = low.hybrid_axis_layout(16, 2)
+    names, sizes = mesh.hybrid_axis_layout(16, 2)
+
+    def walk(degs):
+        return mesh.assign_axes(names, sizes, degs,
+                                mesh.dim_roles(None, len(degs)))
+
     # pure DP: batch spans everything, never a spill
-    groups, spill = low.assign_axes(names, sizes, (16, 1))
+    groups, spill = walk((16, 1))
     assert groups[0][0] == "dcn" and spill == ()
     # dp2 x tp8: batch on dcn, the whole TP split stays intra-host
-    groups, spill = low.assign_axes(names, sizes, (2, 8))
+    groups, spill = walk((2, 8))
     assert groups == [("dcn",), ("m0", "m1", "m2")] and spill == ()
     # tp16: the parameter dim MUST take dcn to reach 16 — recorded
-    groups, spill = low.assign_axes(names, sizes, (1, 16))
+    groups, spill = walk((1, 16))
     assert "dcn" in groups[1]
     assert spill == ((1, 2),)
     # model parallel 4x4: splits share dcn+ici without spilling sample
-    groups, spill = low.assign_axes(names, sizes, (4, 4))
+    groups, spill = walk((4, 4))
     assert spill == () and groups[0][0] == "dcn"
 
 
 def test_spec_string_rendering():
-    assert low.spec_string([("m0", "m1"), (), ("m2",)]) == \
+    assert mesh.spec_string([("m0", "m1"), (), ("m2",)]) == \
         "('m0','m1'), None, 'm2'"
-    assert low.spec_string([(), ()]) == "replicated"
-    assert low.spec_string([("dcn",), ("m0",)]) == "'dcn', 'm0'"
+    assert mesh.spec_string([(), ()]) == "replicated"
+    assert mesh.spec_string([("dcn",), ("m0",)]) == "'dcn', 'm0'"
 
 
 # ---------------------------------------------------------------------------
-# the knob: loud parse, precedence, compile()-time refusal
-# ---------------------------------------------------------------------------
-
-def test_lowered_env_knob_is_loud(monkeypatch):
-    for raw, want in [("1", True), ("true", True), ("ON", True),
-                      ("yes", True), ("0", False), ("False", False),
-                      ("off", False), ("no", False), ("", None),
-                      ("auto", None)]:
-        monkeypatch.setenv("FF_LOWERED", raw)
-        assert low.lowered_from_env() is want, raw
-    monkeypatch.delenv("FF_LOWERED")
-    assert low.lowered_from_env() is None
-    monkeypatch.setenv("FF_LOWERED", "banana")
-    with pytest.raises(ValueError, match="FF_LOWERED"):
-        low.lowered_from_env()
-
-
-def test_resolve_lowered_precedence(monkeypatch):
-    monkeypatch.delenv("FF_LOWERED", raising=False)
-    # auto: on exactly when the run spans nodes/processes
-    assert low.resolve_lowered(None, 1, 1) is False
-    assert low.resolve_lowered(None, 2, 1) is True
-    assert low.resolve_lowered(None, 1, 4) is True
-    # explicit config wins over auto and over the env
-    monkeypatch.setenv("FF_LOWERED", "1")
-    assert low.resolve_lowered(False, 2, 4) is False
-    assert low.resolve_lowered(None, 1, 1) is True
-    monkeypatch.setenv("FF_LOWERED", "0")
-    assert low.resolve_lowered(True, 1, 1) is True
-    assert low.resolve_lowered(None, 2, 1) is False
-    # non-bool config values refuse loudly (a truthy "no" would flip it)
-    with pytest.raises(ValueError, match="FFConfig.lowered"):
-        low.resolve_lowered("yes", 1, 1)
-
-
-def test_compile_refuses_garbage_env(devices, monkeypatch):
-    monkeypatch.setenv("FF_LOWERED", "banana")
-    m, _ = _tiny_dense()
-    with pytest.raises(ValueError, match="FF_LOWERED"):
-        m.compile(ff.SGDOptimizer(lr=0.1),
-                  "sparse_categorical_crossentropy", ["accuracy"])
-
-
-def test_cli_flags_set_config():
-    cfg = ff.FFConfig(batch_size=8)
-    cfg.parse_args(["--lowered"])
-    assert cfg.lowered is True
-    cfg.parse_args(["--no-lowered"])
-    assert cfg.lowered is False
-
-
-# ---------------------------------------------------------------------------
-# the pjit wrapper: CPU fallback is plain jit
-# ---------------------------------------------------------------------------
-
-def test_pjit_cpu_fallback(devices):
-    fn = low.pjit_with_cpu_fallback(lambda x: x * 2.0)
-    x = np.arange(8, dtype=np.float32)
-    np.testing.assert_array_equal(np.asarray(fn(x)), x * 2.0)
-    # explicit shardings are dropped on CPU, not passed to jit
-    mach = Machine(jax.devices())
-    from jax.sharding import NamedSharding
-    sh = NamedSharding(mach.mesh, PartitionSpec())
-    fn2 = low.pjit_with_cpu_fallback(lambda x: x + 1.0, in_shardings=(sh,),
-                                     out_shardings=sh)
-    np.testing.assert_array_equal(np.asarray(fn2(x)), x + 1.0)
-
-
-# ---------------------------------------------------------------------------
-# bitwise parity: lowered step == per-op dispatch
+# every op of the strategy sets the repo ships and tests: the output
+# constraint through Machine, with the op's roles, is the reference's spec
 # ---------------------------------------------------------------------------
 
 HYBRID = {
@@ -186,102 +170,160 @@ HYBRID = {
     "softmax1": ff.ParallelConfig(dims=(8, 1)),
 }
 
+TRANSFORMER_TP = {
+    "attn_0": ff.ParallelConfig(dims=(2, 1, 4)),
+    "mlp_up_0": ff.ParallelConfig(dims=(2, 4)),
+    "mlp_down_0": ff.ParallelConfig(dims=(2, 1)),
+    "lm_head": ff.ParallelConfig(dims=(2, 1, 4)),
+    "softmax": ff.ParallelConfig(dims=(8, 1, 1)),
+}
 
-def _tiny_dense(batch=16, lowered=None):
-    cfg = ff.FFConfig(batch_size=batch, compute_dtype="float32",
-                      lowered=lowered)
+
+def _hybrid_graph(cfg):
+    m = ff.FFModel(cfg)
+    inp = m.create_tensor((16, 3, 12, 12))
+    t = m.conv2d(inp, 8, 3, 3, 1, 1, 1, 1, name="conv1")
+    t = m.pool2d(t, 2, 2, 2, 2, 0, 0, name="pool1")
+    t = m.flat(t, name="flat1")
+    t = m.dense(t, 32, name="fc1")
+    t = m.dense(t, 10, name="fc2")
+    m.softmax(t, name="softmax1")
+    return m
+
+
+def _transformer_graph(cfg):
+    from flexflow_tpu.models.transformer import build_transformer
+
+    m = ff.FFModel(cfg)
+    build_transformer(m, 8, seq_length=8, num_layers=1, embed_dim=32,
+                      num_heads=4, vocab_size=64)
+    return m
+
+
+def _alexnet_graph(cfg):
+    from flexflow_tpu.models.alexnet import build_alexnet
+
+    m = ff.FFModel(cfg)
+    build_alexnet(m, 64)
+    return m
+
+
+def _dlrm_graph(cfg):
+    from flexflow_tpu.models.dlrm import build_dlrm
+
+    m = ff.FFModel(cfg)
+    build_dlrm(m, 64, embedding_sizes=[1000] * 8)
+    return m
+
+
+def _nmt_graph(cfg):
+    from flexflow_tpu.models.nmt import build_nmt
+
+    m = ff.FFModel(cfg)
+    build_nmt(m, 64)
+    return m
+
+
+def _shipped(name):
+    return load_strategies_from_file(
+        os.path.join(_ROOT, "strategies", name))
+
+
+@pytest.mark.parametrize("graph,strategies,num_devices", [
+    (_hybrid_graph, lambda: HYBRID, 8),
+    (_transformer_graph, lambda: TRANSFORMER_TP, 8),
+    (_alexnet_graph, lambda: _shipped("alexnet_16.pb"), 16),
+    (_dlrm_graph, lambda: _shipped("dlrm_16.pb"), 16),
+    (_nmt_graph, lambda: _shipped("nmt_16.pb"), 16),
+], ids=["hybrid", "transformer_tp", "alexnet_16", "dlrm_16", "nmt_16"])
+def test_op_constraints_match_reference(graph, strategies, num_devices):
+    strategies = strategies()
+    batch = 64 if num_devices == 16 else 16
+    m = graph(ff.FFConfig(batch_size=batch))
+    assert set(strategies) <= {op.name for op in m.ops}
+    mach = _layout_machine(num_devices)
+    split = 0
+    for op in m.ops:
+        rank = op.output.num_dims
+        pc = op.legalize_pc(strategies.get(op.name) or
+                            ff.ParallelConfig.data_parallel(rank,
+                                                            num_devices))
+        got = mach.spec_for_config(pc, rank=rank,
+                                   roles=mesh.dim_roles(op, rank))
+        assert got == _greedy_spec(mach, pc.dims, rank), (op.name, pc.dims)
+        split += any(d > 1 for d in pc.dims[1:])
+    assert split  # the set does split a dim that is not the sample's
+
+
+# ---------------------------------------------------------------------------
+# a dcn mesh under one process: roles keep a parameter dim on ICI, and a
+# caller without roles (weights, batches) gets the plain walk
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def dcn_model(devices):
+    mach = hybrid_machine(dcn_degree=2, devices=devices)
+    assert mach.axis_names == ("dcn", "m0", "m1")
+    cfg = ff.FFConfig(batch_size=16, compute_dtype="float32",
+                      strategies={"fc1": ff.ParallelConfig(dims=(1, 4))})
+    m = ff.FFModel(cfg)
+    inp = m.create_tensor((16, 8), nchw=False)
+    t = m.dense(inp, 32, activation=ff.ActiMode.RELU, name="fc1")
+    m.softmax(m.dense(t, 4, name="fc2"), name="sm")
+    m.compile(ff.SGDOptimizer(lr=0.1), "sparse_categorical_crossentropy",
+              ["accuracy"], machine=mach)
+    m.init_layers(seed=0)
+    rng = np.random.default_rng(0)
+    m.set_batch({inp: rng.standard_normal((16, 8), np.float32)},
+                rng.integers(0, 4, (16, 1), dtype=np.int32))
+    return m
+
+
+def test_dcn_mesh_parameter_dim_stays_on_ici(dcn_model):
+    m = dcn_model
+    # the plain walk would hand fc1's out dim the leading axes, dcn first
+    assert _greedy(m.machine.axis_names, m.machine.axis_sizes, (1, 4)) == \
+        [(), ("dcn", "m0")]
+    hlo = m.train_step_hlo()
+    fc1 = re.findall(r"sdy\.sharding_constraint \S+ <@mesh, \[(.*?)\]> : "
+                     r"tensor<16x32xf32>", hlo)
+    assert fc1 and set(fc1) == {'{}, {"m0", "m1"}'}, fc1
+    plan = m.machine.plan(m.ops)
+    assert plan["fc1"] == {"spec": "None, ('m0','m1')", "roles": "sp"}
+    # the batch is the one dim on dcn
+    assert plan["fc2"]["spec"].startswith("('dcn',")
+    # and the step runs with the kernel and its output on different axes
+    m.train_iteration()
+    m.sync()
+    m.get_metrics()
+    assert np.isfinite(m.last_loss)
+
+
+def test_dcn_mesh_no_roles_is_the_plain_walk(dcn_model):
+    mach = dcn_model.machine
+    for degs in [(1, 4), (1, 2), (2, 4), (4, 2), (8,), (1, 8)]:
+        assert mach.axes_for_degrees(degs) == \
+            _greedy(mach.axis_names, mach.axis_sizes, degs), degs
+        assert mach.spec_for_config(ff.ParallelConfig(dims=degs)) == \
+            _greedy_spec(mach, degs), degs
+    # fc1's kernel is mapped without roles: its out dim takes dcn first
+    kernel = dcn_model._params["fc1"]["kernel"]
+    assert kernel.sharding.spec == PartitionSpec(None, ("dcn", "m0"))
+    assert mach.batch_sharding(8).spec == PartitionSpec(("dcn", "m0", "m1"))
+
+
+# ---------------------------------------------------------------------------
+# exactly one trace+compile per step function (memplane ledger)
+# ---------------------------------------------------------------------------
+
+def _tiny_dense(batch=16):
+    cfg = ff.FFConfig(batch_size=batch, compute_dtype="float32")
     m = ff.FFModel(cfg)
     inp = m.create_tensor((batch, 8), nchw=False)
     t = m.dense(inp, 16, activation=ff.ActiMode.RELU, name="fc1")
     m.softmax(m.dense(t, 4, name="fc2"), name="sm")
     return m, inp
 
-
-def _train_hybrid(lowered, batch=16, steps=4, seed=3):
-    cfg = ff.FFConfig(batch_size=batch, strategies=dict(HYBRID),
-                      lowered=lowered)
-    m = ff.FFModel(cfg)
-    inp = m.create_tensor((batch, 3, 12, 12))
-    t = m.conv2d(inp, 8, 3, 3, 1, 1, 1, 1, activation=ff.ActiMode.RELU,
-                 name="conv1")
-    t = m.pool2d(t, 2, 2, 2, 2, 0, 0, name="pool1")
-    t = m.flat(t, name="flat1")
-    t = m.dense(t, 32, activation=ff.ActiMode.RELU, name="fc1")
-    t = m.dense(t, 10, name="fc2")
-    m.softmax(t, name="softmax1")
-    m.compile(ff.SGDOptimizer(lr=0.1), "sparse_categorical_crossentropy",
-              ["accuracy", "sparse_categorical_crossentropy"])
-    m.init_layers(seed=seed)
-    assert (m._lowering is not None) is lowered
-    rng = np.random.default_rng(7)
-    x = rng.standard_normal((batch * 2, 3, 12, 12), dtype=np.float32)
-    y = rng.integers(0, 10, size=(batch * 2, 1), dtype=np.int32)
-    dl = ff.DataLoader(m, {inp: x}, y)
-    for _ in range(steps):
-        dl.next_batch(m)
-        m.train_iteration()
-    dl.next_batch(m)
-    metrics = m.eval_batch()
-    fc2 = np.asarray(m.get_parameter("fc2", "kernel"))
-    conv1 = np.asarray(m.get_parameter("conv1", "kernel"))
-    return fc2, conv1, metrics
-
-
-def test_lowered_parity_hybrid_soap(devices):
-    """Hybrid SOAP strategy (spatial conv + TP dense + DP tail): the
-    lowered whole-graph step must match per-op dispatch bit for bit —
-    train trajectory AND eval metrics."""
-    fc2_a, conv_a, met_a = _train_hybrid(lowered=False)
-    fc2_b, conv_b, met_b = _train_hybrid(lowered=True)
-    np.testing.assert_array_equal(fc2_a, fc2_b)
-    np.testing.assert_array_equal(conv_a, conv_b)
-    assert met_a == met_b
-
-
-def test_lowered_parity_transformer_tp(devices):
-    """Transformer with head-TP attention and TP MLP: lowered == dispatch
-    bitwise (the ISSUE's 'transformer' parity anchor at 8 devices)."""
-    from flexflow_tpu.models.transformer import build_transformer
-
-    strategies = {
-        "attn_0": ff.ParallelConfig(dims=(2, 1, 4)),
-        "mlp_up_0": ff.ParallelConfig(dims=(2, 4)),
-        "mlp_down_0": ff.ParallelConfig(dims=(2, 1)),
-        "lm_head": ff.ParallelConfig(dims=(2, 1, 4)),
-        "softmax": ff.ParallelConfig(dims=(8, 1, 1)),
-    }
-
-    def run(lowered):
-        cfg = ff.FFConfig(batch_size=8, strategies=dict(strategies),
-                          lowered=lowered)
-        m = ff.FFModel(cfg)
-        tok, pos, _ = build_transformer(m, 8, seq_length=8, num_layers=1,
-                                        embed_dim=32, num_heads=4,
-                                        vocab_size=64)
-        m.compile(ff.SGDOptimizer(lr=0.05),
-                  "sparse_categorical_crossentropy", ["accuracy"])
-        m.init_layers(seed=13)
-        assert (m._lowering is not None) is lowered
-        rng = np.random.default_rng(2)
-        toks = rng.integers(0, 64, size=(8, 8)).astype(np.int32)
-        posa = np.broadcast_to(np.arange(8, dtype=np.int32), (8, 8)).copy()
-        m.set_batch({tok: toks, pos: posa},
-                    np.roll(toks, -1, axis=1).astype(np.int32))
-        for _ in range(2):
-            m.train_iteration()
-        m.sync()
-        return (np.asarray(m.get_parameter("lm_head", "kernel")),
-                np.asarray(m.get_parameter("mlp_up_0", "kernel")))
-
-    lm_a, up_a = run(False)
-    lm_b, up_b = run(True)
-    np.testing.assert_array_equal(lm_a, lm_b)
-    np.testing.assert_array_equal(up_a, up_b)
-
-
-# ---------------------------------------------------------------------------
-# exactly one trace+compile per step function (memplane ledger)
-# ---------------------------------------------------------------------------
 
 def _read_jsonl(path):
     with open(path) as f:
@@ -296,11 +338,11 @@ def test_lowered_single_compile_per_step(devices, tmp_path, monkeypatch):
     monkeypatch.setenv("FF_TELEMETRY_FILE", trace)
     monkeypatch.setenv("FF_MEMPLANE", "1")
     events.reset_active()
-    m, inp = _tiny_dense(lowered=True)
+    m, inp = _tiny_dense()
     m.compile(ff.SGDOptimizer(lr=0.1),
               "sparse_categorical_crossentropy", ["accuracy"])
     m.init_layers(seed=0)
-    assert m._lowering is not None and m._memplane is not None
+    assert m._memplane is not None
     rng = np.random.default_rng(0)
     x = rng.standard_normal((16 * 3, 8), np.float32)
     y = rng.integers(0, 4, (16 * 3, 1), dtype=np.int32)
@@ -324,48 +366,27 @@ def test_lowered_single_compile_per_step(devices, tmp_path, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# introspection: plan() and the provenance sidecar stamp
+# introspection: Machine.plan() and the provenance sidecar stamp
 # ---------------------------------------------------------------------------
 
 def test_lowering_plan_and_sidecar_stamp(devices, tmp_path):
     pb = str(tmp_path / "hybrid.pb")
-    cfg = ff.FFConfig(batch_size=16, strategies=dict(HYBRID),
-                      lowered=True, export_strategy_file=pb)
-    m = ff.FFModel(cfg)
-    inp = m.create_tensor((16, 3, 12, 12))
-    t = m.conv2d(inp, 8, 3, 3, 1, 1, 1, 1, name="conv1")
-    t = m.pool2d(t, 2, 2, 2, 2, 0, 0, name="pool1")
-    t = m.flat(t, name="flat1")
-    t = m.dense(t, 32, name="fc1")
-    t = m.dense(t, 10, name="fc2")
-    m.softmax(t, name="softmax1")
+    m = _hybrid_graph(ff.FFConfig(batch_size=16, strategies=dict(HYBRID),
+                                  export_strategy_file=pb))
     m.compile(ff.SGDOptimizer(lr=0.1), "sparse_categorical_crossentropy",
               ["accuracy"])
-    plan = m._lowering.plan()
+    plan = m.machine.plan(m.ops)
     # TP dense: out dim split 4 ways lands on ICI axes, roles s+p
     assert plan["fc1"]["roles"] == "sp"
     assert "m" in plan["fc1"]["spec"]
     # no dcn axis on this mesh → never a spill
-    assert m._lowering.dcn_spill == {}
+    assert not any("dcn_spill" in row for row in plan.values())
     with open(pb + ".meta.json") as f:
         meta = json.load(f)
-    assert meta["lowered"] is True
-    assert meta["lowering"]["fc1"]["spec"] == plan["fc1"]["spec"]
+    assert "lowered" not in meta
+    assert meta["lowering"] == plan
     # per-op attribution rows carry the resolved spec for --diff
     assert "spec" in next(iter(meta["ops"].values()))
-
-
-def test_sidecar_not_lowered_by_default(devices, tmp_path):
-    pb = str(tmp_path / "plain.pb")
-    m, _ = _tiny_dense()
-    m.config.export_strategy_file = pb
-    m.compile(ff.SGDOptimizer(lr=0.1),
-              "sparse_categorical_crossentropy", ["accuracy"])
-    assert m._lowering is None
-    with open(pb + ".meta.json") as f:
-        meta = json.load(f)
-    assert meta["lowered"] is False
-    assert "lowering" not in meta
 
 
 # ---------------------------------------------------------------------------
@@ -423,94 +444,3 @@ def test_search_never_spills_parameter_dims_to_dcn(devices):
     assert res  # non-empty strategy map
     for name, pc in res.items():
         assert mm.dcn_spill(pc.dims) == (), (name, pc.dims)
-
-
-# ---------------------------------------------------------------------------
-# shipped strategies at 16 devices (subprocess: own XLA device count)
-# ---------------------------------------------------------------------------
-
-_PARITY16 = """
-import sys
-sys.path.insert(0, '.')
-import numpy as np
-import flexflow_tpu as ff
-
-def run(lowered):
-    {build}
-    m.compile({compile_args})
-    m.init_layers(seed=0)
-    assert (m._lowering is not None) is lowered, m._lowering
-    if lowered:
-        assert m._lowering.dcn_spill == {{}}, m._lowering.dcn_spill
-    {batch}
-    for _ in range(2):
-        m.train_iteration()
-    m.sync()
-    return [np.asarray(m.get_parameter(n, w)) for n, w in {params}]
-
-a = run(False)
-b = run(True)
-for x, y in zip(a, b):
-    assert np.array_equal(x, y), (x.shape, np.abs(x - y).max())
-print('parity16 ok {name}')
-"""
-
-
-def _parity16_code(name, build, compile_args, batch, params):
-    return _PARITY16.format(name=name, build=build,
-                            compile_args=compile_args, batch=batch,
-                            params=params)
-
-
-@pytest.mark.slow
-def test_shipped_alexnet16_lowered_parity():
-    """strategies/alexnet_16.pb on 16 virtual devices: FF_LOWERED-style
-    whole-graph step == per-op dispatch, bit for bit."""
-    code = _parity16_code(
-        "alexnet",
-        build="""
-    from flexflow_tpu.models.alexnet import build_alexnet
-    cfg = ff.FFConfig(batch_size=16,
-                      import_strategy_file='strategies/alexnet_16.pb',
-                      lowered=lowered)
-    m = ff.FFModel(cfg)
-    inp, _ = build_alexnet(m, 16)""",
-        compile_args="ff.SGDOptimizer(lr=0.01), "
-                     "'sparse_categorical_crossentropy', ['accuracy']",
-        batch="""
-    rng = np.random.default_rng(5)
-    x = rng.standard_normal((16, 229, 229, 3), dtype=np.float32)  # NHWC
-    y = rng.integers(0, 10, size=(16, 1), dtype=np.int32)
-    m.set_batch({inp: x}, y)""",
-        params="[('conv1', 'kernel'), ('fc1', 'kernel'), ('fc3', 'kernel')]")
-    r = _run16(code, timeout=1500)
-    assert r.returncode == 0, r.stderr[-2500:]
-    assert "parity16 ok alexnet" in r.stdout
-
-
-@pytest.mark.slow
-def test_shipped_dlrm16_lowered_parity():
-    """strategies/dlrm_16.pb (embedding-dim splits + TP top MLP) on 16
-    virtual devices: lowered == dispatch bitwise."""
-    code = _parity16_code(
-        "dlrm",
-        build="""
-    from flexflow_tpu.models.dlrm import build_dlrm, synthetic_batch
-    sizes = [1000] * 8
-    cfg = ff.FFConfig(batch_size=16,
-                      import_strategy_file='strategies/dlrm_16.pb',
-                      lowered=lowered)
-    m = ff.FFModel(cfg)
-    sparse_in, dense_in, _ = build_dlrm(m, 16, embedding_sizes=sizes)""",
-        compile_args="ff.SGDOptimizer(m, lr=0.01), "
-                     "ff.LossType.MEAN_SQUARED_ERROR_AVG_REDUCE, "
-                     "[ff.MetricsType.MEAN_SQUARED_ERROR]",
-        batch="""
-    sparse, dense, labels = synthetic_batch(16, sizes, 1, 64)
-    inputs = {t: a for t, a in zip(sparse_in, sparse)}
-    inputs[dense_in] = dense
-    m.set_batch(inputs, labels)""",
-        params="[('embedding1', 'weight'), ('Dense_114', 'kernel')]")
-    r = _run16(code, timeout=1500)
-    assert r.returncode == 0, r.stderr[-2500:]
-    assert "parity16 ok dlrm" in r.stdout

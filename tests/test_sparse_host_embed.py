@@ -305,8 +305,7 @@ def test_fused_optimizer_composes_with_host_table(devices):
 
 def test_sync_scatter_knob(devices, monkeypatch):
     """FF_HE_SYNC_SCATTER=1 serializes the scatter-back with the step —
-    the measurement knob bench.py A/Bs to report the async overlap's
-    actual win."""
+    the measurement knob for an A/B of the async overlap's actual win."""
     m = _build(offload=True)
     monkeypatch.setenv("FF_HE_SYNC_SCATTER", "1")
     m.train_iteration()

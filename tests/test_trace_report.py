@@ -59,9 +59,6 @@ def synthetic_records():
         recs.append({"t": "event", "name": "op_profile", "ts": 10.0,
                      "attrs": {"op": op, "forward_ms": fwd,
                                "backward_ms": bwd}})
-    for i, phase in enumerate(["preflight", "compile", "warmup", "measure"]):
-        recs.append({"t": "event", "name": "bench_phase",
-                     "ts": float(i), "attrs": {"phase": phase}})
     for it, best in [(0, 9.5), (100, 7.2), (200, 6.8)]:
         recs.append({"t": "event", "name": "search_progress", "ts": 11.0,
                      "attrs": {"engine": "mcmc", "iter": it,
@@ -91,7 +88,7 @@ def test_report_sections(tmp_path):
     report = trace_report.main([path, "-o", str(tmp_path / "r.md")])
     assert os.path.exists(tmp_path / "r.md")
     for section in ["## Steps", "## Phases", "## Counters",
-                    "## Gauges (last value)", "## Top ops", "## Bench phases",
+                    "## Gauges (last value)", "## Top ops",
                     "## Search progress"]:
         assert section in report, f"missing {section}"
     # first step reported separately; steady stats over the other 4
