@@ -12,7 +12,9 @@ out by the repo's own means:
                    loss finite and falling on one repeated synthetic
                    batch, parameters changed, parameters and batch
                    resident on the TPU, no compilation in the timed
-                   window.  Prints samples/s for the reader, not a claim.
+                   window, the image stem computed space-to-depth
+                   (Conv2D.impl_used) and no other convolution.  Prints
+                   samples/s for the reader, not a claim.
   kernels          kernels/flash_attention.py against mha_reference on
                    the chip, forward and gradients, causal, bf16 and f32;
                    its line says which tiling ran at each shape.
@@ -164,8 +166,14 @@ def phase_alexnet(sz, dev, stats):
            for k, a in model.placement().items()
            if {d.platform for d in a.devices()} != {dev.platform}}
     check(not off, f"arrays not on {dev.platform}: {off}")
+    s2d = [op.name for op in model.ops
+           if getattr(op, "impl_used", None)
+           and op.impl_used[0] == "space_to_depth"]
+    check(s2d == ["conv1"], f"convolutions computed space-to-depth: {s2d}, "
+                            f"wanted the image stem alone")
     result(
         "alexnet", batch=b, image=sz["image"], dtype="bfloat16",
+        conv_space_to_depth=s2d,
         loss_first=loss_first, loss_last=loss_last, timed_steps=sz["timed"],
         compilations_in_window=in_window,
         build_compile_warmup_s=round(compile_s, 1),

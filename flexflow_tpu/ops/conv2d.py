@@ -11,6 +11,22 @@ TPU-native design notes:
   * convolution lowers to a single ``lax.conv_general_dilated``; bias and
     activation fuse into it at the XLA level (no separate kernels as in
     the cuDNN path).
+  * an image stem is computed space-to-depth (``Conv2D.impl_used``).  A
+    strided convolution over three channels gives the MXU a contraction
+    three deep per tap in the forward pass and an output three rows high
+    per tap in the weight gradient, and the stride keeps XLA from folding
+    neighbouring taps together cheaply.  Moving each stride x stride block
+    of pixels into the channels makes it, exactly, a stride-1 convolution
+    of ``ceil(k / s)`` taps a side over ``s * s * cin`` channels: AlexNet's
+    11x11 stride 4 over 3 becomes a 3x3 over 48, the same products and
+    sums plus 19 % multiplications by the zeros that pad the kernel to
+    12x12.  The rule is the shape's (``_space_to_depth_rule``): one group,
+    a square stride above 1, a kernel larger than it, at most 4 input
+    channels.  The stored kernel stays ``(kh, kw, cin, cout)``; it is cut
+    the same way inside ``forward`` (23 k elements), so its gradient flows
+    back through the reshape and is the small convolution's.  The image
+    is rearranged by a strided convolution with a one-hot kernel: one
+    pass that takes the padding with it (``Conv2D._space_to_depth``).
   * float32 accumulation is requested via ``preferred_element_type`` when
     activations are bfloat16.
   * spatial (H/W) partitioning — the reference's "attribute" parallelism
@@ -21,7 +37,7 @@ TPU-native design notes:
 
 from __future__ import annotations
 
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -53,6 +69,28 @@ def apply_activation(x, activation: Optional[str]):
     raise ValueError(f"unknown activation {activation}")
 
 
+# An image stem: fewer input channels than one sublane tile holds, let
+# alone 128 lanes.  Widen only with a chip measurement (PERF.md).
+_STEM_CHANNELS = 4
+
+
+def _space_to_depth_rule(kernel, stride, cin, groups) -> Tuple[str, str]:
+    """(form, why) for a convolution of this shape: ``space_to_depth``
+    where the direct form starves the MXU, else ``direct``."""
+    (kh, kw), (sh, sw) = kernel, stride
+    if groups != 1:
+        return "direct", f"{groups} groups"
+    if sh != sw or sh == 1:
+        return "direct", f"stride {sh}x{sw} is not a square stride above 1"
+    if min(kh, kw) <= sh:
+        return "direct", f"kernel {kh}x{kw} is no larger than stride {sh}"
+    if cin > _STEM_CHANNELS:
+        return "direct", f"{cin} input channels fill the MXU's contraction"
+    return "space_to_depth", (
+        f"{kh}x{kw} stride {sh} over {cin} channels as "
+        f"{-(-kh // sh)}x{-(-kw // sh)} stride 1 over {sh * sh * cin}")
+
+
 class Conv2D(Op):
     _type = "Conv2D"
 
@@ -70,6 +108,9 @@ class Conv2D(Op):
         self.activation = activation
         self.use_bias = use_bias
         self.groups = groups
+        # which form forward() computes, decided by the shape alone
+        self.impl_used = _space_to_depth_rule(
+            self.kernel, self.stride, cin, groups)
         out_h = 1 + (h + 2 * padding_h - kernel_h) // stride_h
         out_w = 1 + (w + 2 * padding_w - kernel_w) // stride_w
         self._add_output((n, out_h, out_w, out_channels), input_tensor.dtype)
@@ -94,17 +135,54 @@ class Conv2D(Op):
                              bias_initializer or DefaultBiasInitializer(),
                              partition_dims=(3,))
 
+    def _space_to_depth(self, x, kernel):
+        """``x`` and ``kernel`` with each stride x stride block of pixels
+        moved into the channels, ``(bh, bw, cin)`` minor: the stride-1
+        ``VALID`` convolution of the two is the strided one.
+
+        ``x`` is rearranged by a strided convolution with a one-hot
+        kernel, which is exact and takes the padding with it.  As pad,
+        reshape and transpose XLA:TPU, which keeps the batch on the
+        lanes here, runs three passes over the image that cost more
+        than the rearranged convolution saves (PERF.md, PR 31)."""
+        s = self.stride[0]
+        cin, cout = kernel.shape[2:]
+        depth = s * s * cin
+        _, oh, ow, _ = self.output.dims
+        taps, x_pad, k_pad = [], [], []
+        for size, k, p, out in zip(x.shape[1:3], self.kernel, self.padding,
+                                   (oh, ow)):
+            kb = -(-k // s)
+            taps.append(kb)
+            # negative where the trailing rows reach no window: cropped
+            x_pad.append((p, (out - 1 + kb) * s - size - p))
+            k_pad.append((0, kb * s - k, 0))
+        one_hot = jnp.eye(depth, dtype=x.dtype).reshape(s, s, cin, depth)
+        x = lax.conv_general_dilated(
+            x, one_hot, window_strides=(s, s), padding=x_pad,
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        th, tw = taps
+        kernel = lax.pad(kernel, jnp.zeros((), kernel.dtype),
+                         k_pad + [(0, 0, 0), (0, 0, 0)])
+        kernel = kernel.reshape(th, s, tw, s, cin, cout)
+        kernel = kernel.transpose(0, 2, 1, 3, 4, 5)
+        return x, kernel.reshape(th, tw, depth, cout)
+
     def forward(self, params, xs: List[jax.Array], ctx: FwdCtx):
         x = xs[0]
         kernel = params["kernel"].astype(x.dtype)
         ph, pw = self.padding
+        stride, padding = self.stride, ((ph, ph), (pw, pw))
+        if self.impl_used[0] == "space_to_depth":
+            x, kernel = self._space_to_depth(x, kernel)
+            stride, padding = (1, 1), "VALID"
         # No explicit f32 upcast: the MXU accumulates bf16 convs in f32
         # internally, and a preferred_element_type≠input dtype breaks the
         # conv transpose (wgrad) rule under jax.grad.
         y = lax.conv_general_dilated(
             x, kernel,
-            window_strides=self.stride,
-            padding=((ph, ph), (pw, pw)),
+            window_strides=stride,
+            padding=padding,
             dimension_numbers=("NHWC", "HWIO", "NHWC"),
             feature_group_count=self.groups,
         )

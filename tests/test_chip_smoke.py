@@ -65,6 +65,10 @@ def test_smoke_tiny_mode_runs_every_phase():
                   "fused_optimizer", "multichip"):
         assert any(ln.startswith(f"platform=cpu phase {phase}: ok ")
                    for ln in lines), (phase, r.stdout[-2000:])
+    # the alexnet line names the convolutions computed space-to-depth
+    line = next(ln for ln in lines if " phase alexnet: ok " in ln)
+    assert json.loads(line.split(" ok ", 1)[1])["conv_space_to_depth"] == \
+        ["conv1"]
     # the transformer's line says which tensor the accuracy read: no
     # instruction under the final Softmax's scope, and the two device
     # times a step, which a CPU has not

@@ -444,3 +444,112 @@ def test_search_never_spills_parameter_dims_to_dcn(devices):
     assert res  # non-empty strategy map
     for name, pc in res.items():
         assert mm.dcn_spill(pc.dims) == (), (name, pc.dims)
+
+
+# ---------------------------------------------------------------------------
+# the image stem computed space-to-depth (ops/conv2d.py): the step's
+# program, the stored kernel, checkpoints, and a stem split by height or
+# width across devices
+# ---------------------------------------------------------------------------
+
+def _small_alexnet(devices=1, strategies=None, direct=False, batch=8,
+                   image=67):
+    """AlexNet at a small image in f32, one batch staged.  ``direct``
+    makes every convolution the plain strided one: the reference."""
+    from flexflow_tpu.models.alexnet import build_alexnet
+
+    cfg = ff.FFConfig(batch_size=batch, workers_per_node=devices,
+                      compute_dtype="float32",
+                      strategies=dict(strategies or {}))
+    m = ff.FFModel(cfg)
+    inp, _ = build_alexnet(m, batch, height=image, width=image)
+    if direct:
+        for op in m.ops:
+            if op._type == "Conv2D":
+                op.impl_used = ("direct", "the test's reference")
+    m.compile(ff.SGDOptimizer(lr=0.01), "sparse_categorical_crossentropy",
+              ["accuracy"])
+    m.init_layers(seed=5)
+    rng = np.random.default_rng(5)
+    m.set_batch({inp: rng.standard_normal((batch, image, image, 3),
+                                          dtype=np.float32)},
+                rng.integers(0, 10, (batch, 1), dtype=np.int32))
+    return m
+
+
+def _losses(m, steps=5):
+    out = []
+    for _ in range(steps):
+        m.reset_metrics()
+        m.train_iteration()
+        m.sync()
+        m.get_metrics()
+        out.append(m.last_loss)
+    return out
+
+
+def _conv_windows(hlo):
+    """(kernel type, ...) of every convolution in a StableHLO text:
+    ``11x11x3x64xf32`` for an 11x11 window over 3 features."""
+    return re.findall(r"stablehlo\.convolution\(.*?: \(tensor<[^>]*>, "
+                      r"tensor<([^>]*)>\)", hlo)
+
+
+def test_alexnet_stem_step_program(devices):
+    m = _small_alexnet()
+    assert [op.name for op in m.ops if op._type == "Conv2D"
+            and op.impl_used[0] == "space_to_depth"] == ["conv1"]
+    windows = _conv_windows(m.train_step_hlo())
+    assert not [w for w in windows if w.startswith("11x11x")], windows
+    # forward and kernel gradient of the 3x3 over 48 (the data has no
+    # gradient), and the one-hot rearrangement of the image before them
+    assert windows.count("3x3x48x64xf32") == 1, windows
+    assert windows.count("4x4x3x48xf32") == 1, windows
+    # conv2..conv5 as they always were
+    assert "5x5x64x192xf32" in windows and "3x3x192x384xf32" in windows
+    assert m.get_parameter("conv1", "kernel").shape == (11, 11, 3, 64)
+    assert m.ops[0].flops_per_sample() == 2.0 * 16 * 16 * 64 * 11 * 11 * 3
+    # the reference's program does hold the 11x11
+    ref = _small_alexnet(direct=True)
+    assert "11x11x3x64xf32" in _conv_windows(ref.train_step_hlo())
+
+
+def test_alexnet_stem_trains_as_the_direct_form(devices, tmp_path):
+    ref = _small_alexnet(direct=True)
+    # a checkpoint written by the direct form: the layout every earlier
+    # checkpoint has
+    ckpt = str(tmp_path / "direct.npz")
+    ref.save(ckpt)
+    m = _small_alexnet()
+    m.load(ckpt)
+    for name in ("conv1", "conv2", "fc3"):
+        np.testing.assert_array_equal(m.get_parameter(name, "kernel"),
+                                      ref.get_parameter(name, "kernel"))
+    k_first = m.get_parameter("conv1", "kernel")
+    want, got = _losses(ref), _losses(m)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    assert got[-1] < got[0]
+    k_ref, k = (x.get_parameter("conv1", "kernel") for x in (ref, m))
+    assert np.abs(k_ref).max() > 0
+    np.testing.assert_allclose(k, k_ref, rtol=1e-4, atol=1e-6)
+    assert not np.array_equal(k, k_first)  # and the steps moved it
+
+
+@pytest.fixture(scope="module")
+def stem_one_device_losses(devices):
+    return _losses(_small_alexnet())
+
+
+@pytest.mark.parametrize("dims", [(2, 2, 1, 1), (2, 1, 2, 1)],
+                         ids=["sample-x-height", "sample-x-width"])
+def test_alexnet_stem_split_across_devices(stem_one_device_losses, dims):
+    """conv1's output constrained sample x height or sample x width on
+    four devices: GSPMD carries the split through the rearrangement and
+    the loss is the one-device loss."""
+    want = stem_one_device_losses
+    m = _small_alexnet(devices=4,
+                       strategies={"conv1": ff.ParallelConfig(dims=dims)})
+    assert m.machine.num_devices == 4
+    assert tuple(m.ops[0].pc.dims) == dims
+    assert m.ops[0].impl_used[0] == "space_to_depth"
+    np.testing.assert_allclose(_losses(m), want, rtol=1e-5)

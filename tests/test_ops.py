@@ -49,6 +49,125 @@ def test_conv2d_matches_torch():
                                y_ref.numpy(), rtol=2e-5, atol=2e-5)
 
 
+def _conv_op(kernel, stride, padding, image, cin=3, cout=8, batch=2, **kw):
+    m = make_model(batch)
+    m.conv2d(m.create_tensor((batch, cin, *image)), cout, *kernel, *stride,
+             *padding, **kw)
+    return m.ops[0]
+
+
+def _direct_conv(op, params, x):
+    """The op as one strided convolution of the stored kernel."""
+    (ph, pw) = op.padding
+    y = jax.lax.conv_general_dilated(
+        x, params["kernel"].astype(x.dtype), op.stride,
+        ((ph, ph), (pw, pw)), dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        feature_group_count=op.groups)
+    if op.use_bias:
+        y = y + params["bias"].astype(y.dtype)
+    return ff.ops.conv2d.apply_activation(y, op.activation)
+
+
+# kernel, stride, padding, image (h, w), the rest of conv2d's arguments
+STEMS = {
+    "alexnet-11/4/2-229": ((11, 11), (4, 4), (2, 2), (229, 229), {}),
+    "resnet-7/2/3-224": ((7, 7), (2, 2), (3, 3), (224, 224), {}),
+    "inception-3/2/0-299": ((3, 3), (2, 2), (0, 0), (299, 299), {}),
+    "kernel-multiple-of-stride-8/4/2": ((8, 8), (4, 4), (2, 2), (61, 61), {}),
+    "trailing-rows-cropped-8/4/0-15": ((8, 8), (4, 4), (0, 0), (15, 15), {}),
+    "non-square-11/4/2-67x45": ((11, 11), (4, 4), (2, 2), (67, 45), {}),
+    "no-bias": ((11, 11), (4, 4), (2, 2), (67, 67), {"use_bias": False}),
+    "relu": ((11, 11), (4, 4), (2, 2), (67, 67),
+             {"activation": ff.ActiMode.RELU}),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", STEMS)
+def test_conv2d_space_to_depth_matches_direct(case, dtype):
+    """An image stem is computed space-to-depth (ops/conv2d.py): output,
+    kernel gradient and bias gradient are the direct convolution's of
+    the stored kernel.  f32 to 1e-4 of the largest magnitude; bf16 as
+    close to the f32 answer as the direct form in bf16 comes."""
+    kernel, stride, padding, image, kw = STEMS[case]
+    op = _conv_op(kernel, stride, padding, image, **kw)
+    assert op.impl_used[0] == "space_to_depth", op.impl_used
+    rng = np.random.default_rng(11)
+    x32 = jnp.asarray(rng.standard_normal((2, *image, 3), dtype=np.float32))
+    params = {"kernel": jnp.asarray(
+        rng.standard_normal((*kernel, 3, 8), dtype=np.float32))}
+    if op.use_bias:
+        params["bias"] = jnp.asarray(rng.standard_normal(8, dtype=np.float32))
+    weight = jnp.asarray(
+        rng.standard_normal(op.output.dims, dtype=np.float32))
+
+    def graded(forward, dt):
+        def f(params):
+            y = forward(params, x32.astype(dt)).astype(jnp.float32)
+            return jnp.sum(y * weight), y
+        (_, y), grads = jax.value_and_grad(f, has_aux=True)(params)
+        return {"out": y, **grads}
+
+    def worst(got, want):
+        return {k: float(jnp.abs(got[k] - want[k]).max()
+                         / jnp.abs(want[k]).max()) for k in want}
+
+    oracle = graded(lambda p, x: _direct_conv(op, p, x), jnp.float32)
+    got = graded(lambda p, x: run_op(op, p, x), dtype)
+    assert got["out"].shape == tuple(op.output.dims)
+    assert got["kernel"].shape == (*kernel, 3, 8)  # the stored layout's
+    if dtype == "float32":
+        bound = dict.fromkeys(oracle, 1e-4)
+    else:
+        direct = worst(graded(lambda p, x: _direct_conv(op, p, x), dtype),
+                       oracle)
+        bound = {k: 2 * v + 1e-3 for k, v in direct.items()}
+    err = worst(got, oracle)
+    assert all(err[k] <= bound[k] for k in err), (err, bound)
+
+
+# kernel, stride, cin, groups -> does the rule take it
+RULE = {
+    "alexnet-stem": ((11, 11), (4, 4), 3, 1, True),
+    "resnet-stem": ((7, 7), (2, 2), 3, 1, True),
+    "inception-stem": ((3, 3), (2, 2), 3, 1, True),
+    "one-channel": ((5, 5), (3, 3), 1, 1, True),
+    "four-channels": ((5, 3), (2, 2), 4, 1, True),
+    "stride-1": ((11, 11), (1, 1), 3, 1, False),
+    "stride-not-square": ((5, 5), (2, 4), 3, 1, False),
+    "groups": ((3, 3), (2, 2), 4, 2, False),
+    "64-channels": ((3, 3), (2, 2), 64, 1, False),
+    "5-channels": ((3, 3), (2, 2), 5, 1, False),
+    "kernel-is-the-stride": ((2, 2), (2, 2), 3, 1, False),
+    "1x1-stride-2": ((1, 1), (2, 2), 3, 1, False),
+    "1x3-stride-2": ((1, 3), (2, 2), 3, 1, False),
+}
+
+
+@pytest.mark.parametrize("case", RULE)
+def test_conv2d_space_to_depth_rule(case):
+    """The rule is the shape's; a shape it leaves alone traces to the one
+    convolution the op always was."""
+    kernel, stride, cin, groups, engages = RULE[case]
+    op = _conv_op(kernel, stride, (1, 1), (19, 19), cin=cin, groups=groups)
+    form, why = op.impl_used
+    assert form == ("space_to_depth" if engages else "direct"), why
+    assert why
+    params = {"kernel": jnp.ones((*kernel, cin // groups, 8)),
+              "bias": jnp.ones(8)}
+    x = jnp.ones((2, 19, 19, cin))
+    prims = [str(e.primitive) for e in jax.make_jaxpr(
+        lambda p, x: run_op(op, p, x))(params, x).eqns]
+    convs = prims.count("conv_general_dilated")
+    if engages:
+        assert convs == 2  # the rearrangement of x, and the convolution
+    else:
+        assert convs == 1
+        assert not {"pad", "reshape", "transpose"} & set(prims), prims
+    np.testing.assert_allclose(run_op(op, params, x),
+                               _direct_conv(op, params, x), rtol=1e-5)
+
+
 def test_conv2d_shape_formula():
     # out = 1 + (in + 2p - k)/s  (reference conv_2d.cu:100-101)
     m = make_model()
