@@ -55,6 +55,16 @@ class GlorotUniform(Initializer):
         return jax.random.uniform(key, shape, dtype, minval=-scale, maxval=scale)
 
 
+class StackedGlorotUniform(GlorotUniform):
+    """Glorot uniform for a stack of matrices (..., fan_in, fan_out): each
+    matrix of the stack is drawn as a dense kernel of its own shape would
+    be (an expert's weights do not shrink with the number of experts)."""
+
+    @staticmethod
+    def _fans(shape: Sequence[int]) -> Tuple[float, float]:
+        return float(shape[-2]), float(shape[-1])
+
+
 class ZeroInitializer(Initializer):
     def __call__(self, key, shape, dtype=jnp.float32):
         return jnp.zeros(shape, dtype)

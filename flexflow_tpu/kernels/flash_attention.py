@@ -9,7 +9,8 @@ backward.  The kernel also returns the per-row logsumexp, which is what
 lets ring attention (parallel/sequence.py) merge partial results across
 sequence shards.
 
-Layout: (batch, heads, seq, head_dim) in and out.  Three kernels, each a
+Layout: (batch, heads, seq, head_dim) in and out; the value head may be
+narrower or wider than the query/key head.  Three kernels, each a
 ``pallas_call`` named as its scope: ``flash_fwd`` (grid batch*heads x
 q blocks x k blocks, k innermost), ``flash_dq`` (the same grid) and
 ``flash_dkv`` (batch*heads x k blocks x q blocks, q innermost), so each
@@ -314,9 +315,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 @functools.partial(jax.jit, **_STATIC)
 def _fwd_call(qr, kr, vr, scale, causal, bq, bk, interpret):
-    """o (bh, sq, d) and lse (bh, sq) of (bh, s, d) operands."""
+    """o (bh, sq, dv) and lse (bh, sq) of q, k (bh, s, d) and v (bh, sk,
+    dv) operands."""
     bh, sq, d = qr.shape
-    sk = kr.shape[1]
+    sk, dv = vr.shape[1:]
 
     call = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -325,19 +327,19 @@ def _fwd_call(qr, kr, vr, scale, causal, bq, bk, interpret):
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh_, qi, ki: (bh_, qi, 0)),
             pl.BlockSpec((1, bk, d), _kv_map(causal, bq, bk)),
-            pl.BlockSpec((1, bk, d), _kv_map(causal, bq, bk)),
+            pl.BlockSpec((1, bk, dv), _kv_map(causal, bq, bk)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh_, qi, ki: (bh_, qi, 0)),
+            pl.BlockSpec((1, bq, dv), lambda bh_, qi, ki: (bh_, qi, 0)),
             pl.BlockSpec((None, None, 1, bq),
                          lambda bh_, qi, ki: (bh_, qi, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), qr.dtype),
+            jax.ShapeDtypeStruct((bh, sq, dv), qr.dtype),
             jax.ShapeDtypeStruct((bh, sq // bq, 1, bq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((d, bq), jnp.float32),
+            pltpu.VMEM((dv, bq), jnp.float32),
             pltpu.VMEM((1, bq), jnp.float32),
             pltpu.VMEM((1, bq), jnp.float32),
         ],
@@ -351,13 +353,13 @@ def _fwd_call(qr, kr, vr, scale, causal, bq, bk, interpret):
 
 def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = v.shape[2:]
     bq, bk = _block_sizes(sq, sk, d, block_q, block_k)
     with jax.named_scope("ff.kernel.flash_fwd"):
         out, lse = _fwd_call(q.reshape(b * h, sq, d), k.reshape(b * h, sk, d),
-                             v.reshape(b * h, sk, d), scale, causal, bq, bk,
+                             v.reshape(b * h, sk, dv), scale, causal, bq, bk,
                              interpret)
-    return out.reshape(b, h, sq, d), lse.reshape(b, h, sq)
+    return out.reshape(b, h, sq, dv), lse.reshape(b, h, sq)
 
 
 # ---------------------------------------------------------------------------
@@ -429,9 +431,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 @functools.partial(jax.jit, **_STATIC)
 def _dkv_call(qr, kr, vr, dor, lse, delta, scale, causal, bq, bk, interpret):
-    """dk, dv (bh, sk, d); ``lse`` and ``delta`` are (bh, sq)."""
+    """dk (bh, sk, d) and dv (bh, sk, dv); ``lse`` and ``delta`` are
+    (bh, sq)."""
     bh, sq, d = qr.shape
-    sk = kr.shape[1]
+    sk, dv = vr.shape[1:]
     nq = sq // bq
 
     def q_of(ki, qi):
@@ -439,23 +442,28 @@ def _dkv_call(qr, kr, vr, dor, lse, delta, scale, causal, bq, bk, interpret):
             qi = jnp.minimum(jnp.maximum(qi, _first_q(ki, bq, bk)), nq - 1)
         return qi
 
-    rows = pl.BlockSpec((1, bq, d), lambda bh_, ki, qi: (bh_, q_of(ki, qi), 0))
-    cols = pl.BlockSpec((1, bk, d), lambda bh_, ki, qi: (bh_, ki, 0))
+    def rows(width):
+        return pl.BlockSpec((1, bq, width),
+                            lambda bh_, ki, qi: (bh_, q_of(ki, qi), 0))
+
+    def cols(width):
+        return pl.BlockSpec((1, bk, width), lambda bh_, ki, qi: (bh_, ki, 0))
+
     stat = pl.BlockSpec((None, None, 1, bq),
                         lambda bh_, ki, qi: (bh_, q_of(ki, qi), 0, 0))
     call = pl.pallas_call(
         functools.partial(_bwd_dkdv_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk),
         grid=(bh, sk // bk, nq),
-        in_specs=[rows, cols, cols, rows, stat, stat],
-        out_specs=[cols, cols],
+        in_specs=[rows(d), cols(d), cols(dv), rows(dv), stat, stat],
+        out_specs=[cols(d), cols(dv)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sk, d), kr.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), vr.dtype),
+            jax.ShapeDtypeStruct((bh, sk, dv), vr.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, dv), jnp.float32),
         ],
         interpret=interpret,
         name="flash_dkv",
@@ -468,18 +476,22 @@ def _dkv_call(qr, kr, vr, dor, lse, delta, scale, causal, bq, bk, interpret):
 def _dq_call(qr, kr, vr, dor, lse, delta, scale, causal, bq, bk, interpret):
     """dq (bh, sq, d); ``lse`` and ``delta`` are (bh, sq)."""
     bh, sq, d = qr.shape
-    sk = kr.shape[1]
+    sk, dv = vr.shape[1:]
 
-    rows = pl.BlockSpec((1, bq, d), lambda bh_, qi, ki: (bh_, qi, 0))
-    cols = pl.BlockSpec((1, bk, d), _kv_map(causal, bq, bk))
+    def rows(width):
+        return pl.BlockSpec((1, bq, width), lambda bh_, qi, ki: (bh_, qi, 0))
+
+    def cols(width):
+        return pl.BlockSpec((1, bk, width), _kv_map(causal, bq, bk))
+
     stat = pl.BlockSpec((None, None, 1, bq),
                         lambda bh_, qi, ki: (bh_, qi, 0, 0))
     call = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk),
         grid=(bh, sq // bq, sk // bk),
-        in_specs=[rows, cols, cols, rows, stat, stat],
-        out_specs=rows,
+        in_specs=[rows(d), cols(d), cols(dv), rows(dv), stat, stat],
+        out_specs=rows(d),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), qr.dtype),
         scratch_shapes=[pltpu.VMEM((d, bq), jnp.float32)],
         interpret=interpret,
@@ -493,21 +505,21 @@ def _flash_backward(scale, causal, block_q, block_k, interpret, res, grads):
     q, k, v, out, lse = res
     do, _ = grads
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = v.shape[2:]
     bq, bk = _block_sizes(sq, sk, d, block_q, block_k)
     bh = b * h
 
     delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
-    args = (q.reshape(bh, sq, d), k.reshape(bh, sk, d), v.reshape(bh, sk, d),
-            do.reshape(bh, sq, d), lse.reshape(bh, sq), delta.reshape(bh, sq),
+    args = (q.reshape(bh, sq, d), k.reshape(bh, sk, d), v.reshape(bh, sk, dv),
+            do.reshape(bh, sq, dv), lse.reshape(bh, sq), delta.reshape(bh, sq),
             scale, causal, bq, bk, interpret)
     with jax.named_scope("ff.kernel.flash_dkv"):
-        dk, dv = _dkv_call(*args)
+        dk, dv_ = _dkv_call(*args)
     with jax.named_scope("ff.kernel.flash_dq"):
         dq = _dq_call(*args)
     return (dq.reshape(b, h, sq, d),
             dk.reshape(b, h, sk, d),
-            dv.reshape(b, h, sk, d))
+            dv_.reshape(b, h, sk, dv))
 
 
 # ---------------------------------------------------------------------------
@@ -536,7 +548,9 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     interpret: bool = False):
     """Fused attention: softmax(q k^T * scale [+ causal mask]) v.
 
-    Args are (B, H, S, D).  Returns the output, plus the per-row
+    q and k are (B, H, S, D); v is (B, H, S, Dv) and the output has its
+    width, which need not be D (latent attention's 192 | 128).  ``scale``
+    defaults to 1/sqrt(D).  Returns the output, plus the per-row
     logsumexp (B, H, S) when ``return_lse`` — ring attention uses the
     lse to merge shard-local partials.  Raises ValueError for a sequence
     length the kernel cannot tile (``unsupported_reason``).

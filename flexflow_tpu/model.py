@@ -54,6 +54,7 @@ from .ops.misc import (BatchNorm, Concat, Dropout, ElementBinary, ElementUnary,
                        Flat, MSELoss, Softmax)
 from .parallel.mesh import Machine, dim_roles
 from .parallel.strategy import load_strategies_from_file, save_strategies_to_file
+from .runtime.profiling import count as _ff_count
 from .runtime.profiling import span as _ff_span
 from .runtime.profiling import step_enqueue as _ff_step_enqueue
 from .tensor import DataType, Parameter, Tensor
@@ -131,6 +132,7 @@ class FFModel:
         self._staged = False
         self._train_step_fn = None
         self._eval_step_fn = None
+        self._logits_fn = None
         self._fresh_jit = False  # next train-step build bypasses the
         #                          persistent compile cache (recompile)
         self._compiled = False
@@ -279,6 +281,44 @@ class FFModel:
 
         return self._append(LayerNorm(self, input_tensor, eps,
                                       elementwise_affine, name))
+
+    def rms_norm(self, input_tensor: Tensor, eps: float = 1e-6,
+                 name: Optional[str] = None) -> Tensor:
+        from .ops.misc import RMSNorm
+
+        return self._append(RMSNorm(self, input_tensor, eps, name))
+
+    def gated_mlp(self, input_tensor: Tensor, width: int,
+                  kernel_initializer=None,
+                  name: Optional[str] = None) -> Tensor:
+        """(silu(x W_gate) * (x W_up)) W_down, no bias; the last config
+        dim splits ``width``."""
+        from .ops.linear import GatedMLP
+
+        return self._append(GatedMLP(self, input_tensor, width,
+                                     kernel_initializer, name))
+
+    def latent_attention(self, input_tensor: Tensor, num_heads: int,
+                         name: Optional[str] = None, **sizes) -> Tensor:
+        """Causal multi-head latent attention (B,S,E)->(B,S,E) over the
+        ``num_heads`` heads held here; ``sizes`` are ``LatentAttention``'s
+        (the ranks, the three head dims, ``rope_theta``, ``rope_scaling``)."""
+        from .ops.attention import LatentAttention
+
+        return self._append(LatentAttention(self, input_tensor, num_heads,
+                                            name=name, **sizes))
+
+    def routed_experts(self, input_tensor: Tensor, n_routed_experts: int,
+                       num_experts_per_tok: int, expert_width: int,
+                       name: Optional[str] = None, **kw) -> Tensor:
+        """Routed experts with shared experts under a device budget, for
+        the experts held here (``RoutedExperts``); config dim 1 is the
+        expert-parallel degree."""
+        from .ops.moe import RoutedExperts
+
+        return self._append(RoutedExperts(
+            self, input_tensor, n_routed_experts, num_experts_per_tok,
+            expert_width, name=name, **kw))
 
     def concat(self, tensors: Sequence[Tensor], axis: int,
                name: Optional[str] = None) -> Tensor:
@@ -1032,6 +1072,7 @@ class FFModel:
         self._compiled = True
         self._train_step_fn = None
         self._eval_step_fn = None
+        self._logits_fn = None
 
     def _legalize_pc(self, op: Op, pc: ParallelConfig) -> ParallelConfig:
         """Clamp a config to one the op can execute (op-specific hook:
@@ -1840,7 +1881,10 @@ class FFModel:
     # ------------------------------------------------------------------
     # forward-graph evaluation (inside jit)
     # ------------------------------------------------------------------
-    def _run_graph(self, params, stats, batch, training: bool, rng):
+    def _run_graph(self, params, stats, batch, training: bool, rng,
+                   counters: Optional[Dict[str, jax.Array]] = None):
+        """``counters``: a dict the ops' per-step scalars are summed into
+        (``Op.COUNTERS``); the train step passes one."""
         env: Dict[int, jax.Array] = {}
         multi = self.machine.num_devices > 1
         cdtype = self.compute_dtype
@@ -1861,7 +1905,7 @@ class FFModel:
             fill_dtype = jnp.int32 if "int" in t.dtype else cdtype
             env[t.guid] = jnp.full(t.dims, val, fill_dtype)
         ctx = FwdCtx(training=training, rng=rng, stats_in=stats,
-                     stats_out={} if training else None)
+                     stats_out={} if training else None, counters=counters)
         plan = getattr(self, "_pipeline_plan", None)
         use_pipe = (plan is not None and multi and plan["degree"] > 1)
         head_ids = ({id(op) for op in plan["head"]}
@@ -1989,8 +2033,9 @@ class FFModel:
                 jnp.where(jnp.isfinite(gnorm), gnorm, 0.0))
             return vec
 
-        def micro_metrics(loss, read, labels):
+        def micro_metrics(loss, read, labels, counts):
             msum = metrics_of(read, labels)
+            msum.update(counts)
             msum["loss"] = loss
             msum["steps"] = 1.0
             # On-device metric accumulation: one small vector rides along
@@ -2047,14 +2092,16 @@ class FFModel:
             labels = batch["label"]
 
             def loss_fn(p):
-                env, new_stats = self._run_graph(p, stats, batch, True, rng)
+                counts = {}
+                env, new_stats = self._run_graph(p, stats, batch, True, rng,
+                                                 counts)
                 loss, read = loss_of(env, labels)
-                return loss, (read, new_stats)
+                return loss, (read, new_stats, counts)
 
-            (loss, (read, new_stats)), grads = jax.value_and_grad(
+            (loss, (read, new_stats, counts)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
             with jax.named_scope("ff.metrics"):
-                mvec = micro_metrics(loss, read, labels)
+                mvec = micro_metrics(loss, read, labels, counts)
                 if track_health:
                     mvec = mvec + health_metrics(loss, grads)
             return finish(params, stats, opt_state, hparams, grads,
@@ -2079,16 +2126,19 @@ class FFModel:
                 mlabels = mb["label"]
 
                 def loss_fn(p):
+                    counts = {}
                     env, new_stats = self._run_graph(
-                        p, stats_c, mb, True, jax.random.fold_in(rng, idx))
+                        p, stats_c, mb, True, jax.random.fold_in(rng, idx),
+                        counts)
                     loss, read = loss_of(env, mlabels)
-                    return loss, (read, new_stats)
+                    return loss, (read, new_stats, counts)
 
-                (loss, (read, new_stats)), g = jax.value_and_grad(
+                (loss, (read, new_stats, counts)), g = jax.value_and_grad(
                     loss_fn, has_aux=True)(params)
                 g_acc = jax.tree.map(lambda a, b: a + b / accum, g_acc, g)
                 with jax.named_scope("ff.metrics"):
-                    mv_acc = mv_acc + micro_metrics(loss, read, mlabels)
+                    mv_acc = mv_acc + micro_metrics(loss, read, mlabels,
+                                                    counts)
                 return (g_acc, mv_acc, new_stats), None
 
             (grads, mvec, new_stats), _ = jax.lax.scan(
@@ -2194,7 +2244,13 @@ class FFModel:
             keys += list(HEALTH_METRIC_KEYS)
         if self._nonfinite_guard is not None:
             keys += list(self._nonfinite_guard.METRIC_KEYS)
-        return keys
+        return keys + self._op_counter_keys()
+
+    def _op_counter_keys(self) -> List[str]:
+        """The ops' own per-step scalars (``Op.COUNTERS``), which ride the
+        metric vector and leave it at the drain for
+        ``runtime.profiling.counters()``."""
+        return sorted({k for op in self.ops for k in op.COUNTERS})
 
     def update(self) -> None:
         # The step choke point fires on the GLOBAL step index, so an
@@ -2352,6 +2408,19 @@ class FFModel:
         params_in, batch_in = self._eval_inputs()
         _, probs = self._eval_step_fn(params_in, self._stats, batch_in)
         return np.asarray(probs)
+
+    def logits_batch(self) -> jax.Array:
+        """What the loss reads for the staged batch, on the device and in
+        the compute dtype: the logits where a trailing Softmax fuses with
+        the loss, else the final tensor.  A forward pass of the graph as
+        the train step runs it (no dropout); it trains nothing."""
+        if self._logits_fn is None:
+            read_t = self._loss_input_tensor()
+            self._logits_fn = jax.jit(
+                lambda params, stats, batch: self._run_graph(
+                    params, stats, batch, False, None)[0][read_t.guid])
+        params_in, batch_in = self._eval_inputs()
+        return self._logits_fn(params_in, self._stats, batch_in)
 
     # ------------------------------------------------------------------
     # autoregressive generation (beyond the reference, which is
@@ -2853,6 +2922,7 @@ class FFModel:
         if self._stepstats is not None:
             self._stepstats.on_drain()  # a sync point: the rate's interval
         totals = dict(zip(self._metric_keys(), [float(v) for v in vec]))
+        _ff_count({k: totals.pop(k) for k in self._op_counter_keys()})
         steps = totals.pop("steps", 0.0)
         loss_sum = totals.pop("loss", None)
         if steps > 0 and loss_sum is not None:

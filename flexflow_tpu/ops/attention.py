@@ -25,6 +25,7 @@ from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .base import FwdCtx, Op
 from ..initializers import ConstantInitializer, DefaultWeightInitializer, ZeroInitializer
@@ -64,7 +65,40 @@ class LayerNorm(Op):
         return 8.0 * float(np.prod(self.output.dims[1:]))
 
 
-class MultiHeadAttention(Op):
+class _PicksAttentionImpl:
+    """How an attention op chooses its per-chip core (the Pallas flash
+    kernel or XLA's ``blockwise_attention``) and records the choice."""
+
+    # None: chosen by platform and shape at trace time (_pick_impl).
+    # A test sets "pallas_interpret" (the kernel in the Pallas
+    # interpreter, any backend), "pallas" or "xla" by name.
+    impl: Optional[str] = None
+    # (impl, why) of the last trace — what actually ran.
+    impl_used: Optional[Tuple[str, str]] = None
+
+    def _pick_impl(self, seq_q: int, seq_k: int) -> Tuple[str, str]:
+        """(impl, why) for one chip's (seq_q, seq_k) attention block:
+        the Pallas kernel on a TPU when it can tile the shape, else the
+        XLA path — and on a TPU that is worth a warning, because the
+        shape is then running without the kernel written for it."""
+        if self.impl is not None:
+            if self.impl not in ("pallas", "pallas_interpret", "xla"):
+                raise ValueError(f"{self.name}: unknown attention impl "
+                                 f"{self.impl!r}")
+            return self.impl, "set on the op"
+        from ..kernels.flash_attention import unsupported_reason
+
+        platform = self.model.machine.devices[0].platform
+        if platform != "tpu":
+            return "xla", f"platform is {platform}"
+        why = unsupported_reason(seq_q, seq_k)
+        if why is not None:
+            warnings.warn(f"{self.name}: {why}; using XLA attention")
+            return "xla", why
+        return "pallas", "platform is tpu"
+
+
+class MultiHeadAttention(_PicksAttentionImpl, Op):
     """Scaled-dot-product multi-head attention with QKV/output projections.
 
     query/key/value: (B, Sq, E) / (B, Sk, E) / (B, Sk, E).  Output
@@ -89,12 +123,6 @@ class MultiHeadAttention(Op):
         self.dropout = dropout
         self.use_bias = use_bias
         self.seq_parallel_mode = seq_parallel_mode
-        # None: chosen by platform and shape at trace time (_pick_impl).
-        # A test sets "pallas_interpret" (the kernel in the Pallas
-        # interpreter, any backend), "pallas" or "xla" by name.
-        self.impl: Optional[str] = None
-        # (impl, why) of the last trace — what actually ran.
-        self.impl_used: Optional[Tuple[str, str]] = None
         b, sq, _ = query.dims
         self._add_output((b, sq, embed_dim), query.dtype)
         init = kernel_initializer or DefaultWeightInitializer()
@@ -131,27 +159,6 @@ class MultiHeadAttention(Op):
         if pc is None or len(pc.dims) < 2:
             return 1
         return pc.dims[1]
-
-    def _pick_impl(self, seq_q: int, seq_k: int) -> Tuple[str, str]:
-        """(impl, why) for one chip's (seq_q, seq_k) attention block:
-        the Pallas kernel on a TPU when it can tile the shape, else the
-        XLA path — and on a TPU that is worth a warning, because the
-        shape is then running without the kernel written for it."""
-        if self.impl is not None:
-            if self.impl not in ("pallas", "pallas_interpret", "xla"):
-                raise ValueError(f"{self.name}: unknown attention impl "
-                                 f"{self.impl!r}")
-            return self.impl, "set on the op"
-        from ..kernels.flash_attention import unsupported_reason
-
-        platform = self.model.machine.devices[0].platform
-        if platform != "tpu":
-            return "xla", f"platform is {platform}"
-        why = unsupported_reason(seq_q, seq_k)
-        if why is not None:
-            warnings.warn(f"{self.name}: {why}; using XLA attention")
-            return "xla", why
-        return "pallas", "platform is tpu"
 
     def forward(self, params, xs: List[jax.Array], ctx: FwdCtx):
         q_in, k_in, v_in = xs
@@ -336,3 +343,191 @@ class MultiHeadAttention(Op):
             in_dims = self.inputs[j].dims
             rng[1] = (0, in_dims[1] - 1)
         return rng
+
+
+# ---------------------------------------------------------------------------
+# Rotary positions with YaRN's frequency blend, and latent attention
+# ---------------------------------------------------------------------------
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor: 0.1 * mscale * ln(factor) + 1
+    (1 where the context is not extended)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float = 10000.0, factor: float = 1.0,
+                  original_max_position_embeddings: int = 4096,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0, **_):
+    """The ``dim // 2`` rotary frequencies under YaRN (Peng et al. 2023):
+    ``theta^(-2i/dim)`` for the dimensions that turn more than
+    ``beta_fast`` times over the original context, that over ``factor``
+    for those that turn fewer than ``beta_slow`` times, and a linear ramp
+    between the two correction dims.  float64 numpy; ``factor`` 1 gives
+    plain rotary frequencies."""
+    extra = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if factor <= 1:
+        return extra
+
+    def correction_dim(turns):
+        return dim * math.log(original_max_position_embeddings
+                              / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def rotate_pairs(x, inv_freq, scale: float = 1.0):
+    """Rotary position embedding of x (B, S, ..., d) along axis 1, on
+    adjacent pairs (x[2i], x[2i+1]) by the angle ``position * inv_freq[i]``.
+    The result holds the first elements of the rotated pairs, then the
+    second: a fixed permutation of the pairs' layout, which a dot product
+    of two vectors rotated here does not see."""
+    s = x.shape[1]
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] \
+        * jnp.asarray(inv_freq, jnp.float32)[None, :]
+    shape = (1, s) + (1,) * (x.ndim - 3) + (ang.shape[-1],)
+    cos = (jnp.cos(ang) * scale).reshape(shape)
+    sin = (jnp.sin(ang) * scale).reshape(shape)
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (-1, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    return jnp.concatenate([a * cos - b * sin, a * sin + b * cos],
+                           axis=-1).astype(x.dtype)
+
+
+class LatentAttention(_PicksAttentionImpl, Op):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434, section
+    2.1), causal self-attention over (B, S, E) for training:
+
+        c_q = norm(x W_DQ);  [q_nope | q_pe] = c_q W_UQ        per head
+        [c_kv | k_pe] = x W_DKV;  [k_nope | v] = norm(c_kv) W_UKV  per head
+        score = (q_nope . k_nope + rot(q_pe) . rot(k_pe)) * scale
+        out = concat_h(softmax(score) v) W_O
+
+    with RMSNorms on the two latents, ``k_pe`` one vector shared by every
+    head, rotary positions on adjacent pairs with YaRN's frequencies
+    (``rope_scaling``), and ``scale = (nope + rope)^-0.5 * m^2``, ``m``
+    YaRN's mscale of ``mscale_all_dim``.  No bias.
+
+    ``num_heads`` is the heads this op holds: with fewer than the model
+    has, ``W_UQ``, ``W_UKV`` carry those heads' columns, ``W_O`` their
+    rows, and the output is that share's partial sum.  Config dim 2 is
+    head parallelism over the held heads (the up-projections' columns and
+    ``W_O``'s rows shard over it; the down-projections and their norms
+    are replicated); the output is placed by the batch degree alone.
+    The core is the flash kernel at query/key head ``nope + rope`` and
+    value head ``v_head_dim`` on a TPU, XLA's blockwise attention
+    elsewhere (``impl_used``)."""
+
+    _type = "LatentAttention"
+
+    def __init__(self, model, input_tensor, num_heads: int, q_lora_rank: int,
+                 kv_lora_rank: int, qk_nope_head_dim: int,
+                 qk_rope_head_dim: int, v_head_dim: int,
+                 rope_theta: float = 10000.0, rope_scaling=None,
+                 eps: float = 1e-6, kernel_initializer=None,
+                 name: Optional[str] = None):
+        super().__init__(model, [input_tensor], name)
+        b, s, e = input_tensor.dims
+        self.num_heads = int(num_heads)
+        self.q_lora_rank, self.kv_lora_rank = int(q_lora_rank), int(kv_lora_rank)
+        self.nope, self.rope, self.v_dim = (int(qk_nope_head_dim),
+                                            int(qk_rope_head_dim),
+                                            int(v_head_dim))
+        self.eps = eps
+        scaling = dict(rope_scaling or {})
+        factor = float(scaling.get("factor", 1.0))
+        self.inv_freq = yarn_inv_freq(self.rope, rope_theta, **scaling)
+        self.rope_scale = (yarn_mscale(factor, scaling.get("mscale", 1.0))
+                           / yarn_mscale(factor,
+                                         scaling.get("mscale_all_dim", 0.0)))
+        self.softmax_scale = (self.nope + self.rope) ** -0.5 * yarn_mscale(
+            factor, scaling.get("mscale_all_dim", 0.0)) ** 2
+        self._add_output((b, s, e), input_tensor.dtype)
+        init = kernel_initializer or DefaultWeightInitializer()
+        h = self.num_heads
+        self._add_weight("w_dq", (e, self.q_lora_rank), init)
+        self._add_weight("q_norm", (self.q_lora_rank,),
+                         ConstantInitializer(1.0))
+        self._add_weight("w_uq", (self.q_lora_rank,
+                                  h * (self.nope + self.rope)), init,
+                         partition_dims=(None, 2))
+        self._add_weight("w_dkv", (e, self.kv_lora_rank + self.rope), init)
+        self._add_weight("kv_norm", (self.kv_lora_rank,),
+                         ConstantInitializer(1.0))
+        self._add_weight("w_ukv", (self.kv_lora_rank,
+                                   h * (self.nope + self.v_dim)), init,
+                         partition_dims=(None, 2))
+        self._add_weight("w_o", (h * self.v_dim, e), init,
+                         partition_dims=(2, None))
+
+    def _config_dim_bound(self, i: int):
+        if i == 1:
+            return 1   # no sequence parallelism: the ring is MHA's
+        if i == 2:
+            return self.num_heads
+        return super()._config_dim_bound(i)
+
+    constraint_pc = Op.batch_only_pc
+
+    def cost_key(self) -> str:
+        return (f"mla{self.num_heads}q{self.q_lora_rank}kv{self.kv_lora_rank}"
+                f"d{self.nope}.{self.rope}.{self.v_dim}")
+
+    def forward(self, params, xs: List[jax.Array], ctx: FwdCtx):
+        from .linear import project
+        from .misc import rms_norm
+
+        x = xs[0]
+        b, s, _ = x.shape
+        h, dn, dr, dv = self.num_heads, self.nope, self.rope, self.v_dim
+        with jax.named_scope("ff.mla.q_proj"):
+            c_q = rms_norm(project(x, params["w_dq"]), params["q_norm"],
+                           self.eps)
+            q = project(c_q, params["w_uq"]).reshape(b, s, h, dn + dr)
+        with jax.named_scope("ff.mla.kv_proj"):
+            ckv = project(x, params["w_dkv"])
+            c_kv = rms_norm(ckv[..., :self.kv_lora_rank], params["kv_norm"],
+                            self.eps)
+            kv = project(c_kv, params["w_ukv"]).reshape(b, s, h, dn + dv)
+        with jax.named_scope("ff.mla.rope"):
+            q_pe = rotate_pairs(q[..., dn:], self.inv_freq, self.rope_scale)
+            k_pe = rotate_pairs(ckv[..., self.kv_lora_rank:], self.inv_freq,
+                                self.rope_scale)
+            k_pe = jnp.broadcast_to(k_pe[:, :, None, :], (b, s, h, dr))
+            heads = lambda t: t.transpose(0, 2, 1, 3)     # (B, H, S, d)
+            qh = heads(jnp.concatenate([q[..., :dn], q_pe], axis=-1))
+            kh = heads(jnp.concatenate([kv[..., :dn], k_pe], axis=-1))
+            vh = heads(kv[..., dn:])
+        impl, why = self._pick_impl(s, s)
+        self.impl_used = (impl, why)
+        if impl == "xla":
+            from ..parallel.sequence import blockwise_attention
+            oh, _ = blockwise_attention(qh, kh, vh, causal=True,
+                                        scale=self.softmax_scale)
+        else:
+            from ..kernels.flash_attention import flash_attention
+            oh = flash_attention(qh, kh, vh, causal=True,
+                                 scale=self.softmax_scale,
+                                 interpret=impl == "pallas_interpret")
+        with jax.named_scope("ff.mla.o_proj"):
+            out = project(oh.transpose(0, 2, 1, 3).reshape(b, s, h * dv),
+                          params["w_o"])
+        return [out]
+
+    def decode(self, params, xs, cache, pos, ctx):
+        raise NotImplementedError(
+            f"{self.name}: LatentAttention has no decode path (a cache "
+            f"would hold the latent and the shared rotary key, not heads)")
+
+    def flops_per_sample(self):
+        _, s, e = self.output.dims
+        h, dn, dr, dv = self.num_heads, self.nope, self.rope, self.v_dim
+        proj = 2.0 * s * (e * self.q_lora_rank
+                          + self.q_lora_rank * h * (dn + dr)
+                          + e * (self.kv_lora_rank + dr)
+                          + self.kv_lora_rank * h * (dn + dv)
+                          + h * dv * e)
+        return proj + 2.0 * h * s * s * (dn + dr + dv)

@@ -35,6 +35,9 @@ class FwdCtx:
     rng: Optional[jax.Array] = None  # folded per-op by guid before use
     stats_in: Optional[Dict[str, Dict[str, jax.Array]]] = None
     stats_out: Optional[Dict[str, Dict[str, jax.Array]]] = None
+    # Scalars an op adds to under the names of its ``COUNTERS``; the train
+    # step sums them into its metric vector.  None outside a train step.
+    counters: Optional[Dict[str, jax.Array]] = None
 
     def op_rng(self, op: "Op") -> jax.Array:
         assert self.rng is not None, "op requires an RNG but none was provided"
@@ -45,6 +48,8 @@ class Op:
     """Graph node: inputs → outputs with optional weights/state."""
 
     _type: str = "Op"
+    # names of the per-step scalars this op adds to ``FwdCtx.counters``
+    COUNTERS: Sequence[str] = ()
 
     def __init__(self, model, inputs: Sequence[Tensor], name: Optional[str] = None):
         self.model = model
@@ -104,6 +109,15 @@ class Op:
         Defaults to the op's own config; ops whose config dims carry
         non-layout meaning (e.g. the pipeline degree) override this."""
         return self.pc
+
+    def batch_only_pc(self):
+        """A ``constraint_pc`` for ops whose other config dims place
+        weights, not outputs (an expert, head or width degree whose
+        shards give partial sums): the output is batch-sharded only."""
+        from ..config import ParallelConfig
+
+        return ParallelConfig(dims=(self.pc.dims[0],)
+                              + (1,) * (self.output.num_dims - 1))
 
     def _config_dim_bound(self, i: int) -> Optional[int]:
         """The size config dim ``i``'s degree must divide (None: no

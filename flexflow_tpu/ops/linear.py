@@ -79,3 +79,70 @@ class Linear(Op):
         in_dims = self.inputs[0].dims
         rng[-1] = (0, in_dims[-1] - 1)
         return rng
+
+
+def project(x, w):
+    """x @ w with w cast to x's dtype; float32 accumulation for bfloat16
+    operands, the result in x's dtype.  No bias."""
+    acc = jnp.float32 if x.dtype == jnp.bfloat16 else None
+    return jnp.dot(x, w.astype(x.dtype),
+                   preferred_element_type=acc).astype(x.dtype)
+
+
+def gated_mlp(x, w_gate, w_up, w_down):
+    """(silu(x W_gate) * (x W_up)) W_down: the SiLU-gated MLP of the
+    decoders after GPT-2 (Shazeer 2020, "GLU Variants")."""
+    return project(jax.nn.silu(project(x, w_gate)) * project(x, w_up), w_down)
+
+
+class GatedMLP(Op):
+    """A SiLU-gated MLP of width ``width`` with no bias: one op, so that
+    a strategy splits the width of all three matrices together.  Config
+    dim ``last`` is that split: ``w_gate`` and ``w_up`` shard their
+    columns over it and ``w_down`` its rows, each shard's product is a
+    partial sum of the output, and the output itself is placed by the
+    batch degree alone (``constraint_pc``)."""
+
+    _type = "GatedMLP"
+
+    def __init__(self, model, input_tensor, width: int,
+                 kernel_initializer=None, name: Optional[str] = None):
+        super().__init__(model, [input_tensor], name)
+        dims = input_tensor.dims
+        d = dims[-1]
+        self.width = int(width)
+        self._add_output(dims, input_tensor.dtype)
+        init = kernel_initializer or DefaultWeightInitializer()
+        split = len(dims) - 1
+        self._add_weight("w_gate", (d, self.width), init,
+                         partition_dims=(None, split))
+        self._add_weight("w_up", (d, self.width), init,
+                         partition_dims=(None, split))
+        self._add_weight("w_down", (self.width, d), init,
+                         partition_dims=(split, None))
+
+    def _config_dim_bound(self, i: int):
+        if i == self.output.num_dims - 1:
+            return self.width
+        return super()._config_dim_bound(i)
+
+    constraint_pc = Op.batch_only_pc
+
+    def cost_key(self) -> str:
+        return f"w{self.width}"
+
+    def forward(self, params, xs: List[jax.Array], ctx: FwdCtx):
+        return [gated_mlp(xs[0], params["w_gate"], params["w_up"],
+                          params["w_down"])]
+
+    def flops_per_sample(self):
+        tokens = 1
+        for dim in self.output.dims[1:-1]:
+            tokens *= dim
+        return 6.0 * tokens * self.output.dims[-1] * self.width
+
+    def input_ranges(self, j, pc, part_idx):
+        """A width shard reads the whole input feature dim."""
+        rng = super().input_ranges(j, pc, part_idx)
+        rng[-1] = (0, self.inputs[0].dims[-1] - 1)
+        return rng

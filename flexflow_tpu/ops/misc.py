@@ -1,4 +1,4 @@
-"""Flat / Softmax / Concat / Dropout / element-wise operators.
+"""Flat / Softmax / Concat / Dropout / RMSNorm / element-wise operators.
 
 Reference files: src/ops/flat.cu (cross-rank partition copy),
 src/ops/softmax.cu (cudnnSoftmaxForward ACCURATE), src/ops/concat.cu,
@@ -16,6 +16,7 @@ from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .base import FwdCtx, Op
 
@@ -225,6 +226,39 @@ class BatchNorm(Op):
         if self.relu:
             y = jax.nn.relu(y)
         return [y]
+
+
+def rms_norm(x, scale, eps: float):
+    """x / sqrt(mean(x^2) + eps) * scale over the last dim, computed in
+    float32 and returned in x's dtype."""
+    xf = x.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+    return (xf * inv * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+class RMSNorm(Op):
+    """Root-mean-square normalisation over the last dim with a learned
+    scale and no shift (Zhang & Sennrich 2019): what the decoders after
+    GPT-2 use where it has LayerNorm.  No reference counterpart."""
+
+    _type = "RMSNorm"
+
+    def __init__(self, model, input_tensor, eps: float = 1e-6,
+                 name: Optional[str] = None):
+        super().__init__(model, [input_tensor], name)
+        from ..initializers import ConstantInitializer
+
+        self.eps = eps
+        dims = input_tensor.dims
+        self._add_output(dims, input_tensor.dtype)
+        self._add_weight("scale", (dims[-1],), ConstantInitializer(1.0),
+                         partition_dims=(len(dims) - 1,))
+
+    def forward(self, params, xs: List[jax.Array], ctx: FwdCtx):
+        return [rms_norm(xs[0], params["scale"], self.eps)]
+
+    def flops_per_sample(self):
+        return 4.0 * float(np.prod(self.output.dims[1:]))
 
 
 class MSELoss(Op):

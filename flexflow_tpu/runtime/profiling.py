@@ -19,7 +19,10 @@ Reference instrumentation (SURVEY §5.1): per-op cudaEvent timers behind
     join between a device trace and the ``jax.named_scope``s of
     ``FFModel._build_train_step``,
   * ``counters()`` — process-wide counters: how often the train step
-    was compiled, and for how long,
+    was compiled, and for how long, and what the graph's ops counted in
+    their steps (``Op.COUNTERS``: the routed experts' assignments made,
+    kept and dropped, and their load), which arrive with the metric
+    drain (``count``),
   * ``op_profile(model)`` — per-op forward/backward wall times, measured
     by compiling and timing each op standalone on the real device, the
     way the reference's ``measure_compute_time`` does per-op benchmarks;
@@ -94,8 +97,29 @@ def counters() -> Dict[str, float]:
     """Process-wide counters: ``train_step_compiles``, the XLA
     compilations (or persistent-cache fetches) that happened inside a
     train step's call, over every model of the process, and
-    ``train_step_compile_s``, their seconds."""
-    return dict(_counters)
+    ``train_step_compile_s``, their seconds.  Where a model with routed
+    experts has drained its metrics, also the sums its layers counted
+    (``ops/moe.py``, ``RoutedExperts.COUNTERS``) and what follows from
+    them over all drained steps: ``moe_assignments_made_per_token`` and
+    ``moe_assignments_kept_per_token`` (a token and expert layer),
+    ``moe_dropped_share`` (of the assignments made, those the device
+    budget dropped) and ``moe_load_max_over_mean`` (the held experts'
+    largest kept load over their mean, averaged over layers and steps)."""
+    out = dict(_counters)
+    tokens = out.get("moe_tokens")
+    if tokens:
+        made, kept = out["moe_assignments_made"], out["moe_assignments_kept"]
+        out["moe_assignments_made_per_token"] = made / tokens
+        out["moe_assignments_kept_per_token"] = kept / tokens
+        out["moe_dropped_share"] = (made - kept) / made if made else 0.0
+        out["moe_load_max_over_mean"] /= out["moe_layers"]
+    return out
+
+
+def count(sums: Dict[str, float]) -> None:
+    """Add what a model's ops counted since its last drain."""
+    for name, value in sums.items():
+        _counters[name] = _counters.get(name, 0.0) + value
 
 
 @contextlib.contextmanager
@@ -145,7 +169,10 @@ def _opcode(rest: str) -> str:
 
 
 def scope_of(op_name: str) -> Dict[str, Optional[str]]:
-    """Scope, phase and kernel of one instruction's ``op_name``.
+    """Scope, phase and kernel of one instruction's ``op_name``; where
+    the graph op (the outermost ``ff.`` name, the scope) opened a scope of
+    its own inside itself (``ff.mla.q_proj``, ``ff.moe.experts``), also
+    ``span``, the innermost of them.
 
     ``bwd`` where the name holds ``transpose(`` (the recomputed forward
     of a ``jax.checkpoint`` is there too, where its time is spent), else
@@ -154,7 +181,8 @@ def scope_of(op_name: str) -> Dict[str, Optional[str]]:
     names = _FF_SCOPE.findall(op_name)
     kernel = next((n[len(_KERNEL):] for n in names if n.startswith(_KERNEL)),
                   None)
-    scope = next((n for n in names if not n.startswith(_KERNEL)), None)
+    outer = [n for n in names if not n.startswith(_KERNEL)]
+    scope = outer[0] if outer else None
     if "transpose(" in op_name:
         phase = "bwd"
     elif scope == SPAN_PREFIX + "optimizer":
@@ -163,7 +191,10 @@ def scope_of(op_name: str) -> Dict[str, Optional[str]]:
         phase = "fwd"
     else:
         phase = "other"
-    return {"scope": scope, "phase": phase, "kernel": kernel}
+    out = {"scope": scope, "phase": phase, "kernel": kernel}
+    if len(outer) > 1 and outer[-1] != scope:
+        out["span"] = outer[-1]
+    return out
 
 
 def parse_hlo_scopes(text: str) -> Dict[str, Dict[str, object]]:
@@ -267,6 +298,7 @@ def step_scopes() -> Dict[str, List[Dict[str, Dict[str, object]]]]:
         {module name: [{instruction name: {"scope": "ff.op.conv2d.conv1",
                                            "phase": "fwd"|"bwd"|"opt"|"other",
                                            "kernel": "flash_fwd"|None,
+                                           ["span": "ff.moe.route",]
                                            "mixed": bool}}, ...]}
 
     one entry of the list for each loaded program of that name (the

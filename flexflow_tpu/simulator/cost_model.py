@@ -354,6 +354,8 @@ class CostModel:
             extra = f"k{op.kernel}s{op.stride}"
         if hasattr(op, "hidden_size"):
             extra = f"h{op.hidden_size}"
+        if hasattr(op, "cost_key"):  # sizes neither shape nor width shows
+            extra = op.cost_key()
         return (f"{op._type}:{sub}:{ins}:{extra}:"
                 f"{self.compute_dtype}:{which}")
 

@@ -57,6 +57,10 @@ _SPLITTABLE = {
     "ExpertMLP": (0, 1),       # dim 1 = expert-parallel degree
     "MultiHeadAttention": (0, 1, 2),  # batch, seq (ring), head TP
     "LayerNorm": (0, 1),       # batch, seq
+    "RMSNorm": (0, 1),         # batch, seq
+    "GatedMLP": (0, "last"),   # batch, the width of all three matrices
+    "LatentAttention": (0, 2),  # batch, the held heads
+    "RoutedExperts": (0, 1),   # dim 1 = expert-parallel degree
 }
 
 

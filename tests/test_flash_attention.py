@@ -188,3 +188,33 @@ def test_tiling_follows_a_block_override_and_the_shape_alone():
     assert t["flash_fwd"]["body_steps"] == sum(
         1 for qi in range(8) for ki in range(4) if ki * 256 <= qi * 128 + 127)
     assert tiling(1024, 1024, 64, True) == tiling(1024, 1024, 64, True)
+
+
+# latent attention's heads: query and key 192 wide (128 without position,
+# 64 rotary), value 128, and a scale that is neither 1/sqrt(d) nor a
+# power of two; several blocks a sequence, a diagonal block in bands
+@pytest.mark.parametrize("dtype,tol", [(jnp.bfloat16, 2e-2),
+                                       (jnp.float32, 1e-5)])
+@pytest.mark.parametrize("block", [128, 256])
+def test_query_key_head_wider_than_value_head(block, dtype, tol):
+    ks = jax.random.split(jax.random.key(3), 4)
+    q, k = (jax.random.normal(kk, (2, 2, 256, 192), jnp.float32).astype(dtype)
+            for kk in ks[:2])
+    v = jax.random.normal(ks[2], (2, 2, 256, 128), jnp.float32).astype(dtype)
+    w = jax.random.normal(ks[3], (2, 2, 256, 128), jnp.float32)
+    scale = 192 ** -0.5 * (0.1 * 0.707 * np.log(40) + 1) ** 2
+
+    def graded(attention):
+        def f(q, k, v):
+            o = attention(q, k, v, causal=True, scale=scale)
+            return jnp.sum(o.astype(jnp.float32) * w), o
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    (_, o), g = graded(_flash(block))(q, k, v)
+    (_, o_ref), g_ref = graded(mha_reference)(q, k, v)
+    assert o.shape == (2, 2, 256, 128) and o.dtype == dtype
+    for name, got, want in zip(("out", "dq", "dk", "dv"), (o,) + g,
+                               (o_ref,) + g_ref):
+        assert got.shape == want.shape and got.dtype == want.dtype, name
+        a, r = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        assert np.abs(a - r).max() <= tol * np.abs(r).max(), name

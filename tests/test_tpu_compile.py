@@ -1,0 +1,68 @@
+"""The kernels of the DeepSeek-V2 cell at their real widths, compiled for a
+v5e that is described and not attached: what the chip's compiler refuses
+(a block Mosaic cannot tile, too much VMEM) costs no chip time.  Nothing
+runs, so nothing here is a result or a time.  The topology is described
+inside a fixture, in this one file, so that only the worker that runs
+these tests loads the TPU's library."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from flexflow_tpu.kernels.flash_attention import flash_attention
+from flexflow_tpu.kernels.grouped_matmul import grouped_matmul
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a program compiled for a described chip is written to the
+    # persistent cache and cannot be read back without one
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+
+
+def _custom_calls(compiled):
+    return compiled.as_text().count("tpu_custom_call")
+
+
+def test_flash_kernels_at_latent_attention_heads(one_chip):
+    """2 sequences of 4096, 8 heads, query/key 192 and value 128."""
+    def spec(d):
+        return jax.ShapeDtypeStruct((2, 8, 4096, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def f(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True,
+                                       scale=0.1147).astype(jnp.float32))
+    compiled = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(
+        spec(192), spec(192), spec(128)).compile()
+    assert _custom_calls(compiled) >= 3        # forward, dq, dkv
+
+
+@pytest.mark.parametrize("k,n", [(5120, 1536), (1536, 5120)])
+def test_grouped_products_at_expert_widths(one_chip, k, n):
+    """The budget's buffer of the cell: 24 + 10 tiles of 128 rows, 10
+    experts, float32 weights, bfloat16 rows; up and down projection."""
+    tiles = 34
+    x = jax.ShapeDtypeStruct((tiles * 128, k), jnp.bfloat16,
+                             sharding=one_chip)
+    w = jax.ShapeDtypeStruct((10, k, n), jnp.float32, sharding=one_chip)
+    tg = jax.ShapeDtypeStruct((tiles,), jnp.int32, sharding=one_chip)
+
+    def f(x, w, tg):
+        return jnp.sum(jnp.sin(grouped_matmul(x, w, tg, tile_m=128)
+                               .astype(jnp.float32)))
+    compiled = jax.jit(jax.value_and_grad(f, argnums=(0, 1))).lower(
+        x, w, tg).compile()
+    assert _custom_calls(compiled) >= 3        # gmm, gmm_t, tgmm

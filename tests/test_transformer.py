@@ -362,3 +362,69 @@ def test_generate_compile_cache_reuse(devices):
     assert len(m._gen_cache) == 1  # one sampled-scan executable
     m.generate(prompt, 3)          # greedy variant adds exactly one more
     assert len(m._gen_cache) == 2
+
+
+# ---------------------------------------------------------------------------
+# build_transformer is build_decoder with GPT-2's parts: the graph it built
+# before it took its parts as arguments (PR 31's builder, copied below) and
+# the one it builds now are the same program
+# ---------------------------------------------------------------------------
+
+def _build_transformer_pr31(m, batch_size, seq_length, num_layers, embed_dim,
+                            num_heads, vocab_size, mlp_ratio=4, moe_every=0,
+                            num_experts=8):
+    from flexflow_tpu.ops.embedding import AggrMode
+
+    tok = m.create_tensor((batch_size, seq_length), name="tokens",
+                          dtype="int32", nchw=False)
+    pos = m.create_tensor((batch_size, seq_length), name="positions",
+                          dtype="int32", nchw=False)
+    x = m.embedding(tok, vocab_size, embed_dim, aggr=AggrMode.NONE,
+                    name="tok_embed")
+    p = m.embedding(pos, seq_length, embed_dim, aggr=AggrMode.NONE,
+                    name="pos_embed")
+    x = m.add(x, p, name="embed_add")
+    for i in range(num_layers):
+        h = m.layer_norm(x, name=f"ln1_{i}")
+        h = m.multihead_attention(h, num_heads=num_heads, causal=True,
+                                  dropout=0.0, name=f"attn_{i}")
+        x = m.add(x, h, name=f"res_attn_{i}")
+        h = m.layer_norm(x, name=f"ln2_{i}")
+        if moe_every and (i + 1) % moe_every == 0:
+            h = m.expert_mlp(h, num_experts=num_experts,
+                             hidden_size=embed_dim * mlp_ratio,
+                             activation="gelu", name=f"moe_{i}")
+        else:
+            h = m.dense(h, embed_dim * mlp_ratio, activation="gelu",
+                        name=f"mlp_up_{i}")
+            h = m.dense(h, embed_dim, name=f"mlp_down_{i}")
+        x = m.add(x, h, name=f"res_mlp_{i}")
+    x = m.layer_norm(x, name="ln_f")
+    return tok, pos, m.softmax(m.dense(x, vocab_size, name="lm_head"),
+                               name="softmax")
+
+
+@pytest.mark.parametrize("moe_every", [0, 2])
+def test_gpt2_graph_is_the_one_it_was(devices, moe_every):
+    sizes = dict(seq_length=S, num_layers=2, embed_dim=E, num_heads=HEADS,
+                 vocab_size=V, moe_every=moe_every, num_experts=4)
+    models = []
+    for builder in (_build_transformer_pr31, build_transformer):
+        cfg = ff.FFConfig(batch_size=B, compute_dtype="bfloat16")
+        m = ff.FFModel(cfg)
+        tok, pos, _ = builder(m, B, **sizes)
+        m.compile(ff.AdamOptimizer(m, alpha=1e-4),
+                  ff.LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
+                  [ff.MetricsType.ACCURACY])
+        m.init_layers(seed=0)
+        toks, pos_arr, labels = _batch(np.random.default_rng(0))
+        m.set_batch({tok: toks, pos: pos_arr}, labels)
+        models.append(m)
+    was, now = models
+    assert [(op._type, op.name) for op in now.ops] \
+        == [(op._type, op.name) for op in was.ops]
+    params = lambda m: sorted(k for k in m.placement()
+                              if not k.startswith("batch/"))
+    assert params(now) == params(was)
+    assert now._metric_keys() == was._metric_keys()
+    assert now.train_step_hlo() == was.train_step_hlo()
