@@ -25,7 +25,9 @@ out by the repo's own means:
                    accuracy read from the logits (no instruction under
                    the final Softmax's scope; the line gives the device
                    time a step under ff.metrics and under that scope,
-                   from a profile of three more steps); then
+                   from a profile of three more steps; and which form
+                   each embedding's table gradient took,
+                   Embedding.grad_impl_used); then
                    serving.InferenceEngine over the same model answers
                    four requests of mixed prompt length and its tokens
                    are compared with FFModel.generate().
@@ -386,6 +388,8 @@ def phase_transformer(sz, dev, stats):
     result("transformer", layers=sz["layers"], embed=sz["embed"],
            heads=sz["heads"], seq=seq, batch=b, dtype="bfloat16",
            attention=want, tpu_custom_calls_in_step=calls, losses=losses,
+           embedding_grad={op.name: op.grad_impl_used[0] for op in model.ops
+                           if op._type == "Embedding"},
            **tail)
 
     # the second surface on the same graph: the serving engine

@@ -82,6 +82,9 @@ def test_smoke_tiny_mode_runs_every_phase():
     assert said["loss_instructions"] > 0
     assert said["loss_ms_per_step"] is None
     assert said["loss_classwide_f32_instructions"] is None
+    # and the form of each embedding's table gradient (rows 64 wide)
+    assert said["embedding_grad"] == {"tok_embed": "scatter_add",
+                                      "pos_embed": "scatter_add"}
     # every line the script prints says where it ran (the package's own
     # notices start "flexflow_tpu:"), and none of them is a result line
     assert all("platform=cpu" in ln for ln in lines

@@ -136,7 +136,9 @@ def test_every_entry_instruction_has_a_phase(devices, kind):
     assert len(names) > 20
     for name in names:
         assert scopes[name]["phase"] in PHASES, name
-        assert set(scopes[name]) == {"scope", "phase", "kernel", "mixed"}
+        # `span` where the op opened a scope of its own (ff.embed.*)
+        assert set(scopes[name]) - {"span"} == {"scope", "phase", "kernel",
+                                                "mixed"}
     # and the process-wide map holds this program under its module's name
     assert any(scopes == loaded
                for loaded in profiling.step_scopes()["jit_step"])
