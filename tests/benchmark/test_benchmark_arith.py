@@ -21,7 +21,12 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     # the three cells' synced single steps at block_min_ms 300, and how
     # far each may move before k does
     (0.082, 300, 4), (0.076, 300, 4), (0.099, 300, 4),
-    (0.113, 300, 3), (0.101, 300, 3), (0.149, 300, 3), (0.088, 300, 4)])
+    (0.113, 300, 3), (0.101, 300, 3), (0.149, 300, 3), (0.088, 300, 4),
+    # since PR 34: AlexNet on one chip at 270 (k = 4 from 67.5 to 90 ms)
+    # and DeepSeek-V2 at 1600 (k = 6 from 266.7 to 320), each in the middle
+    (0.0784, 270, 4), (0.068, 270, 4), (0.0899, 270, 4), (0.0901, 270, 3),
+    (0.0674, 270, 5), (0.298, 1600, 6), (0.267, 1600, 6), (0.3199, 1600, 6),
+    (0.3201, 1600, 5), (0.2666, 1600, 7)])
 def test_steps_per_block(step_s, min_ms, want):
     assert steps_per_block(step_s, min_ms) == want
 
@@ -193,27 +198,30 @@ def test_transformer_flops_by_hand():
 
 
 # --------------------------------------------------------------------------
-# BENCHMARK.json against the contract's limits
+# BENCHMARK.json against the contract's limits: the repo's, and the copy
+# that has grown as later PRs grow it (conftest.py), so that none of this
+# holds only of the file as it happens to stand
 # --------------------------------------------------------------------------
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
 
-@pytest.fixture(scope="module")
-def bench():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+@pytest.fixture
+def bench(bench_root):
+    with open(os.path.join(bench_root, "BENCHMARK.json")) as f:
         return json.load(f)
 
 
-def test_benchmark_json_keys_and_names(bench):
+def test_benchmark_json_keys_and_names(bench, bench_root):
     assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
-    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) < 64 * 1024
+    assert os.path.getsize(
+        os.path.join(bench_root, "BENCHMARK.json")) < 64 * 1024
     for c in bench["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert any(c["file"].startswith(p + "/") for p in bench["paths"])
-        assert os.path.isfile(os.path.join(REPO, c["file"]))
+        assert os.path.isfile(os.path.join(bench_root, c["file"]))
     for w in bench["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         assert w["chips"] in (1, 4) and NAME.match(w["traffic"])
@@ -243,14 +251,16 @@ def test_benchmark_json_keys_and_names(bench):
     assert "setup_s" in {m["name"] for m in bench["end_to_end"]}
 
 
-def test_benchmark_json_cells_and_budget(bench):
+def test_benchmark_json_cells_and_budget(bench, bench_root):
     cells = bench["workloads"]
     pairs = [(w["config"], w["traffic"]) for w in cells]
     assert len(set(pairs)) == len(pairs)
     assert {w["config"] for w in cells} == {c["name"]
                                             for c in bench["configs"]}
+    assert 1 <= len(cells) <= 24 and 1 <= len(bench["configs"]) <= 24
     four = sum(w["chips"] == 4 for w in cells)
-    assert four <= max(1, len(cells) // 4)
+    if bench_root == REPO:      # the tests' copy rehearses two such cells
+        assert four <= max(1, len(cells) // 4)
     rs = bench["run_seconds"]
     assert isinstance(rs, int) and 1 <= rs <= 51
     # a full check of the full 24 cells fits the driver's 43200 s
@@ -270,26 +280,26 @@ def test_every_metric_is_reported_where_its_target_is(bench):
         assert set(m.get("workloads", cells)) <= e2e[m["moves"]], m["name"]
 
 
-def test_the_gpt2_cell_reports_its_rate_per_layer(bench):
+def test_the_gpt2_cell_reports_its_rate_per_layer(bench_root):
     """Stalls of seconds in a few runs spread its rate past any bound
     (PERF.md, Findings): end to end it reports the tail and set-up."""
     from benchmark.run import load_cell
 
-    cell = load_cell(REPO, "gpt2m-train-s1024")
+    cell = load_cell(bench_root, "gpt2m-train-s1024")
     assert {m["name"] for m in cell["end_to_end"]} == {"step_ms_p90",
                                                        "setup_s"}
     assert {"samples_per_s_per_chip_window",
             "samples_per_s_per_chip_block_median"} <= set(
                 cell["layer_metrics"])
     for name in ("alexnet-train-resident", "alexnet-4chip-dp"):
-        cell = load_cell(REPO, name)
+        cell = load_cell(bench_root, name)
         assert {m["name"] for m in cell["end_to_end"]} == {
             "samples_per_s_per_chip", "mfu", "step_ms_p90", "setup_s"}
         assert "samples_per_s_per_chip_window" not in cell["layer_metrics"]
 
 
-def test_every_per_layer_metric_has_its_reader_file(bench):
-    home = os.path.join(REPO, bench["paths"][0])
+def test_every_per_layer_metric_has_its_reader_file(bench, bench_root):
+    home = os.path.join(bench_root, bench["paths"][0])
     layers = set()
     for m in bench["per_layer"]:
         with open(os.path.join(home, "layer_metrics",
@@ -302,11 +312,11 @@ def test_every_per_layer_metric_has_its_reader_file(bench):
         layers.add(m["layer"])
     with open(os.path.join(REPO, "PERF.md")) as f:
         perf = f.read()
-    assert all(layer in perf for layer in layers)
+    assert all(layer in perf for layer in layers if bench_root == REPO)
 
 
-def test_data_files_are_found_by_name(bench):
-    home = os.path.join(REPO, bench["paths"][0])
+def test_data_files_are_found_by_name(bench, bench_root):
+    home = os.path.join(bench_root, bench["paths"][0])
     for w in bench["workloads"]:
         with open(os.path.join(home, "traffic", w["traffic"] + ".json")) as f:
             traffic = json.load(f)
@@ -316,7 +326,7 @@ def test_data_files_are_found_by_name(bench):
         peaks = json.load(f)
     assert "TPU v5 lite" in peaks and "source" in peaks["TPU v5 lite"]
     for c in bench["configs"]:
-        with open(os.path.join(REPO, c["file"])) as f:
+        with open(os.path.join(bench_root, c["file"])) as f:
             cfg = json.load(f)
         assert cfg["reduced"] == c["reduced"]
         assert callable(formula(cfg["flops"]))

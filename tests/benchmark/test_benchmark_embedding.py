@@ -29,24 +29,26 @@ def _spec():
         return json.load(f)
 
 
-def test_the_entry_and_its_file():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+def test_the_entry_and_its_file(bench_root):
+    with open(os.path.join(bench_root, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    entry = bench["per_layer"][-1]      # appended, nothing moved
+    entry = next(m for m in bench["per_layer"] if m["name"] == NAME)
     spec = _spec()
-    assert entry["name"] == NAME
     assert (entry["layer"], entry["unit"], entry["moves"]) == \
         (spec["layer"], spec["unit"], spec["moves"]) == \
         ("kernels", "ms", "step_ms_p90")
     assert (entry["better"], entry["source"]) == ("lower", "device_trace")
     assert spec["reader"] == "device_span" and "phase" not in spec
-    assert entry["workloads"] == ["gpt2m-train-s1024"]
-    cell = run.load_cell(REPO, "gpt2m-train-s1024")
-    assert cell["layer_metrics"][NAME] == spec
+    # both cells whose graph has an Embedding
+    for cell in ("gpt2m-train-s1024", "dsv2-train-s4096"):
+        assert cell in entry["workloads"]
+        assert run.load_cell(bench_root, cell)["layer_metrics"][NAME] == spec
     # the AlexNet cells have no Embedding in their graph
     for w in bench["workloads"]:
         if w["config"] == "alexnet":
-            assert NAME not in run.load_cell(REPO, w["name"])["layer_metrics"]
+            assert w["name"] not in entry["workloads"]
+            assert NAME not in run.load_cell(
+                bench_root, w["name"])["layer_metrics"]
 
 
 def _step_scopes():

@@ -4,10 +4,8 @@ CPU.  The cells run here are added the way a later PR adds one: new
 files and new entries, no edit to a file that is there.  Nothing this
 file measures is a speed."""
 
-import hashlib
 import json
 import os
-import shutil
 import subprocess
 import sys
 
@@ -17,91 +15,15 @@ from benchmark import run
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-DATA = os.path.join(os.path.dirname(__file__), "data")
 BIG_SEED = 2 ** 31 + 11   # the driver's seeds pass 32 signed bits
-
-NEW_FILES = {                       # source under data/ -> place under benchmark/
-    "alexnet-tiny.json": "configs", "gpt2-tiny.json": "configs",
-    "tiny-resident.json": "traffic", "tiny-4dev-searched.json": "traffic",
-    "tiny-4dev-dp.json": "traffic",
-    "sync_ms_per_block.json": "layer_metrics",
-    "search_s.json": "layer_metrics",
-    "sim_predicted_searched_over_dp.json": "layer_metrics"}
-NEW_CELLS = [("alexnet-tiny.resident", "alexnet-tiny", "tiny-resident", 1),
-             ("gpt2-tiny.resident", "gpt2-tiny", "tiny-resident", 1),
-             ("alexnet-tiny.4dev", "alexnet-tiny", "tiny-4dev-searched", 4),
-             ("alexnet-tiny.4dev-dp", "alexnet-tiny", "tiny-4dev-dp", 4)]
-
-
-def _digests(top):
-    out = {}
-    for d, _, files in os.walk(top):
-        for f in files:
-            p = os.path.join(d, f)
-            with open(p, "rb") as fh:
-                out[os.path.relpath(p, top)] = hashlib.sha256(
-                    fh.read()).hexdigest()
-    return out
 
 
 @pytest.fixture(scope="module")
-def root(tmp_path_factory):
-    """A copy of the benchmark with three tiny cells added: files and
-    entries only."""
-    top = str(tmp_path_factory.mktemp("bench_root"))
-    shutil.copytree(os.path.join(REPO, "benchmark"),
-                    os.path.join(top, "benchmark"),
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    before = _digests(top)
-    for name, where in NEW_FILES.items():
-        shutil.copy(os.path.join(DATA, name),
-                    os.path.join(top, "benchmark", where, name))
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    old = json.loads(json.dumps(bench))
-    for cfg in ("alexnet-tiny", "gpt2-tiny"):
-        bench["configs"].append({
-            "name": cfg, "source": "tests", "reduced": [], "why": "tests",
-            "file": f"benchmark/configs/{cfg}.json"})
-    for name, cfg, traffic, chips in NEW_CELLS:
-        bench["workloads"].append({"name": name, "config": cfg,
-                                   "traffic": traffic, "chips": chips,
-                                   "why": "tests"})
-    # the rate and its mfu list their cells: a new cell that reports them
-    # is appended to those lists, the one edit a cell makes to an entry
-    for m in bench["end_to_end"]:
-        if m["name"] in ("samples_per_s_per_chip", "mfu"):
-            m["workloads"] = m["workloads"] + [c[0] for c in NEW_CELLS]
-    listed = json.loads(json.dumps(bench))
-    # new metrics list their cells; no other entry that is there is edited
-    bench["end_to_end"].append({
-        "name": "searched_over_dp.tiny", "unit": "ratio", "better": "higher",
-        "bound": 0.1, "source": "host_clock",
-        "workloads": ["alexnet-tiny.4dev"]})
-    bench["per_layer"].append({
-        "name": "sync_ms_per_block", "unit": "ms", "better": "lower",
-        "source": "host_clock", "layer": "host step loop",
-        "moves": "samples_per_s_per_chip",
-        "workloads": [c[0] for c in NEW_CELLS]})
-    for name, unit, source in (
-            ("search_s", "s", "host_clock"),
-            ("sim_predicted_searched_over_dp", "ratio", "program_counter")):
-        bench["per_layer"].append({
-            "name": name, "unit": unit, "better": "lower", "source": source,
-            "layer": "strategy search", "moves": "searched_over_dp.tiny",
-            "workloads": ["alexnet-tiny.4dev"]})
-    with open(os.path.join(top, "BENCHMARK.json"), "w") as f:
-        json.dump(bench, f)
-    after = _digests(top)
-    assert {k: after[k] for k in before} == before      # nothing edited
-    for kind in ("configs", "workloads", "per_layer"):
-        assert bench[kind][:len(old[kind])] == old[kind]
-    assert bench["end_to_end"][:len(old["end_to_end"])] == \
-        listed["end_to_end"]
-    for was, now in zip(old["end_to_end"], listed["end_to_end"]):
-        assert {k: v for k, v in now.items() if k != "workloads"} == \
-            {k: v for k, v in was.items() if k != "workloads"}
-    return top
+def root(grown):
+    """A copy of the benchmark with tiny cells added: files and entries
+    only (`conftest.py`; `test_benchmark_cells.py` holds that no file
+    was edited)."""
+    return grown.top
 
 
 def _cell(root, name):
@@ -172,13 +94,10 @@ def test_tiny_cell_on_one_device(root, name):
 
 
 def test_tiny_cell_on_four_devices(root):
-    cell = _cell(root, "alexnet-tiny.4dev")
     # the harness names the ratio after the traffic file's two variants
-    cell["end_to_end"] = [dict(m, name="searched_over_dp")
-                          if m["name"] == "searched_over_dp.tiny" else m
-                          for m in cell["end_to_end"]]
     lines = []
-    res = run.run_cell(cell, 7, 1.0, False, say=lines.append)
+    res = run.run_cell(_cell(root, "alexnet-tiny.4dev"), 7, 1.0, False,
+                       say=lines.append)
     _check_result(res, ["samples_per_s_per_chip", "mfu", "step_ms_p90",
                         "setup_s", "searched_over_dp"])
     assert any(ln.startswith("searched: ops not plainly data parallel")
