@@ -63,12 +63,14 @@ FULL = dict(
     alexnet_batch=256, image=229, warmup=3, timed=12,
     layers=4, embed=512, heads=8, seq=512, batch=16, vocab=32000,
     flash_shapes=((16, 8, 512, 64), (4, 16, 1024, 64), (2, 16, 4096, 128)),
+    flash_window=513,
     serve_seq=128, prompts=(5, 12, 33, 60), new_tokens=(8, 12, 16, 10),
     search_budget=2000)
 TINY = dict(
     alexnet_batch=8, image=67, warmup=2, timed=3,
     layers=2, embed=64, heads=4, seq=64, batch=4, vocab=128,
     flash_shapes=((2, 2, 64, 16), (1, 2, 128, 32)),
+    flash_window=9,
     serve_seq=64, prompts=(3, 5, 9, 17), new_tokens=(4, 6, 8, 5),
     search_budget=200)
 
@@ -244,6 +246,16 @@ def phase_kernels(sz, dev, stats):
             worst[tag] = round(max(errs.values()), 5)
             check(worst[tag] <= KERNEL_TOL,
                   f"flash_attention {tag} off the reference: {errs}")
+    # what the same kernels do with the last shape under a window and
+    # under a selection (flash_win_*, flash_sel_*)
+    longest = sz["flash_shapes"][-1]
+    for tag, how in ((f"window{sz['flash_window']}",
+                      dict(window=sz["flash_window"])),
+                     ("selected", dict(selected=True))):
+        tiles["x".join(map(str, longest)) + "/" + tag] = {
+            kernel: "{block_q}x{block_k} {body_steps}/{grid_steps}".format(**t)
+            for kernel, t in tiling(longest[2], longest[2], longest[3],
+                                    causal=True, **how).items()}
     result("kernels", compiled=not interpret, tolerance=KERNEL_TOL,
            max_normalized_error=worst, tiling=tiles)
 

@@ -44,6 +44,19 @@ under it skips the mask; a block on the diagonal of square blocks is cut
 into bands of keys that leave out the queries before them (``_tiles``).
 A scale that is a power of two goes on the query, where it is exact.
 
+Window and selection.  With ``window`` a query sees its own key and the
+``window - 1`` before it: a band under the diagonal.  A block wholly
+below the band is treated as one wholly above the diagonal (no body, no
+fetch), and a block the band's lower edge crosses is masked whole, on
+both edges.  With ``select`` (batch, seq_k, seq_q), the pairs a query
+keeps as non-zero entries, keys down the rows as the scores are held,
+every block under the diagonal runs and masks by its block of
+``select`` alone (the selection is causal already); a query with no kept
+key in a block leaves rows that the next block with one wipes
+(``alpha`` is 0), and its own key's block comes last.  Both variants
+carry their own kernel names (``flash_win_*``, ``flash_sel_*``), so that
+a trace tells the three cores apart.
+
 Blocks are a function of (seq_q, seq_k, head_dim) alone
 (``_block_sizes``, chosen by a sweep on the v5e), the bands of the
 kernel; ``tiling()`` says what each kernel does with a shape.
@@ -67,6 +80,17 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
+# The kernels' names under a window and under a selection: the same
+# three bodies, told apart in a trace.
+WINDOW_KERNELS = tuple(k.replace("flash_", "flash_win_") for k in KERNELS)
+SELECT_KERNELS = tuple(k.replace("flash_", "flash_sel_") for k in KERNELS)
+
+
+def kernel_names(window=None, selected=False):
+    """The names of the forward, dq and dkv kernels of a call."""
+    if selected:
+        return SELECT_KERNELS
+    return WINDOW_KERNELS if window is not None else KERNELS
 # A sequence of up to this many rows can be one block whatever its
 # length; a longer one is cut into divisors that are multiples of 8.
 _WHOLE = 512
@@ -136,10 +160,13 @@ def _block_sizes(seq_q: int, seq_k: int, head_dim: int,
             _largest_block(seq_k, block_k or _max_block(head_dim)))
 
 
-def _runs(qi, ki, block_q: int, block_k: int):
-    """Causal: does block (qi, ki) hold a pair with k <= q?  Python
-    ints or traced scalars."""
-    return qi * block_q + block_q - 1 >= ki * block_k
+def _runs(qi, ki, block_q: int, block_k: int, window=None):
+    """Causal: does block (qi, ki) hold a pair with k <= q (and, under a
+    window, with k > q - window)?  Python ints or traced scalars."""
+    runs = qi * block_q + block_q - 1 >= ki * block_k
+    if window is not None:
+        runs = runs & (ki * block_k + block_k - 1 > qi * block_q - window)
+    return runs
 
 
 def _on_diagonal(qi, ki, block_q: int, block_k: int):
@@ -148,21 +175,31 @@ def _on_diagonal(qi, ki, block_q: int, block_k: int):
     return ki * block_k + block_k - 1 > qi * block_q
 
 
+def _below_band(qi, ki, block_q: int, block_k: int, window: int):
+    """Window: does block (qi, ki) hold a pair with k <= q - window, so
+    that it needs the mask of the band's lower edge?"""
+    return ki * block_k <= qi * block_q + block_q - 1 - window
+
+
 def tiling(seq_q: int, seq_k: int, head_dim: int, causal: bool,
-           block_q: Optional[int] = None, block_k: Optional[int] = None
+           block_q: Optional[int] = None, block_k: Optional[int] = None,
+           window: Optional[int] = None, selected: bool = False
            ) -> Dict[str, Dict[str, int]]:
     """What each kernel does with one (batch, head) of this shape: its
     blocks, the steps its grid has and those of them that run a body
-    (the rest lie wholly above the causal diagonal and fetch nothing),
-    and the bands a block on the diagonal is cut into (``_tiles``)."""
+    (the rest lie wholly above the causal diagonal, or wholly below the
+    window's band, and fetch nothing), and the bands a block on the
+    diagonal is cut into (``_tiles``; one under a window or a
+    selection, whose blocks are masked whole)."""
     bq, bk = _block_sizes(seq_q, seq_k, head_dim, block_q, block_k)
     nq, nk = seq_q // bq, seq_k // bk
     body = sum(1 for qi in range(nq) for ki in range(nk)
-               if not causal or _runs(qi, ki, bq, bk))
-    return {kernel: dict(block_q=bq, block_k=bk, grid_steps=nq * nk,
-                         body_steps=body, diagonal_bands=len(
-                             _tiles(kernel, causal, 0, 0, bq, bk)))
-            for kernel in KERNELS}
+               if not causal or _runs(qi, ki, bq, bk, window))
+    whole = window is not None or selected
+    return {name: dict(block_q=bq, block_k=bk, grid_steps=nq * nk,
+                       body_steps=body, diagonal_bands=1 if whole else len(
+                           _tiles(kernel, causal, 0, 0, bq, bk)))
+            for kernel, name in zip(KERNELS, kernel_names(window, selected))}
 
 
 def _folds(scale: float) -> bool:
@@ -181,36 +218,53 @@ def _scores_t(q, k, scale: float):
     return st if _folds(scale) else st * scale
 
 
-def _dscores_t(q, k, v, do, lse, delta, scale, mask_at):
+def _dscores_t(q, k, v, do, lse, delta, scale, mask_at, window=None,
+               keep=None):
     """p^T and (p * (dp - delta))^T of one block, (bk, bq) in f32; the
     scale of ds is left to the caller's accumulator."""
-    st = _causal_mask(_scores_t(q, k, scale), mask_at)
+    st = _causal_mask(_scores_t(q, k, scale), mask_at, window)
+    if keep is not None:
+        st = jnp.where(keep, st, NEG_INF)
     pt = jnp.exp(st - lse)
     dpt = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
     return pt, pt * (dpt - delta)
 
 
-def _causal_mask(st, mask_at):
-    """Transposed scores with every pair k > q at NEG_INF; ``mask_at``
-    is the (q, k) position of the tile's first pair, or None for a tile
-    that needs no mask."""
+def _causal_mask(st, mask_at, window=None):
+    """Transposed scores with every pair k > q (and, under a window,
+    k <= q - window) at NEG_INF; ``mask_at`` is the (q, k) position of
+    the tile's first pair, or None for a tile that needs no mask."""
     if mask_at is None:
         return st
     qs = mask_at[0] + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
     ks = mask_at[1] + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
-    return jnp.where(qs >= ks, st, NEG_INF)
+    keep = qs >= ks
+    if window is not None:
+        keep = keep & (ks > qs - window)
+    return jnp.where(keep, st, NEG_INF)
 
 
-def _tiles(kernel: str, masked: bool, qi, ki, block_q: int, block_k: int):
+def _kept(sel_ref, ks, qs):
+    """The pairs of a tile its block of ``select`` keeps, (keys,
+    queries) bool; compared in f32 (the v5e compares no bf16)."""
+    return sel_ref[ks, qs].astype(jnp.float32) > 0.0
+
+
+def _tiles(kernel: str, masked: bool, qi, ki, block_q: int, block_k: int,
+           whole: bool = False):
     """[(k rows, q columns, mask_at)] that cover what block (qi, ki) has
     to compute: two slices of the block and what ``_causal_mask`` takes.
     A masked block of square blocks starts on the diagonal (qi == ki),
     so which of its pairs are masked is known here: it is cut into bands
     of ``_DIAGONAL_BAND`` keys, and a band leaves out the queries before
-    its first key."""
+    its first key.  ``whole``: one tile, masked at the block's own
+    position (a window's block may lie off the diagonal)."""
     if not masked:
         return [(slice(0, block_k), slice(0, block_q), None)]
     band = _DIAGONAL_BAND[kernel]
+    if whole:
+        return [(slice(0, block_k), slice(0, block_q),
+                 (qi * block_q, ki * block_k))]
     if block_q != block_k or block_k % band:
         band = block_k                      # one band: the whole block
     return [(slice(lo, lo + band), slice(lo, block_q),
@@ -218,14 +272,22 @@ def _tiles(kernel: str, masked: bool, qi, ki, block_q: int, block_k: int):
             for lo in range(0, block_k, band)]
 
 
-def _when_causal(causal: bool, qi, ki, block_q: int, block_k: int, body):
+def _when_causal(causal: bool, qi, ki, block_q: int, block_k: int, body,
+                 window=None, selected: bool = False):
     """Run ``body(masked)`` as block (qi, ki) needs it: not at all above
-    the diagonal, masked on it, plain under it or without ``causal``."""
+    the diagonal or below the window's band, masked on the diagonal or
+    the band's lower edge, plain between them or without ``causal``.  A
+    selection masks every block itself, so its blocks run plain."""
     if not causal:
         body(False)
         return
+    if selected:
+        pl.when(_runs(qi, ki, block_q, block_k))(lambda: body(False))
+        return
     diag = _on_diagonal(qi, ki, block_q, block_k)
-    pl.when(jnp.logical_and(diag, _runs(qi, ki, block_q, block_k)))(
+    if window is not None:
+        diag = diag | _below_band(qi, ki, block_q, block_k, window)
+    pl.when(jnp.logical_and(diag, _runs(qi, ki, block_q, block_k, window)))(
         lambda: body(True))
     pl.when(jnp.logical_not(diag))(lambda: body(False))
 
@@ -240,14 +302,38 @@ def _first_q(ki, block_q: int, block_k: int):
     return (ki * block_k) // block_q
 
 
-def _kv_map(causal: bool, block_q: int, block_k: int):
-    """Index map of a k or v block on a (batch*heads, q, k) grid; a step
-    above the causal diagonal names the block already held."""
-    def index(bh_, qi, ki):
+def _k_of(causal: bool, block_q: int, block_k: int, window=None):
+    """The k block a step (qi, ki) of a (batch*heads, q, k) grid names: a
+    step above the causal diagonal, or below the window's band, names a
+    block that is held or will be."""
+    def k_of(qi, ki):
         if causal:
             ki = jnp.minimum(ki, _last_k(qi, block_q, block_k))
-        return (bh_, ki, 0)
-    return index
+        if window is not None:
+            ki = jnp.maximum(ki, jnp.maximum(
+                qi * block_q - window + 1, 0) // block_k)
+        return ki
+    return k_of
+
+
+def _q_of(causal: bool, block_q: int, block_k: int, nq: int, window=None):
+    """The q block a step (ki, qi) of a (batch*heads, k, q) grid names: a
+    skipped step names the first block that runs, or the last."""
+    def q_of(ki, qi):
+        if causal:
+            qi = jnp.minimum(jnp.maximum(qi, _first_q(ki, block_q, block_k)),
+                             nq - 1)
+        if window is not None:
+            qi = jnp.minimum(
+                qi, (ki * block_k + block_k + window - 2) // block_q)
+        return qi
+    return q_of
+
+
+def _kv_map(causal: bool, block_q: int, block_k: int, window=None):
+    """Index map of a k or v block on a (batch*heads, q, k) grid."""
+    k_of = _k_of(causal, block_q, block_k, window)
+    return lambda bh_, qi, ki: (bh_, k_of(qi, ki), 0)
 
 
 def _stats_rows(x, block_q: int):
@@ -266,14 +352,20 @@ _SEMANTICS = pltpu.CompilerParams(
 # lowers it to Mosaic again (5 s of a 24-layer step's lowering against
 # 1 s, PERF.md section 6, PR 26).  The scopes are the call sites', so
 # that a cached trace holds no name.
-_STATIC = dict(static_argnames=("scale", "causal", "bq", "bk", "interpret"))
+_STATIC = dict(static_argnames=("scale", "causal", "bq", "bk", "interpret",
+                                "window"))
 
 # ---------------------------------------------------------------------------
 # Forward kernel
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc_sc, m_sc, l_sc, *, scale, causal, block_q, block_k):
+def _fwd_kernel(*refs, scale, causal, block_q, block_k, window=None,
+                selected=False):
+    if selected:
+        (q_ref, k_ref, v_ref, sel_ref, o_ref, lse_ref,
+         acc_sc, m_sc, l_sc) = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc = refs
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -286,9 +378,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     def _body(masked):
         for ks, qs, mask_at in _tiles("flash_fwd", masked, qi, ki,
-                                      block_q, block_k):
+                                      block_q, block_k, window is not None):
             st = _causal_mask(                       # (keys, queries)
-                _scores_t(q_ref[0, qs, :], k_ref[0, ks, :], scale), mask_at)
+                _scores_t(q_ref[0, qs, :], k_ref[0, ks, :], scale), mask_at,
+                window)
+            if selected:
+                st = jnp.where(_kept(sel_ref, ks, qs), st, NEG_INF)
             v = v_ref[0, ks, :]
             m_prev = m_sc[:, qs]                     # (1, queries)
             m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
@@ -302,7 +397,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 preferred_element_type=jnp.float32)
             m_sc[:, qs] = m_new
 
-    _when_causal(causal, qi, ki, block_q, block_k, _body)
+    _when_causal(causal, qi, ki, block_q, block_k, _body, window, selected)
 
     @pl.when(ki == nk - 1)
     def _finish():
@@ -313,22 +408,37 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[...] = jnp.where(empty, NEG_INF, m_sc[...] + jnp.log(l_safe))
 
 
+def _sel_spec(sel, bh: int, bq: int, bk: int, q_of, k_of):
+    """Block (bk, bq) of ``select`` (batch, seq_k, seq_q) for a step whose
+    grid indices after batch*heads are (a, b): the heads of a batch read
+    the same block."""
+    heads = bh // sel.shape[0]
+    return pl.BlockSpec((None, bk, bq), lambda bh_, a, b: (
+        bh_ // heads, k_of(a, b), q_of(a, b)))
+
+
 @functools.partial(jax.jit, **_STATIC)
-def _fwd_call(qr, kr, vr, scale, causal, bq, bk, interpret):
+def _fwd_call(qr, kr, vr, scale, causal, bq, bk, interpret, window=None,
+              sel=None):
     """o (bh, sq, dv) and lse (bh, sq) of q, k (bh, s, d) and v (bh, sk,
     dv) operands."""
     bh, sq, d = qr.shape
     sk, dv = vr.shape[1:]
+    k_of = _k_of(causal, bq, bk, window)
+    cols = lambda width: pl.BlockSpec((1, bk, width),
+                                      _kv_map(causal, bq, bk, window))
+    extra = [] if sel is None else [_sel_spec(
+        sel, bh, bq, bk, lambda qi, ki: qi, k_of)]
 
     call = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk),
+                          block_q=bq, block_k=bk, window=window,
+                          selected=sel is not None),
         grid=(bh, sq // bq, sk // bk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh_, qi, ki: (bh_, qi, 0)),
-            pl.BlockSpec((1, bk, d), _kv_map(causal, bq, bk)),
-            pl.BlockSpec((1, bk, dv), _kv_map(causal, bq, bk)),
-        ],
+            cols(d), cols(dv),
+        ] + extra,
         out_specs=[
             pl.BlockSpec((1, bq, dv), lambda bh_, qi, ki: (bh_, qi, 0)),
             pl.BlockSpec((None, None, 1, bq),
@@ -344,21 +454,31 @@ def _fwd_call(qr, kr, vr, scale, causal, bq, bk, interpret):
             pltpu.VMEM((1, bq), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_fwd",
+        name=kernel_names(window, sel is not None)[0],
         compiler_params=_SEMANTICS,
     )
-    out, lse = call(qr, kr, vr)
+    out, lse = call(qr, kr, vr, *([] if sel is None else [sel]))
     return out, lse.reshape(bh, sq)
 
 
-def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
+def _variant(window, sel):
+    """What a jitted call takes beside the plain causal call's arguments:
+    nothing for that call, so that its trace is the one it was."""
+    return {} if window is None and sel is None else dict(window=window,
+                                                          sel=sel)
+
+
+def _flash_forward(q, k, v, sel, scale, causal, block_q, block_k, interpret,
+                   window):
     b, h, sq, d = q.shape
     sk, dv = v.shape[2:]
     bq, bk = _block_sizes(sq, sk, d, block_q, block_k)
-    with jax.named_scope("ff.kernel.flash_fwd"):
+    more = _variant(window, sel)
+    with jax.named_scope(
+            "ff.kernel." + kernel_names(window, sel is not None)[0]):
         out, lse = _fwd_call(q.reshape(b * h, sq, d), k.reshape(b * h, sk, d),
                              v.reshape(b * h, sk, dv), scale, causal, bq, bk,
-                             interpret)
+                             interpret, **more)
     return out.reshape(b, h, sq, dv), lse.reshape(b, h, sq)
 
 
@@ -366,9 +486,14 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
 # Backward kernels
 # ---------------------------------------------------------------------------
 
-def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                     dk_ref, dv_ref, dk_sc, dv_sc,
-                     *, scale, causal, block_q, block_k):
+def _bwd_dkdv_kernel(*refs, scale, causal, block_q, block_k, window=None,
+                     selected=False):
+    if selected:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sel_ref,
+         dk_ref, dv_ref, dk_sc, dv_sc) = refs
+    else:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+         dk_ref, dv_ref, dk_sc, dv_sc) = refs
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     nq = pl.num_programs(2)
@@ -380,11 +505,12 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     def _body(masked):
         for ks, qs, mask_at in _tiles("flash_dkv", masked, qi, ki,
-                                      block_q, block_k):
+                                      block_q, block_k, window is not None):
             q, do = q_ref[0, qs, :], do_ref[0, qs, :]
             pt, dst = _dscores_t(
                 q, k_ref[0, ks, :], v_ref[0, ks, :], do, lse_ref[:, qs],
-                delta_ref[:, qs], scale, mask_at)
+                delta_ref[:, qs], scale, mask_at, window,
+                _kept(sel_ref, ks, qs) if selected else None)
             dv_sc[ks, :] += jax.lax.dot_general(
                 pt.astype(do.dtype), do, _NN,
                 preferred_element_type=jnp.float32)
@@ -392,7 +518,7 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dst.astype(q.dtype), q, _NN,
                 preferred_element_type=jnp.float32)
 
-    _when_causal(causal, qi, ki, block_q, block_k, _body)
+    _when_causal(causal, qi, ki, block_q, block_k, _body, window, selected)
 
     @pl.when(qi == nq - 1)
     def _finish():
@@ -400,8 +526,14 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dq_sc, *, scale, causal, block_q, block_k):
+def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, window=None,
+                   selected=False):
+    if selected:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sel_ref,
+         dq_ref, dq_sc) = refs
+    else:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+         dq_ref, dq_sc) = refs
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -412,17 +544,18 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     def _body(masked):
         for ks, qs, mask_at in _tiles("flash_dq", masked, qi, ki,
-                                      block_q, block_k):
+                                      block_q, block_k, window is not None):
             k = k_ref[0, ks, :]
             dst = _dscores_t(
                 q_ref[0, qs, :], k, v_ref[0, ks, :], do_ref[0, qs, :],
-                lse_ref[:, qs], delta_ref[:, qs], scale, mask_at)[1]
+                lse_ref[:, qs], delta_ref[:, qs], scale, mask_at, window,
+                _kept(sel_ref, ks, qs) if selected else None)[1]
             # dq^T (d, queries) += k^T ds^T
             dq_sc[:, qs] += jax.lax.dot_general(
                 k, dst.astype(k.dtype), _TN,
                 preferred_element_type=jnp.float32)
 
-    _when_causal(causal, qi, ki, block_q, block_k, _body)
+    _when_causal(causal, qi, ki, block_q, block_k, _body, window, selected)
 
     @pl.when(ki == nk - 1)
     def _finish():
@@ -430,17 +563,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 @functools.partial(jax.jit, **_STATIC)
-def _dkv_call(qr, kr, vr, dor, lse, delta, scale, causal, bq, bk, interpret):
+def _dkv_call(qr, kr, vr, dor, lse, delta, scale, causal, bq, bk, interpret,
+              window=None, sel=None):
     """dk (bh, sk, d) and dv (bh, sk, dv); ``lse`` and ``delta`` are
     (bh, sq)."""
     bh, sq, d = qr.shape
     sk, dv = vr.shape[1:]
     nq = sq // bq
-
-    def q_of(ki, qi):
-        if causal:   # a skipped step names the first block that runs
-            qi = jnp.minimum(jnp.maximum(qi, _first_q(ki, bq, bk)), nq - 1)
-        return qi
+    q_of = _q_of(causal, bq, bk, nq, window)
 
     def rows(width):
         return pl.BlockSpec((1, bq, width),
@@ -451,11 +581,14 @@ def _dkv_call(qr, kr, vr, dor, lse, delta, scale, causal, bq, bk, interpret):
 
     stat = pl.BlockSpec((None, None, 1, bq),
                         lambda bh_, ki, qi: (bh_, q_of(ki, qi), 0, 0))
+    extra = [] if sel is None else [_sel_spec(
+        sel, bh, bq, bk, q_of, lambda ki, qi: ki)]
     call = pl.pallas_call(
         functools.partial(_bwd_dkdv_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk),
+                          block_q=bq, block_k=bk, window=window,
+                          selected=sel is not None),
         grid=(bh, sk // bk, nq),
-        in_specs=[rows(d), cols(d), cols(dv), rows(dv), stat, stat],
+        in_specs=[rows(d), cols(d), cols(dv), rows(dv), stat, stat] + extra,
         out_specs=[cols(d), cols(dv)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sk, d), kr.dtype),
@@ -466,43 +599,51 @@ def _dkv_call(qr, kr, vr, dor, lse, delta, scale, causal, bq, bk, interpret):
             pltpu.VMEM((bk, dv), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_dkv",
+        name=kernel_names(window, sel is not None)[2],
         compiler_params=_SEMANTICS,
     )
-    return call(qr, kr, vr, dor, _stats_rows(lse, bq), _stats_rows(delta, bq))
+    return call(qr, kr, vr, dor, _stats_rows(lse, bq), _stats_rows(delta, bq),
+                *([] if sel is None else [sel]))
 
 
 @functools.partial(jax.jit, **_STATIC)
-def _dq_call(qr, kr, vr, dor, lse, delta, scale, causal, bq, bk, interpret):
+def _dq_call(qr, kr, vr, dor, lse, delta, scale, causal, bq, bk, interpret,
+             window=None, sel=None):
     """dq (bh, sq, d); ``lse`` and ``delta`` are (bh, sq)."""
     bh, sq, d = qr.shape
     sk, dv = vr.shape[1:]
+    k_of = _k_of(causal, bq, bk, window)
 
     def rows(width):
         return pl.BlockSpec((1, bq, width), lambda bh_, qi, ki: (bh_, qi, 0))
 
     def cols(width):
-        return pl.BlockSpec((1, bk, width), _kv_map(causal, bq, bk))
+        return pl.BlockSpec((1, bk, width), _kv_map(causal, bq, bk, window))
 
     stat = pl.BlockSpec((None, None, 1, bq),
                         lambda bh_, qi, ki: (bh_, qi, 0, 0))
+    extra = [] if sel is None else [_sel_spec(
+        sel, bh, bq, bk, lambda qi, ki: qi, k_of)]
     call = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk),
+                          block_q=bq, block_k=bk, window=window,
+                          selected=sel is not None),
         grid=(bh, sq // bq, sk // bk),
-        in_specs=[rows(d), cols(d), cols(dv), rows(dv), stat, stat],
+        in_specs=[rows(d), cols(d), cols(dv), rows(dv), stat, stat] + extra,
         out_specs=rows(d),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), qr.dtype),
         scratch_shapes=[pltpu.VMEM((d, bq), jnp.float32)],
         interpret=interpret,
-        name="flash_dq",
+        name=kernel_names(window, sel is not None)[1],
         compiler_params=_SEMANTICS,
     )
-    return call(qr, kr, vr, dor, _stats_rows(lse, bq), _stats_rows(delta, bq))
+    return call(qr, kr, vr, dor, _stats_rows(lse, bq), _stats_rows(delta, bq),
+                *([] if sel is None else [sel]))
 
 
-def _flash_backward(scale, causal, block_q, block_k, interpret, res, grads):
-    q, k, v, out, lse = res
+def _flash_backward(scale, causal, block_q, block_k, interpret, window, res,
+                    grads):
+    q, k, v, sel, out, lse = res
     do, _ = grads
     b, h, sq, d = q.shape
     sk, dv = v.shape[2:]
@@ -513,28 +654,32 @@ def _flash_backward(scale, causal, block_q, block_k, interpret, res, grads):
     args = (q.reshape(bh, sq, d), k.reshape(bh, sk, d), v.reshape(bh, sk, dv),
             do.reshape(bh, sq, dv), lse.reshape(bh, sq), delta.reshape(bh, sq),
             scale, causal, bq, bk, interpret)
-    with jax.named_scope("ff.kernel.flash_dkv"):
-        dk, dv_ = _dkv_call(*args)
-    with jax.named_scope("ff.kernel.flash_dq"):
-        dq = _dq_call(*args)
+    more = _variant(window, sel)
+    _, dq_name, dkv_name = kernel_names(window, sel is not None)
+    with jax.named_scope("ff.kernel." + dkv_name):
+        dk, dv_ = _dkv_call(*args, **more)
+    with jax.named_scope("ff.kernel." + dq_name):
+        dq = _dq_call(*args, **more)
     return (dq.reshape(b, h, sq, d),
             dk.reshape(b, h, sk, d),
-            dv_.reshape(b, h, sk, dv))
+            dv_.reshape(b, h, sk, dv), None)
 
 
 # ---------------------------------------------------------------------------
 # Public API
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, scale, causal, block_q, block_k, interpret):
-    return _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, sel, scale, causal, block_q, block_k, interpret, window):
+    return _flash_forward(q, k, v, sel, scale, causal, block_q, block_k,
+                          interpret, window)
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
-    out, lse = _flash_forward(q, k, v, scale, causal, block_q, block_k,
-                              interpret)
-    return (out, lse), (q, k, v, out, lse)
+def _flash_fwd(q, k, v, sel, scale, causal, block_q, block_k, interpret,
+               window):
+    out, lse = _flash_forward(q, k, v, sel, scale, causal, block_q, block_k,
+                              interpret, window)
+    return (out, lse), (q, k, v, sel, out, lse)
 
 
 _flash.defvjp(_flash_fwd, _flash_backward)
@@ -545,7 +690,9 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     return_lse: bool = False,
-                    interpret: bool = False):
+                    interpret: bool = False,
+                    window: Optional[int] = None,
+                    select=None):
     """Fused attention: softmax(q k^T * scale [+ causal mask]) v.
 
     q and k are (B, H, S, D); v is (B, H, S, Dv) and the output has its
@@ -556,10 +703,23 @@ def flash_attention(q, k, v, *, causal: bool = False,
     length the kernel cannot tile (``unsupported_reason``).
     ``interpret=True`` runs the kernel in the Pallas interpreter (any
     backend, for tests); the default compiles it for the TPU.
+
+    Causal self-attention only: ``window`` keeps, of the keys a query
+    may see, its own and the ``window - 1`` before it; ``select``
+    (B, S, S), keys down the rows and queries along the columns, keeps
+    the pairs that are non-zero (of any float dtype; it must be causal
+    itself and keep at least one key a query, and takes no gradient).
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    out, lse = _flash(q, k, v, scale, causal, block_q, block_k, interpret)
+    if (window is not None or select is not None) and not (
+            causal and q.shape[2] == k.shape[2]):
+        raise ValueError("flash_attention: a window or a selection is for "
+                         "causal self-attention")
+    if window is not None and select is not None:
+        raise ValueError("flash_attention: a window or a selection, not both")
+    out, lse = _flash(q, k, v, select, scale, causal, block_q, block_k,
+                      interpret, window)
     if return_lse:
         return out, lse
     return out

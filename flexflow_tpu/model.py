@@ -1882,9 +1882,12 @@ class FFModel:
     # forward-graph evaluation (inside jit)
     # ------------------------------------------------------------------
     def _run_graph(self, params, stats, batch, training: bool, rng,
-                   counters: Optional[Dict[str, jax.Array]] = None):
+                   counters: Optional[Dict[str, jax.Array]] = None,
+                   losses: Optional[Dict[str, jax.Array]] = None):
         """``counters``: a dict the ops' per-step scalars are summed into
-        (``Op.COUNTERS``); the train step passes one."""
+        (``Op.COUNTERS``); ``losses``: a dict the ops' terms of the
+        objective are summed into (``FwdCtx.add_loss``).  The train step
+        passes both."""
         env: Dict[int, jax.Array] = {}
         multi = self.machine.num_devices > 1
         cdtype = self.compute_dtype
@@ -1905,7 +1908,8 @@ class FFModel:
             fill_dtype = jnp.int32 if "int" in t.dtype else cdtype
             env[t.guid] = jnp.full(t.dims, val, fill_dtype)
         ctx = FwdCtx(training=training, rng=rng, stats_in=stats,
-                     stats_out={} if training else None, counters=counters)
+                     stats_out={} if training else None, counters=counters,
+                     losses=losses)
         plan = getattr(self, "_pipeline_plan", None)
         use_pipe = (plan is not None and multi and plan["degree"] > 1)
         head_ids = ({id(op) for op in plan["head"]}
@@ -2033,6 +2037,13 @@ class FFModel:
                 jnp.where(jnp.isfinite(gnorm), gnorm, 0.0))
             return vec
 
+        def objective(loss, terms):
+            # the final tensor's loss plus the terms the ops added from
+            # inside the graph (FwdCtx.add_loss); with none, the loss
+            for name in sorted(terms):
+                loss = loss + terms[name]
+            return loss
+
         def micro_metrics(loss, read, labels, counts):
             msum = metrics_of(read, labels)
             msum.update(counts)
@@ -2092,11 +2103,11 @@ class FFModel:
             labels = batch["label"]
 
             def loss_fn(p):
-                counts = {}
+                counts, terms = {}, {}
                 env, new_stats = self._run_graph(p, stats, batch, True, rng,
-                                                 counts)
+                                                 counts, terms)
                 loss, read = loss_of(env, labels)
-                return loss, (read, new_stats, counts)
+                return objective(loss, terms), (read, new_stats, counts)
 
             (loss, (read, new_stats, counts)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
@@ -2126,12 +2137,12 @@ class FFModel:
                 mlabels = mb["label"]
 
                 def loss_fn(p):
-                    counts = {}
+                    counts, terms = {}, {}
                     env, new_stats = self._run_graph(
                         p, stats_c, mb, True, jax.random.fold_in(rng, idx),
-                        counts)
+                        counts, terms)
                     loss, read = loss_of(env, mlabels)
-                    return loss, (read, new_stats, counts)
+                    return objective(loss, terms), (read, new_stats, counts)
 
                 (loss, (read, new_stats, counts)), g = jax.value_and_grad(
                     loss_fn, has_aux=True)(params)

@@ -144,6 +144,97 @@ def build_deepseek_v2(ff: FFModel, batch_size: int, seq_length: int = 4096,
         learned_positions=False)
 
 
+def build_dots3(ff: FFModel, batch_size: int, seq_length: int = 8192,
+                hidden_size: int = 5120, num_hidden_layers: int = 46,
+                layer_types=None, first_k_dense_replace: int = 1,
+                intermediate_size: int = 13824,
+                moe_intermediate_size: int = 1536,
+                num_attention_heads: int = 128, q_lora_rank: int = 1024,
+                kv_lora_rank: int = 512, qk_nope_head_dim: int = 128,
+                qk_rope_head_dim: int = 64, v_head_dim: int = 128,
+                rope_theta: float = 8e7, index_n_heads: int = 64,
+                index_head_dim: int = 128, index_topk: int = 2048,
+                attention_gate_type: str = "headwise",
+                swa_num_attention_heads: int = 64, swa_q_lora_rank: int = 1024,
+                swa_kv_lora_rank: int = 1024, swa_qk_nope_head_dim: int = 192,
+                swa_qk_rope_head_dim: int = 64, swa_v_head_dim: int = 128,
+                swa_rope_theta: float = 5e4, sliding_window_size: int = 513,
+                swa_attention_gate_type: str = "headwise",
+                apply_mla_qkv_lora_rescale: bool = True,
+                rms_norm_eps: float = 1e-5, n_routed_experts: int = 256,
+                num_experts_per_tok: int = 8,
+                routed_scaling_factor: float = 1.0, n_shared_experts: int = 1,
+                scoring_func: str = "sigmoid", norm_topk_prob: bool = True,
+                vocab_size: int = 152064, experts_held=None,
+                first_expert: int = 0, capacity_factor: float = 1.0,
+                tile_rows: int = 128):
+    """dots3-note-prev's language model (the keys are those of its public
+    config.json) for training, as one chip of a deployment that divides
+    each layer holds it: RMSNorm; by ``layer_types``, latent attention
+    under a learned index (``full_attention``: the ``index_topk`` keys of
+    largest index score, ``ops/dsa.py``) or latent attention of the
+    ``swa_`` widths within a window (``sliding_attention``), both with a
+    head-wise output gate and rescaled latents, over the heads held here;
+    ``first_k_dense_replace`` dense gated MLPs and then routed experts
+    (sigmoid scores, a selection bias, weights renormalised over the
+    chosen; ``experts_held`` of ``n_routed_experts`` from ``first_expert``
+    on, with the shared expert) under the device budget; an untied head
+    without bias over the ``vocab_size`` rows held here.  The defaults
+    are the whole published model, ``layer_types`` its published pattern
+    (a full layer, then a period of full, window, window, window).
+    Returns (tokens_tensor, softmax_output); labels are next-token ids."""
+    if layer_types is None:
+        layer_types = ["full_attention"] + [
+            "sliding_attention" if i % 4 else "full_attention"
+            for i in range(num_hidden_layers - 1)]
+    if len(layer_types) != num_hidden_layers or set(layer_types) - {
+            "full_attention", "sliding_attention"}:
+        raise ValueError(f"build_dots3: {num_hidden_layers} layers, "
+                         f"layer_types {layer_types}")
+
+    def attention(ff, h, i):
+        if layer_types[i] == "sliding_attention":
+            return ff.latent_attention(
+                h, swa_num_attention_heads, q_lora_rank=swa_q_lora_rank,
+                kv_lora_rank=swa_kv_lora_rank,
+                qk_nope_head_dim=swa_qk_nope_head_dim,
+                qk_rope_head_dim=swa_qk_rope_head_dim,
+                v_head_dim=swa_v_head_dim, rope_theta=swa_rope_theta,
+                eps=rms_norm_eps, window=sliding_window_size,
+                gate=swa_attention_gate_type,
+                latent_rescale=apply_mla_qkv_lora_rescale, name=f"attn_{i}")
+        return ff.latent_attention(
+            h, num_attention_heads, q_lora_rank=q_lora_rank,
+            kv_lora_rank=kv_lora_rank, qk_nope_head_dim=qk_nope_head_dim,
+            qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+            rope_theta=rope_theta, eps=rms_norm_eps,
+            gate=attention_gate_type,
+            latent_rescale=apply_mla_qkv_lora_rescale,
+            index=(index_n_heads, index_head_dim, index_topk),
+            name=f"attn_{i}")
+
+    def mlp(ff, h, i):
+        if i < first_k_dense_replace:
+            return ff.gated_mlp(h, intermediate_size, name=f"mlp_{i}")
+        return ff.routed_experts(
+            h, n_routed_experts, num_experts_per_tok, moe_intermediate_size,
+            experts_held=experts_held, first_expert=first_expert,
+            routed_scaling_factor=routed_scaling_factor,
+            n_shared_experts=n_shared_experts,
+            capacity_factor=capacity_factor, tile_rows=tile_rows,
+            scoring=scoring_func, select_bias=True,
+            norm_topk_prob=norm_topk_prob, name=f"moe_{i}")
+
+    return build_decoder(
+        ff, batch_size, seq_length, num_hidden_layers, hidden_size,
+        vocab_size,
+        norm=lambda ff, x, name: ff.rms_norm(x, eps=rms_norm_eps, name=name),
+        attention=attention, mlp=mlp,
+        head=lambda ff, x: ff.dense(x, vocab_size, use_bias=False,
+                                    name="lm_head"),
+        learned_positions=False)
+
+
 def synthetic_lm_batch(batch_size: int, seq_length: int, vocab_size: int,
                        seed: int = 0):
     """(tokens, positions, next-token labels) for a synthetic LM step —

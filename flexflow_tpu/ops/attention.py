@@ -419,7 +419,31 @@ class LatentAttention(_PicksAttentionImpl, Op):
     are replicated); the output is placed by the batch degree alone.
     The core is the flash kernel at query/key head ``nope + rope`` and
     value head ``v_head_dim`` on a TPU, XLA's blockwise attention
-    elsewhere (``impl_used``)."""
+    elsewhere (``impl_used``).
+
+    Four options, all off by default (DeepSeek-V2 takes none):
+
+    * ``window``: query t sees keys ``t - window + 1 .. t`` (the flash
+      kernel's band; a dense mask in XLA elsewhere).
+    * ``gate="headwise"`` (Qiu et al., arXiv:2505.06708): ``g =
+      sigmoid(x W_g)``, one scalar a held head, multiplied onto that
+      head's attention output before ``W_O``.
+    * ``latent_rescale`` (LongCat-Flash's scale-corrected latent
+      attention, arXiv:2509.01322): the normed latents ``c_q`` and
+      ``c_kv`` times ``sqrt(hidden / rank)``.
+    * ``index=(heads, head_dim, topk)``: learned sparse attention
+      (``ops/dsa.py``).  ``q^I = c_q W^I_q``, ``k^I = LayerNorm(x
+      W^I_k)``, the rotary turn on the first ``qk_rope_head_dim`` of
+      each, ``w = x W^I_w * heads^-0.5 * head_dim^-0.5``; the main
+      softmax runs over the ``topk`` keys of largest index score alone
+      (the flash kernel under a selection), and the index's objective
+      ``L_I`` goes to the step's objective as the term ``dsa_index_kl``
+      (``FwdCtx.add_loss``).  The index reads ``x`` and ``c_q`` under
+      ``stop_gradient`` and the selection passes no gradient, so
+      ``L_I`` reaches the index's parameters alone and nothing else
+      does.  The index is whole whatever share of the heads is held:
+      every share computes the same selection.
+    """
 
     _type = "LatentAttention"
 
@@ -428,8 +452,17 @@ class LatentAttention(_PicksAttentionImpl, Op):
                  qk_rope_head_dim: int, v_head_dim: int,
                  rope_theta: float = 10000.0, rope_scaling=None,
                  eps: float = 1e-6, kernel_initializer=None,
+                 window: Optional[int] = None, gate: Optional[str] = None,
+                 latent_rescale: bool = False, index=None,
                  name: Optional[str] = None):
         super().__init__(model, [input_tensor], name)
+        if gate not in (None, "headwise"):
+            raise ValueError(f"{self.name}: unknown gate {gate!r}")
+        if window is not None and index is not None:
+            raise ValueError(f"{self.name}: a window layer has no index")
+        self.window = None if window is None else int(window)
+        self.gate = gate
+        self.index = None if index is None else tuple(int(v) for v in index)
         b, s, e = input_tensor.dims
         self.num_heads = int(num_heads)
         self.q_lora_rank, self.kv_lora_rank = int(q_lora_rank), int(kv_lora_rank)
@@ -462,6 +495,24 @@ class LatentAttention(_PicksAttentionImpl, Op):
                          partition_dims=(None, 2))
         self._add_weight("w_o", (h * self.v_dim, e), init,
                          partition_dims=(2, None))
+        if gate is not None:
+            self._add_weight("w_gate", (e, h), init, partition_dims=(None, 2))
+        self.q_rescale = self.kv_rescale = None
+        if latent_rescale:
+            self.q_rescale = math.sqrt(e / self.q_lora_rank)
+            self.kv_rescale = math.sqrt(e / self.kv_lora_rank)
+        if self.index is not None:
+            ih, idim, _ = self.index
+            if idim < self.rope:
+                raise ValueError(f"{self.name}: index head {idim} is "
+                                 f"narrower than the rotary part {self.rope}")
+            self._add_weight("wi_q", (self.q_lora_rank, ih * idim), init)
+            self._add_weight("wi_k", (e, idim), init)
+            self._add_weight("wi_k_scale", (idim,), ConstantInitializer(1.0))
+            self._add_weight("wi_k_bias", (idim,), ZeroInitializer())
+            self._add_weight("wi_w", (e, ih), init)
+            # the index's objective, summed over layers, and the layers
+            self.COUNTERS = ("dsa_index_kl", "dsa_layers")
 
     def _config_dim_bound(self, i: int):
         if i == 1:
@@ -473,8 +524,69 @@ class LatentAttention(_PicksAttentionImpl, Op):
     constraint_pc = Op.batch_only_pc
 
     def cost_key(self) -> str:
-        return (f"mla{self.num_heads}q{self.q_lora_rank}kv{self.kv_lora_rank}"
-                f"d{self.nope}.{self.rope}.{self.v_dim}")
+        key = (f"mla{self.num_heads}q{self.q_lora_rank}kv{self.kv_lora_rank}"
+               f"d{self.nope}.{self.rope}.{self.v_dim}")
+        if self.window is not None:
+            key += f"w{self.window}"
+        if self.gate is not None:
+            key += "g"
+        if self.index is not None:
+            key += "i{}.{}.{}".format(*self.index)
+        return key
+
+    def _index_scores(self, params, x, c_q):
+        """``I`` (B, S, S) float32 of the index, from the op's input and
+        the query latent, both constants to it."""
+        from .dsa import index_scores
+
+        ih, idim, _ = self.index
+        b, s, _ = x.shape
+        f32 = jnp.float32
+        hi = jax.lax.Precision.HIGHEST
+        grad_dtype = x.dtype
+        x = jax.lax.stop_gradient(x).astype(f32)
+        c_q = jax.lax.stop_gradient(c_q).astype(f32)
+        qi = jnp.dot(c_q, params["wi_q"].astype(f32),
+                     precision=hi).reshape(b, s, ih, idim)
+        ki = jnp.dot(x, params["wi_k"].astype(f32), precision=hi)
+        mean = jnp.mean(ki, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(ki - mean), axis=-1, keepdims=True)
+        ki = (ki - mean) * jax.lax.rsqrt(var + self.eps) \
+            * params["wi_k_scale"].astype(f32) \
+            + params["wi_k_bias"].astype(f32)
+        turn = lambda t: jnp.concatenate(
+            [rotate_pairs(t[..., :self.rope], self.inv_freq),
+             t[..., self.rope:]], axis=-1)
+        w = jnp.dot(x, params["wi_w"].astype(f32), precision=hi) \
+            * (ih ** -0.5 * idim ** -0.5)
+        return index_scores(turn(qi), turn(ki[:, :, None, :])[:, :, 0], w,
+                            grad_dtype)
+
+    def _core(self, qh, kh, vh, keep, impl):
+        """The attention core of a layer with a window or an index: the
+        flash kernel (banded by the window, or under the selection ``keep``,
+        (B, S, S) with the keys down the rows, as the kernel takes it) or
+        XLA's form of the same (``keep`` bool, queries down the rows).
+        Returns the heads' outputs (B, H, S, dv) and the rows'
+        log-sum-exp, None without a selection."""
+        s = qh.shape[2]
+        if impl == "xla":
+            from .dsa import causal_mask, masked_attention
+            if keep is None:
+                t = jnp.arange(s)
+                keep = (causal_mask(s)
+                        & (t[None, :] > t[:, None] - self.window))[None]
+            return masked_attention(qh, kh, vh, keep, self.softmax_scale)
+        from ..kernels.flash_attention import flash_attention
+        more = {}
+        if self.window is not None:
+            more["window"] = self.window
+        if keep is not None:
+            more.update(select=keep, return_lse=True)
+        out = flash_attention(qh, kh, vh, causal=True,
+                              scale=self.softmax_scale,
+                              interpret=impl == "pallas_interpret", **more)
+        return out if keep is not None else (out, None)
 
     def forward(self, params, xs: List[jax.Array], ctx: FwdCtx):
         from .linear import project
@@ -484,12 +596,18 @@ class LatentAttention(_PicksAttentionImpl, Op):
         b, s, _ = x.shape
         h, dn, dr, dv = self.num_heads, self.nope, self.rope, self.v_dim
         with jax.named_scope("ff.mla.q_proj"):
-            c_q = rms_norm(project(x, params["w_dq"]), params["q_norm"],
-                           self.eps)
+            # the rescale goes on the norm's float32 scale, where it is
+            # exact: as a bf16 constant sqrt(10) is 0.2 % short, on every
+            # latent alike
+            c_q = rms_norm(project(x, params["w_dq"]),
+                           params["q_norm"] if self.q_rescale is None
+                           else params["q_norm"] * self.q_rescale, self.eps)
             q = project(c_q, params["w_uq"]).reshape(b, s, h, dn + dr)
         with jax.named_scope("ff.mla.kv_proj"):
             ckv = project(x, params["w_dkv"])
-            c_kv = rms_norm(ckv[..., :self.kv_lora_rank], params["kv_norm"],
+            c_kv = rms_norm(ckv[..., :self.kv_lora_rank],
+                            params["kv_norm"] if self.kv_rescale is None
+                            else params["kv_norm"] * self.kv_rescale,
                             self.eps)
             kv = project(c_kv, params["w_ukv"]).reshape(b, s, h, dn + dv)
         with jax.named_scope("ff.mla.rope"):
@@ -503,15 +621,43 @@ class LatentAttention(_PicksAttentionImpl, Op):
             vh = heads(kv[..., dn:])
         impl, why = self._pick_impl(s, s)
         self.impl_used = (impl, why)
-        if impl == "xla":
-            from ..parallel.sequence import blockwise_attention
-            oh, _ = blockwise_attention(qh, kh, vh, causal=True,
-                                        scale=self.softmax_scale)
+        if self.index is None and self.window is None:
+            if impl == "xla":
+                from ..parallel.sequence import blockwise_attention
+                oh, _ = blockwise_attention(qh, kh, vh, causal=True,
+                                            scale=self.softmax_scale)
+            else:
+                from ..kernels.flash_attention import flash_attention
+                oh = flash_attention(qh, kh, vh, causal=True,
+                                     scale=self.softmax_scale,
+                                     interpret=impl == "pallas_interpret")
+        elif self.index is None:
+            oh, _ = self._core(qh, kh, vh, None, impl)
         else:
-            from ..kernels.flash_attention import flash_attention
-            oh = flash_attention(qh, kh, vh, causal=True,
-                                 scale=self.softmax_scale,
-                                 interpret=impl == "pallas_interpret")
+            from .dsa import index_kl, select_topk
+            with jax.named_scope("ff.dsa.index"):
+                scores = self._index_scores(params, x, c_q)
+            with jax.named_scope("ff.dsa.select"):
+                keep = select_topk(scores, self.index[2])
+                # keys down the rows, as the kernels hold the scores
+                sel = keep if impl == "xla" else jnp.swapaxes(
+                    keep, 1, 2).astype(jnp.bfloat16)
+            oh, lse = self._core(qh, kh, vh, sel, impl)
+            if ctx.losses is not None:
+                with jax.named_scope("ff.dsa.loss"):
+                    sg = jax.lax.stop_gradient
+                    ctx.add_loss("dsa_index_kl", index_kl(
+                        scores, keep, sg(qh), sg(kh), sg(lse),
+                        self.softmax_scale))
+                if ctx.counters is not None:
+                    ctx.counters["dsa_layers"] = ctx.counters.get(
+                        "dsa_layers", 0.0) + jnp.float32(1)
+        if self.gate is not None:
+            with jax.named_scope("ff.mla.gate"):
+                g = jax.nn.sigmoid(project(x, params["w_gate"])
+                                   .astype(jnp.float32))       # (B, S, H)
+                oh = (oh.astype(jnp.float32)
+                      * g.transpose(0, 2, 1)[..., None]).astype(oh.dtype)
         with jax.named_scope("ff.mla.o_proj"):
             out = project(oh.transpose(0, 2, 1, 3).reshape(b, s, h * dv),
                           params["w_o"])
@@ -530,4 +676,25 @@ class LatentAttention(_PicksAttentionImpl, Op):
                           + e * (self.kv_lora_rank + dr)
                           + self.kv_lora_rank * h * (dn + dv)
                           + h * dv * e)
-        return proj + 2.0 * h * s * s * (dn + dr + dv)
+        # the pairs the core is given: the window's band, the selection,
+        # or all of them (what a masked kernel computes is not asked)
+        keys = min(s, self.window) if self.window is not None else s
+        proj += 2.0 * s * e * h * (self.gate is not None)
+        if self.index is not None:
+            keys = min(s, self.index[2])     # the index: unsplit_cost_...
+        return proj + 2.0 * h * s * keys * (dn + dr + dv)
+
+    def unsplit_cost_per_sample(self):
+        """(FLOPs, bytes) a sample of what every head share does whole,
+        for the cost model: the index's projections and its scores over
+        the causal pairs, in MXU passes (float32 at the highest precision
+        is six bf16 passes), and the selection's traffic over the (S, S)
+        scores: written, read by the top-k, the mask and the KL term,
+        and the mask read by the core forward and backward."""
+        if self.index is None:
+            return 0.0, 0.0
+        _, s, e = self.output.dims
+        ih, idim, _ = self.index
+        flops = 6.0 * (2.0 * s * (self.q_lora_rank * ih * idim
+                                  + e * (idim + ih)) + ih * s * s * idim)
+        return flops, float(s) * s * (4 * 4 + 2 * 4)

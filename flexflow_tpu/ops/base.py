@@ -38,6 +38,19 @@ class FwdCtx:
     # Scalars an op adds to under the names of its ``COUNTERS``; the train
     # step sums them into its metric vector.  None outside a train step.
     counters: Optional[Dict[str, jax.Array]] = None
+    # Scalars an op adds to the step's objective, by name (``add_loss``):
+    # the train step differentiates the final tensor's loss plus their
+    # sum.  None outside a train step, and an op then computes no term.
+    losses: Optional[Dict[str, jax.Array]] = None
+
+    def add_loss(self, name: str, value: jax.Array) -> None:
+        """Add ``value`` to the objective under ``name`` (terms of one
+        name are summed).  A term is also a counter of that name where
+        the op lists it in ``COUNTERS``, so the drain carries it out."""
+        self.losses[name] = self.losses.get(name, 0.0) + value
+        if self.counters is not None:
+            self.counters[name] = self.counters.get(name, 0.0) \
+                + jax.lax.stop_gradient(value)
 
     def op_rng(self, op: "Op") -> jax.Array:
         assert self.rng is not None, "op requires an RNG but none was provided"

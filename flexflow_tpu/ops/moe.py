@@ -207,25 +207,34 @@ def _ceil_div(a, b):
     return -(-a // b)
 
 
-def router_scores(x, router):
+def router_scores(x, router, scoring: str = "softmax"):
     """Every expert's affinity for every token: the softmax of ``x @
-    router`` over the experts, product and softmax in float32 at the
-    highest matmul precision whatever ``x``'s dtype (an affinity decides
-    which experts a token gets)."""
-    return jax.nn.softmax(jnp.dot(
-        x.astype(jnp.float32), router.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    router`` over the experts (or, with ``scoring="sigmoid"``, each
+    logit's sigmoid: DeepSeek-V3, arXiv:2412.19437, 2.1.2), product and
+    softmax in float32 at the highest matmul precision whatever ``x``'s
+    dtype (an affinity decides which experts a token gets)."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if scoring == "sigmoid":
+        return jax.nn.sigmoid(logits)
+    return jax.nn.softmax(logits, axis=-1)
 
 
 def route(scores, *, top_k: int, n_group: int, topk_group: int, first: int,
-          held: int, budget: int, tile_rows: int):
+          held: int, budget: int, tile_rows: int, select_bias=None,
+          norm_topk_prob: bool = False):
     """Group-limited top-k routing, this chip's part of it, under a device
     budget.  ``scores`` is (tokens, all experts), float32 affinities.
 
     1. Group-limited greedy (DeepSeek-V2, arXiv:2405.04434, 2.1.2): a
        group's score is its largest affinity, the ``topk_group`` best
        groups stay, and a token's ``top_k`` experts are the largest
-       affinities among their experts.
+       affinities among their experts.  With ``select_bias`` (all
+       experts,) the choice is by ``scores + select_bias`` and the
+       weights are the chosen experts' own ``scores`` (DeepSeek-V3's
+       ``noaux_tc``; the bias takes no gradient); with
+       ``norm_topk_prob`` a token's weights are divided by their sum
+       over all its ``top_k`` choices, those on absent experts too.
     2. Of the (token, expert) assignments that land on the held experts
        ``first .. first + held``, the ``budget`` with the largest
        affinity are kept (ties: lower token, then lower expert); the rest
@@ -251,7 +260,14 @@ def route(scores, *, top_k: int, n_group: int, topk_group: int, first: int,
         in_best = jax.nn.one_hot(best, n_group, dtype=jnp.int32).sum(axis=1)
         scores = jnp.where(jnp.repeat(in_best > 0, e // n_group, axis=1),
                            scores, 0.0)
-    vals, idx = jax.lax.top_k(scores, top_k)               # (t, top_k)
+    if select_bias is None:
+        vals, idx = jax.lax.top_k(scores, top_k)           # (t, top_k)
+    else:
+        _, idx = jax.lax.top_k(
+            jax.lax.stop_gradient(scores + select_bias), top_k)
+        vals = jnp.take_along_axis(scores, idx, axis=1)
+    if norm_topk_prob:
+        vals = vals / (jnp.sum(vals, axis=-1, keepdims=True) + 1e-20)
     local = idx - first
     here = (local >= 0) & (local < held)
     # top_k prefers the lower index among equals: within a token the
@@ -373,9 +389,17 @@ class RoutedExperts(_ExpertDim, Op):
                  n_group: int = 1, topk_group: int = 1,
                  routed_scaling_factor: float = 1.0,
                  n_shared_experts: int = 0, capacity_factor: float = 1.0,
-                 tile_rows: int = 128, name: Optional[str] = None):
+                 tile_rows: int = 128, scoring: str = "softmax",
+                 select_bias: bool = False, norm_topk_prob: bool = False,
+                 name: Optional[str] = None):
         super().__init__(model, [input_tensor], name)
         from ..initializers import StackedGlorotUniform
+
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"{self.name}: unknown scoring {scoring!r}")
+        self.scoring = scoring
+        self.select_bias = bool(select_bias)
+        self.norm_topk_prob = bool(norm_topk_prob)
 
         dims = input_tensor.dims
         d = dims[-1]
@@ -417,6 +441,14 @@ class RoutedExperts(_ExpertDim, Op):
         return (f"e{self.held}of{self.n_routed}k{self.top_k}"
                 f"w{self.hidden_size}s{self.n_shared}")
 
+    def init_stats(self):
+        """The selection bias, where the router has one: a buffer that
+        no gradient touches (its balancing update is not implemented:
+        the buffer stays as it starts, zeros)."""
+        if not self.select_bias:
+            return {}
+        return {"select_bias": jnp.zeros((self.n_routed,), jnp.float32)}
+
     def budget(self, tokens: int) -> int:
         """Rows of the device's buffer: ``capacity_factor`` times the
         assignments an even router would send these experts (the paper
@@ -439,12 +471,19 @@ class RoutedExperts(_ExpertDim, Op):
         shape, dt = x.shape, x.dtype
         xf = x.reshape(-1, shape[-1])
         tokens = xf.shape[0]
+        # a softmax router is called as it always was: the benchmark's
+        # stand-in for a lower precision (logit_check.lower_router) takes
+        # the two arguments
+        how = {} if self.scoring == "softmax" else {"scoring": self.scoring}
+        bias = ctx.stats_in[self.name]["select_bias"] \
+            if self.select_bias else None
         with jax.named_scope("ff.moe.route"):
-            r = route(router_scores(xf, params["router"]), top_k=self.top_k,
-                      n_group=self.n_group,
+            r = route(router_scores(xf, params["router"], **how),
+                      top_k=self.top_k, n_group=self.n_group,
                       topk_group=self.topk_group, first=self.first,
                       held=self.held, budget=self.budget(tokens),
-                      tile_rows=self.tile_rows)
+                      tile_rows=self.tile_rows, select_bias=bias,
+                      norm_topk_prob=self.norm_topk_prob)
         if ctx.counters is not None:
             sizes = r["sizes"].astype(jnp.float32)
             for name, value in zip(self.COUNTERS, (

@@ -104,8 +104,14 @@ def counters() -> Dict[str, float]:
     ``moe_assignments_kept_per_token`` (a token and expert layer),
     ``moe_dropped_share`` (of the assignments made, those the device
     budget dropped) and ``moe_load_max_over_mean`` (the held experts'
-    largest kept load over their mean, averaged over layers and steps)."""
+    largest kept load over their mean, averaged over layers and steps).
+    Where a model with a learned index (``ops/dsa.py``) has drained,
+    ``dsa_index_kl``: the index's objective ``L_I``, the mean over the
+    indexed layers and every drained step (the layers summed a term each
+    into the step's objective, ``FwdCtx.add_loss``)."""
     out = dict(_counters)
+    if out.get("dsa_layers"):
+        out["dsa_index_kl"] /= out["dsa_layers"]
     tokens = out.get("moe_tokens")
     if tokens:
         made, kept = out["moe_assignments_made"], out["moe_assignments_kept"]

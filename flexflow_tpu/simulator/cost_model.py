@@ -383,6 +383,13 @@ class CostModel:
                     for wi in range(len(w_op.weights)))
         out_vol = int(np.prod(sub))
         bytes_moved = self._dtype_bytes * (in_vol + w_vol + out_vol)
+        whole = getattr(op, "unsplit_cost_per_sample", None)
+        if whole is not None:
+            # work every part does whole for its samples whatever the
+            # other degrees (a learned index: its scores, the selection)
+            w_flops, w_bytes = whole()
+            flops += w_flops * sub[0]
+            bytes_moved += w_bytes * sub[0]
         fam = type(op).__name__
         eff = m.op_efficiency.get(fam, m.mxu_efficiency)
         t = max(flops / (m.peak_flops * eff),

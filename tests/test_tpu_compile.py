@@ -1,4 +1,4 @@
-"""The kernels of the DeepSeek-V2 cell at their real widths, compiled for a
+"""The kernels of the DeepSeek-V2 and dots3 cells at their real widths, compiled for a
 v5e that is described and not attached: what the chip's compiler refuses
 (a block Mosaic cannot tile, too much VMEM) costs no chip time.  Nothing
 runs, so nothing here is a result or a time.  The topology is described
@@ -48,6 +48,39 @@ def test_flash_kernels_at_latent_attention_heads(one_chip):
     compiled = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(
         spec(192), spec(192), spec(128)).compile()
     assert _custom_calls(compiled) >= 3        # forward, dq, dkv
+
+
+def _grads_compile(one_chip, heads, d, dv, **how):
+    """1 sequence of 8192: forward, dq and dkv of a call with ``how``."""
+    def spec(width):
+        return jax.ShapeDtypeStruct((1, heads, 8192, width), jnp.bfloat16,
+                                    sharding=one_chip)
+    extra = [jax.ShapeDtypeStruct((1, 8192, 8192), jnp.bfloat16,
+                                  sharding=one_chip)] if "select" in how else []
+
+    def f(q, k, v, *sel):
+        kw = dict(how, select=sel[0]) if sel else how
+        return jnp.sum(flash_attention(q, k, v, causal=True, scale=d ** -0.5,
+                                       **kw).astype(jnp.float32))
+    compiled = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(
+        spec(d), spec(d), spec(dv), *extra).compile()
+    return compiled.as_text()
+
+
+def test_window_kernels_at_the_window_layers_heads(one_chip):
+    """dots3's window layers: 2 heads of 256 | 128, 513 keys a query."""
+    text = _grads_compile(one_chip, 2, 256, 128, window=513)
+    assert text.count("tpu_custom_call") >= 3
+    assert "flash_win_fwd" in text and "flash_win_dkv" in text
+
+
+def test_selected_kernels_at_the_full_layers_heads(one_chip):
+    """dots3's full layers: 4 heads of 192 | 128 under a bfloat16
+    selection of 8192 x 8192 (compared in float32: the v5e compares no
+    bfloat16, which the interpreter does not mind)."""
+    text = _grads_compile(one_chip, 4, 192, 128, select=True)
+    assert text.count("tpu_custom_call") >= 3
+    assert "flash_sel_fwd" in text and "flash_sel_dq" in text
 
 
 @pytest.mark.parametrize("k,n", [(5120, 1536), (1536, 5120)])
