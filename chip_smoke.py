@@ -63,14 +63,14 @@ FULL = dict(
     alexnet_batch=256, image=229, warmup=3, timed=12,
     layers=4, embed=512, heads=8, seq=512, batch=16, vocab=32000,
     flash_shapes=((16, 8, 512, 64), (4, 16, 1024, 64), (2, 16, 4096, 128)),
-    flash_window=513,
+    flash_window=513, index_shape=(1, 2048, 8, 128),
     serve_seq=128, prompts=(5, 12, 33, 60), new_tokens=(8, 12, 16, 10),
     search_budget=2000)
 TINY = dict(
     alexnet_batch=8, image=67, warmup=2, timed=3,
     layers=2, embed=64, heads=4, seq=64, batch=4, vocab=128,
     flash_shapes=((2, 2, 64, 16), (1, 2, 128, 32)),
-    flash_window=9,
+    flash_window=9, index_shape=(1, 256, 2, 128),
     serve_seq=64, prompts=(3, 5, 9, 17), new_tokens=(4, 6, 8, 5),
     search_budget=200)
 
@@ -211,13 +211,16 @@ def phase_kernels(sz, dev, stats):
                                                     **kw))
     ref = graded(mha_reference)
 
+    def said(tiling_):
+        # per kernel "block_q x block_k body/grid steps"
+        return {kernel: "{block_q}x{block_k} {body_steps}/{grid_steps}"
+                .format(**t) for kernel, t in tiling_.items()}
+
     worst, tiles = {}, {}
     for shape in sz["flash_shapes"]:
-        # which tiling ran: per kernel "block_q x block_k body/grid steps"
-        tiles["x".join(map(str, shape))] = {
-            kernel: "{block_q}x{block_k} {body_steps}/{grid_steps}".format(**t)
-            for kernel, t in tiling(shape[2], shape[2], shape[3],
-                                    causal=True).items()}
+        # which tiling ran
+        tiles["x".join(map(str, shape))] = said(tiling(
+            shape[2], shape[2], shape[3], causal=True))
         for dtype in (jnp.bfloat16, jnp.float32):
             ks = jax.random.split(jax.random.key(shape[2]), 4)
             q, k, v = (jax.random.normal(kk, shape, jnp.float32).astype(dtype)
@@ -252,10 +255,38 @@ def phase_kernels(sz, dev, stats):
     for tag, how in ((f"window{sz['flash_window']}",
                       dict(window=sz["flash_window"])),
                      ("selected", dict(selected=True))):
-        tiles["x".join(map(str, longest)) + "/" + tag] = {
-            kernel: "{block_q}x{block_k} {body_steps}/{grid_steps}".format(**t)
-            for kernel, t in tiling(longest[2], longest[2], longest[3],
-                                    causal=True, **how).items()}
+        tiles["x".join(map(str, longest)) + "/" + tag] = said(tiling(
+            longest[2], longest[2], longest[3], causal=True, **how))
+    # the index's scores and their gradient (dsa_index_fwd, _bwd)
+    # against ops/dsa.py's blocks, and what they do with dots3's index
+    from flexflow_tpu.kernels import dsa_index
+    from flexflow_tpu.ops import dsa
+    b, t, h, d = sz["index_shape"]
+    ks = jax.random.split(jax.random.key(t), 4)
+    q, k, w = (jax.random.normal(kk, shp, jnp.float32) for kk, shp in zip(
+        ks, ((b, t, h, d), (b, t, d), (b, t, h))))
+    g = jnp.tril(jax.random.normal(ks[3], (b, t, t), jnp.float32))
+
+    def scored(impl):
+        def f(q, k, w):
+            scores = jnp.tril(dsa.index_scores(q, k, w, jnp.bfloat16, 256,
+                                               1024, impl))
+            return jnp.sum(scores * g), scores
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2),
+                                          has_aux=True))
+    (_, got), got_grads = scored(
+        "pallas_interpret" if interpret else "pallas")(q, k, w)
+    (_, want), want_grads = scored("xla")(q, k, w)
+    tag = "index/" + "x".join(map(str, sz["index_shape"]))
+    errs = {name: float(jnp.abs(a - r).max() / jnp.abs(r).max())
+            for name, a, r in zip(("scores", "dq", "dk", "dw"),
+                                  (got,) + got_grads, (want,) + want_grads)}
+    worst[tag] = round(max(errs.values()), 5)
+    check(errs["scores"] <= 1e-5 and worst[tag] <= KERNEL_TOL,
+          f"dsa_index {tag} off ops/dsa.py's blocks: {errs}")
+    for shape in ((t, h, d), (8192, 64, 128)):
+        tiles["index/" + "x".join(map(str, shape))] = said(
+            dsa_index.tiling(*shape))
     result("kernels", compiled=not interpret, tolerance=KERNEL_TOL,
            max_normalized_error=worst, tiling=tiles)
 

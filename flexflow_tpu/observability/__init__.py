@@ -27,10 +27,13 @@ The step's own timeline lives beside this package, in
     ``ff.`` when telemetry is on (``ff.update`` is the log's ``step``),
   * ``jax.named_scope``s in the compiled step: ``ff.op.<type>.<name>``
     a graph op, ``ff.input_cast``, ``ff.loss``, ``ff.metrics``,
-    ``ff.optimizer``, ``ff.guard``, ``ff.kernel.flash_fwd`` / ``_dq`` /
-    ``_dkv``; ``profiling.step_scopes()`` maps every instruction of the
-    loaded step programs to its scope and phase (fwd / bwd / opt /
-    other), and ``profiling.trace(logdir)`` writes that map beside the
+    ``ff.optimizer``, ``ff.guard``, and the kernels' ``ff.kernel.<name>``:
+    ``flash_fwd`` / ``_dq`` / ``_dkv`` (``flash_win_*`` under a window,
+    ``flash_sel_*`` under a selection), ``gmm`` / ``gmm_t`` / ``tgmm``,
+    ``dsa_index_fwd`` / ``_bwd``; ``profiling.step_scopes()`` maps every
+    instruction of the loaded step programs to its scope and phase (fwd
+    / bwd / opt / other), and ``profiling.trace(logdir)`` writes that map
+    beside the
     trace as ``ff_step_scopes.json``,
   * ``profiling.counters()``: ``train_step_compiles`` /
     ``train_step_compile_s``.

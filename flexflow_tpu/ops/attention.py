@@ -379,6 +379,12 @@ def yarn_inv_freq(dim: int, theta: float = 10000.0, factor: float = 1.0,
     return extra / factor * ramp + extra * (1.0 - ramp)
 
 
+def _rotary_angles(s: int, inv_freq):
+    """(s, r/2) float32: ``position * inv_freq[i]``."""
+    return jnp.arange(s, dtype=jnp.float32)[:, None] \
+        * jnp.asarray(inv_freq, jnp.float32)[None, :]
+
+
 def rotate_pairs(x, inv_freq, scale: float = 1.0):
     """Rotary position embedding of x (B, S, ..., d) along axis 1, on
     adjacent pairs (x[2i], x[2i+1]) by the angle ``position * inv_freq[i]``.
@@ -386,8 +392,7 @@ def rotate_pairs(x, inv_freq, scale: float = 1.0):
     second: a fixed permutation of the pairs' layout, which a dot product
     of two vectors rotated here does not see."""
     s = x.shape[1]
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] \
-        * jnp.asarray(inv_freq, jnp.float32)[None, :]
+    ang = _rotary_angles(s, inv_freq)
     shape = (1, s) + (1,) * (x.ndim - 3) + (ang.shape[-1],)
     cos = (jnp.cos(ang) * scale).reshape(shape)
     sin = (jnp.sin(ang) * scale).reshape(shape)
@@ -534,10 +539,37 @@ class LatentAttention(_PicksAttentionImpl, Op):
             key += "i{}.{}.{}".format(*self.index)
         return key
 
-    def _index_scores(self, params, x, c_q):
+    # (impl, why) of the index's scores at the last trace, beside the
+    # core's ``impl_used``.
+    index_impl_used: Optional[Tuple[str, str]] = None
+
+    def _pick_index_impl(self, impl: str, why: str, seq: int
+                         ) -> Tuple[str, str]:
+        """(impl, why) of the index's scores, given the core's: the
+        kernels where the core runs its own and they can take the shape
+        (``kernels.dsa_index.unsupported_reason``), else ``ops/dsa.py``'s
+        ``jax.numpy`` form; on a TPU that is worth a warning."""
+        if impl == "xla":
+            return impl, why
+        from ..kernels.dsa_index import unsupported_reason
+        refused = unsupported_reason(seq, *self.index[:2])
+        if refused is None:
+            return impl, why
+        if self.impl is None:
+            warnings.warn(f"{self.name}: {refused}; the index's scores "
+                          f"run as XLA's blocks")
+        return "xla", refused
+
+    def _index_scores(self, params, x, c_q, impl="xla"):
         """``I`` (B, S, S) float32 of the index, from the op's input and
-        the query latent, both constants to it."""
-        from .dsa import index_scores
+        the query latent, both constants to it; ``impl`` as
+        ``dsa.index_scores`` takes it.  Its XLA form is given the queries
+        turned; the kernels turn them themselves, in VMEM, and are given
+        what ``W^I_q`` with each head's rotary pairs set apart makes (a
+        permutation of 33 MB of weights and of their gradient, where the
+        turn and its gradient were a dozen passes over the 268 MB of
+        queries: PERF.md section 6, PR 36)."""
+        from .dsa import index_scores, pairs_apart
 
         ih, idim, _ = self.index
         b, s, _ = x.shape
@@ -546,21 +578,28 @@ class LatentAttention(_PicksAttentionImpl, Op):
         grad_dtype = x.dtype
         x = jax.lax.stop_gradient(x).astype(f32)
         c_q = jax.lax.stop_gradient(c_q).astype(f32)
-        qi = jnp.dot(c_q, params["wi_q"].astype(f32),
-                     precision=hi).reshape(b, s, ih, idim)
+        turn = lambda t: jnp.concatenate(
+            [rotate_pairs(t[..., :self.rope], self.inv_freq),
+             t[..., self.rope:]], axis=-1)
+        wi_q, q_rope = params["wi_q"].astype(f32), None
+        if impl != "xla":
+            wi_q = pairs_apart(wi_q.reshape(-1, ih, idim),
+                               self.rope).reshape(wi_q.shape)
+            ang = _rotary_angles(s, self.inv_freq)
+            q_rope = (jnp.cos(ang), jnp.sin(ang))
+        qi = jnp.dot(c_q, wi_q, precision=hi).reshape(b, s, ih, idim)
+        if q_rope is None:
+            qi = turn(qi)
         ki = jnp.dot(x, params["wi_k"].astype(f32), precision=hi)
         mean = jnp.mean(ki, axis=-1, keepdims=True)
         var = jnp.mean(jnp.square(ki - mean), axis=-1, keepdims=True)
         ki = (ki - mean) * jax.lax.rsqrt(var + self.eps) \
             * params["wi_k_scale"].astype(f32) \
             + params["wi_k_bias"].astype(f32)
-        turn = lambda t: jnp.concatenate(
-            [rotate_pairs(t[..., :self.rope], self.inv_freq),
-             t[..., self.rope:]], axis=-1)
         w = jnp.dot(x, params["wi_w"].astype(f32), precision=hi) \
             * (ih ** -0.5 * idim ** -0.5)
-        return index_scores(turn(qi), turn(ki[:, :, None, :])[:, :, 0], w,
-                            grad_dtype)
+        return index_scores(qi, turn(ki[:, :, None, :])[:, :, 0], w,
+                            grad_dtype, 256, 1024, impl, q_rope)
 
     def _core(self, qh, kh, vh, keep, impl):
         """The attention core of a layer with a window or an index: the
@@ -635,8 +674,10 @@ class LatentAttention(_PicksAttentionImpl, Op):
             oh, _ = self._core(qh, kh, vh, None, impl)
         else:
             from .dsa import index_kl, select_topk
+            self.index_impl_used = self._pick_index_impl(impl, why, s)
             with jax.named_scope("ff.dsa.index"):
-                scores = self._index_scores(params, x, c_q)
+                scores = self._index_scores(params, x, c_q,
+                                            self.index_impl_used[0])
             with jax.named_scope("ff.dsa.select"):
                 keep = select_topk(scores, self.index[2])
                 # keys down the rows, as the kernels hold the scores

@@ -13,10 +13,22 @@ with ``p_t`` the main attention's probabilities over ``S_t`` summed over
 the heads held and normalised to 1, a constant.  Nothing here is ever
 held as (heads, T, T): the scores are reduced over the index's heads a
 block of queries at a time, forward and backward, and so is the target.
-Plain ``jax.numpy``; the scores in float32 at the highest matmul
-precision (a score decides which keys a query gets, as a router's
-affinity decides its experts), their gradient in the step's compute
-dtype at the default one.
+The scores in float32 at the highest matmul precision (a score decides
+which keys a query gets, as a router's affinity decides its experts),
+their gradient in the step's compute dtype at the default one.
+
+What runs where.  ``select_topk``, ``index_kl`` and ``masked_attention``
+are plain ``jax.numpy`` on every platform.  ``index_scores`` has two
+forms, and its caller says which (``impl``, as the attention core's):
+the Pallas kernels of ``kernels/dsa_index.py`` on a TPU where the shape
+tiles, which hold a tile's (heads x queries, keys) product in VMEM and
+write ``I`` alone (``"pallas"``; ``"pallas_interpret"`` for a test; they
+also take the queries' rotary turn, so that nothing as large as the
+queries passes through HBM between the projection and them), and
+the ``jax.numpy`` form below elsewhere (``"xla"``: the CPU, the tests'
+32-token shapes), which is also the kernels' reference: a Python loop
+over blocks of queries that XLA computes through HBM, held in turn by
+``optimization_barrier``.
 """
 
 from __future__ import annotations
@@ -57,18 +69,56 @@ def _head_scores(qb, kb, precision, dtype=jnp.float32):
     return s.reshape(b, n, h, kb.shape[1])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def pairs_apart(x, rope: int):
+    """``x`` (..., d) with the first ``rope`` of its last dimension, r/2
+    adjacent pairs, as the pairs' first elements and then their second
+    ones: the layout ``turn_halves`` and the kernels turn."""
+    pairs = x[..., :rope].reshape(x.shape[:-1] + (rope // 2, 2))
+    return jnp.concatenate([pairs[..., 0], pairs[..., 1], x[..., rope:]],
+                           axis=-1)
+
+
+def turn_halves(q, cos, sin):
+    """The rotary turn of ``q`` (B, T, heads, d) whose heads hold the r/2
+    rotary pairs' first elements, then their second ones, then what is
+    not turned; ``cos``, ``sin`` (T, r/2).  What ``rotate_pairs`` makes
+    of adjacent pairs, value for value."""
+    half = cos.shape[-1]
+    a, b = q[..., :half], q[..., half:2 * half]
+    c, s = cos[None, :, None, :], sin[None, :, None, :]
+    return jnp.concatenate([a * c - b * s, a * s + b * c, q[..., 2 * half:]],
+                           axis=-1)
+
+
 def index_scores(q, k, w, grad_dtype=jnp.float32, block_q: int = 256,
-                 super_q: int = 1024):
+                 super_q: int = 1024, impl: str = "xla", q_rope=None):
     """``I`` (B, T, T) float32 from the index's queries ``q`` (B, T,
     heads, d), its one key a position ``k`` (B, T, d) and the head
     weights ``w`` (B, T, heads), all float32.  Entries above the diagonal
     hold ``NEG_INF`` or a score: ``select_topk`` and ``index_kl`` read
-    the causal ones alone.  The blocks are a Python loop, not a
-    ``lax.map``: a while loop's time is counted twice in a device trace
-    (its own event and its body's).  ``grad_dtype``: what the backward
-    pass holds its (heads, block, keys) products in, the step's compute
-    dtype."""
+    the causal ones alone.  ``grad_dtype``: what the backward pass holds
+    its (heads, queries, keys) products in, the step's compute dtype.
+    ``impl``: ``"xla"``, this module's form in blocks of ``block_q``
+    queries within super blocks of ``super_q``; ``"pallas"`` or
+    ``"pallas_interpret"``, the kernels, whose blocks are their own
+    rule's (``kernels.dsa_index.tiling``).  ``q_rope``: None for queries
+    that come turned, or ``(cos, sin)`` for queries laid out as
+    ``turn_halves`` takes them and not turned yet; the kernels then turn
+    them a q block at a time in VMEM, and their gradient back."""
+    if impl == "xla":
+        if q_rope is not None:
+            q = turn_halves(q, *q_rope)
+        return _index_scores_xla(q, k, w, grad_dtype, block_q, super_q)
+    from ..kernels import dsa_index
+    return dsa_index.index_scores(q, k, w, q_rope, grad_dtype, None, None,
+                                  impl == "pallas_interpret")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _index_scores_xla(q, k, w, grad_dtype, block_q, super_q):
+    """The blocks are a Python loop, not a ``lax.map``: a while loop's
+    time is counted twice in a device trace (its own event and its
+    body's)."""
     return _index_scores_fwd(q, k, w, grad_dtype, block_q, super_q)[0]
 
 
@@ -109,7 +159,7 @@ def _index_scores_bwd(grad_dtype, block_q, super_q, res, g):
     return jnp.concatenate(dqs, axis=1), dk, jnp.concatenate(dws, axis=1)
 
 
-index_scores.defvjp(_index_scores_fwd, _index_scores_bwd)
+_index_scores_xla.defvjp(_index_scores_fwd, _index_scores_bwd)
 
 
 def causal_mask(t: int):

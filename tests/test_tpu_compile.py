@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from flexflow_tpu.kernels.dsa_index import index_scores
 from flexflow_tpu.kernels.flash_attention import flash_attention
 from flexflow_tpu.kernels.grouped_matmul import grouped_matmul
 
@@ -27,9 +28,12 @@ def one_chip():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     # a program compiled for a described chip is written to the
     # persistent cache and cannot be read back without one
+    from jax.experimental.compilation_cache import compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()     # one that is initialised stays on
     yield SingleDeviceSharding(topo.devices[0])
     jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
 
 
 def _custom_calls(compiled):
@@ -81,6 +85,26 @@ def test_selected_kernels_at_the_full_layers_heads(one_chip):
     text = _grads_compile(one_chip, 4, 192, 128, select=True)
     assert text.count("tpu_custom_call") >= 3
     assert "flash_sel_fwd" in text and "flash_sel_dq" in text
+
+
+def test_index_kernels_at_the_full_layers_index(one_chip):
+    """dots3's index: 64 heads of 128 over 1 sequence of 8192, the first
+    64 of a head turned in the kernels, float32 scores at the highest
+    precision and their gradient from bfloat16 operands.  Nothing as long
+    as a (heads x queries, keys) product is left in HBM: XLA's blocks
+    held 3.3 GB of such temporaries (PERF.md, PR 35)."""
+    def spec(*shape, lead=(1, 8192)):
+        return jax.ShapeDtypeStruct(lead + shape, jnp.float32,
+                                    sharding=one_chip)
+
+    def f(q, k, w, g, cos, sin):
+        return jnp.sum(index_scores(q, k, w, (cos, sin), jnp.bfloat16) * g)
+    compiled = jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2))).lower(
+        spec(64, 128), spec(128), spec(64), spec(8192),
+        spec(32, lead=(8192,)), spec(32, lead=(8192,))).compile()
+    text = compiled.as_text()
+    assert "dsa_index_fwd" in text and "dsa_index_bwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
 @pytest.mark.parametrize("k,n", [(5120, 1536), (1536, 5120)])
