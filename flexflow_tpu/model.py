@@ -55,6 +55,9 @@ from .ops.misc import (BatchNorm, Concat, Dropout, ElementBinary, ElementUnary,
 from .parallel.mesh import Machine, dim_roles
 from .parallel.strategy import load_strategies_from_file, save_strategies_to_file
 from .runtime.profiling import count as _ff_count
+from .runtime.profiling import graph_built as _ff_graph_built
+from .runtime.profiling import model_made as _ff_model_made
+from .runtime.profiling import phase as _ff_phase
 from .runtime.profiling import span as _ff_span
 from .runtime.profiling import step_enqueue as _ff_step_enqueue
 from .tensor import DataType, Parameter, Tensor
@@ -95,6 +98,9 @@ def _copy_state_tree(state):
 
 class FFModel:
     def __init__(self, config: Optional[FFConfig] = None):
+        # the process's first model times what came before it and its
+        # own graph's construction (profiling.counters())
+        self._graph_since = _ff_model_made()
         self.config = config or FFConfig()
         self._guid = itertools.count(100)  # reference op_global_guid starts at 100
         self.ops: List[Op] = []
@@ -845,6 +851,9 @@ class FFModel:
         model learns whether ``FFConfig.telemetry`` / ``FF_TELEMETRY`` is
         set — so every later step guards on a plain ``None`` handle.
         """
+        if self._graph_since is not None:
+            _ff_graph_built(self._graph_since)
+            self._graph_since = None
         from .observability import events as _ff_events
         from .observability import health as _ff_health
         from .runtime import resilience as _ff_resilience
@@ -862,8 +871,8 @@ class FFModel:
         self._nonfinite_guard = (
             _ff_resilience.NonFiniteGuard(self, _nf, self._telemetry)
             if _nf else None)
-        with _ff_span(self._telemetry, "compile",
-                      **self._compile_span_attrs()) as at:
+        with _ff_phase(self._telemetry, "compile",
+                       **self._compile_span_attrs()) as at:
             self._compile_impl(optimizer, loss_type, metrics, machine)
             at["num_devices"] = self.machine.num_devices
             at["batch_size"] = self.config.batch_size
@@ -1129,8 +1138,8 @@ class FFModel:
             cfg.strategies.update(strategies)
         tel = self._telemetry
         try:
-            with _ff_span(tel, "recompile",
-                          **self._compile_span_attrs()) as at:
+            with _ff_phase(tel, "recompile",
+                           **self._compile_span_attrs()) as at:
                 self._compile_impl(
                     self.optimizer, self.loss.loss_type,
                     list(self.metrics.metrics),
@@ -1656,7 +1665,13 @@ class FFModel:
                 for k, v in state.items()}
 
     def init_layers(self, seed: Optional[int] = None) -> None:
+        """Make the parameters (one jitted init, compiled or fetched
+        here), the ops' statistics and the optimizer's state."""
         assert self._compiled, "call compile() first"
+        with _ff_phase(self._telemetry, "init_layers"):
+            self._init_layers_impl(seed)
+
+    def _init_layers_impl(self, seed: Optional[int]) -> None:
         seed = self.config.seed if seed is None else seed
         key = jax.random.key(seed)
         shardings = self._param_spec_tree()
@@ -2299,7 +2314,7 @@ class FFModel:
         if self._train_step_fn is not None:
             return self._run_step(contextlib.nullcontext())
         # first update() after a (re)build: build, trace and compile
-        with _ff_span(self._telemetry, "step_build"):
+        with _ff_phase(self._telemetry, "step_build"):
             compile_ctx = contextlib.nullcontext()
             self._train_step_fn = self._build_train_step()
             if self._fresh_jit:

@@ -19,7 +19,7 @@ through ``runtime/profiling.span``, given the handle resolved once at
 The step's own timeline lives beside this package, in
 ``runtime/profiling.py`` (docs/observability.md has the tables):
 
-  * host spans ``ff.compile``, ``ff.update`` (with ``ff.step_build``,
+  * host spans ``ff.compile``, ``ff.init_layers``, ``ff.update`` (with ``ff.step_build``,
     ``ff.update.prepare`` / ``.enqueue`` / ``.finish`` inside),
     ``ff.sync``, ``ff.metric_drain``, ``ff.data_wait``,
     ``ff.checkpoint_save`` / ``_restore``: ``TraceAnnotation``s on the
@@ -35,8 +35,14 @@ The step's own timeline lives beside this package, in
     / bwd / opt / other), and ``profiling.trace(logdir)`` writes that map
     beside the
     trace as ``ff_step_scopes.json``,
-  * ``profiling.counters()``: ``train_step_compiles`` /
-    ``train_step_compile_s``.
+  * ``profiling.counters()``: set-up on the host's clock, with or
+    without a profiler: ``span_s.<phase>`` for the spans outside the
+    step loop (``profiling.phase``), JAX's trace / lower / backend /
+    fetch events by the phase they fired in
+    (``stage_s.<phase>.<stage>``), the step's own as
+    ``train_step_compiles`` / ``_compile_s`` / ``_trace_s`` /
+    ``_lower_s`` / ``_compile_call_s``, and ``before_first_model_s`` /
+    ``graph_build_s`` for what lies before the first ``compile()``.
 
 ``events``    — the env/flag-gated structured event log (spans +
                 counters + gauges, thread-safe, JSONL sink).

@@ -26,7 +26,7 @@ from typing import Any, Dict, Optional
 import jax
 import numpy as np
 
-from .profiling import span
+from .profiling import phase
 
 
 def _unpack_tree(model, tree: Dict[str, Any]) -> Dict[str, Any]:
@@ -171,8 +171,8 @@ def save_checkpoint(model, path: str, force: bool = True) -> None:
     write_heartbeat("checkpoint_save",
                     step=getattr(model, "_step_count", 0))
     tel = getattr(model, "_telemetry", None)
-    with span(tel, "checkpoint_save", path=path,
-              step=getattr(model, "_step_count", 0)):
+    with phase(tel, "checkpoint_save", path=path,
+               step=getattr(model, "_step_count", 0)):
         _save_checkpoint_impl(model, path, force)
     if tel is not None:
         tel.flush()
@@ -212,7 +212,7 @@ def load_checkpoint(model, path: str) -> None:
 
     write_heartbeat("checkpoint_restore")
     tel = getattr(model, "_telemetry", None)
-    with span(tel, "checkpoint_restore", path=path):
+    with phase(tel, "checkpoint_restore", path=path):
         _load_checkpoint_impl(model, path)
     if tel is not None:
         tel.flush()

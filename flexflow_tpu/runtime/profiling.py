@@ -18,7 +18,14 @@ Reference instrumentation (SURVEY §5.1): per-op cudaEvent timers behind
     from, read from the optimized HLO of the loaded executables: the
     join between a device trace and the ``jax.named_scope``s of
     ``FFModel._build_train_step``,
-  * ``counters()`` — process-wide counters: how often the train step
+  * ``phase(log, name)`` — a span outside the step loop (``compile``,
+    ``init_layers``, ``step_build``, a checkpoint): no profiler runs
+    while a process starts, so a phase also adds its wall seconds to the
+    counters, and JAX's trace, lowering, compile and cache-fetch events
+    are put down to the phase they fired in by the package's one pair
+    of ``jax.monitoring`` listeners,
+  * ``counters()`` — process-wide counters: set-up by phase and stage,
+    how often the train step
     was compiled, and for how long, and what the graph's ops counted in
     their steps (``Op.COUNTERS``: the routed experts' assignments made,
     kept and dropped, and their load), which arrive with the metric
@@ -35,6 +42,8 @@ import contextlib
 import json
 import os
 import re
+import threading
+import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -77,27 +86,225 @@ def span(log, name: str, **attrs):
 
 
 # ----------------------------------------------------------------------
-# counters
+# phases: set-up on the host's clock, and JAX's compile pipeline by phase
 # ----------------------------------------------------------------------
-_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
-_counters = {"train_step_compiles": 0, "train_step_compile_s": 0.0}
-_enqueue_depth = 0
-_listening = False
+_counters: Dict[str, float] = {}
+_NO_PHASE = "none"
+_ENQUEUE = "update.enqueue"
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_STAGES = {
+    _LOWER: "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "fetch",
+}
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+# the names the step's share of the pipeline is read by
+_TRAIN_STEP = {
+    "train_step_compiles": f"stage_n.{_ENQUEUE}.backend",
+    "train_step_compile_s": f"stage_s.{_ENQUEUE}.backend",
+    "train_step_trace_s": f"stage_s.{_ENQUEUE}.trace",
+    "train_step_lower_s": f"stage_s.{_ENQUEUE}.lower",
+}
+
+
+class _Thread(threading.local):
+    """What the listener needs to know of the thread an event fires on."""
+
+    def __init__(self):
+        self.phases: List[str] = []  # the open phases, innermost last
+        self.fired = 0  # compile-pipeline events seen on this thread
+        # (start, seconds) of the trace events since the last lowering
+        self.traces: List[Tuple[float, float]] = []
+
+
+_thread = _Thread()
+_lock = threading.Lock()  # the counters, wherever two threads may add
+
+
+def _add(name: str, value: float) -> None:
+    _counters[name] = _counters.get(name, 0.0) + value
+
+
+def _staged(stage: str, secs: Optional[float] = None) -> None:
+    """One pipeline event of this thread, to its innermost open phase."""
+    where = _thread.phases[-1] if _thread.phases else _NO_PHASE
+    with _lock:
+        _add(f"stage_n.{where}.{stage}", 1)
+        if secs is not None:
+            _add(f"stage_s.{where}.{stage}", secs)
+
+
+@contextlib.contextmanager
+def phase(log, name: str, **attrs):
+    """A span that runs outside the step loop (``compile``,
+    ``init_layers``, ``step_build``, a checkpoint): all that ``span``
+    is, and besides its wall seconds (``time.perf_counter``, the
+    ``EventLog``'s clock) and its count go to ``counters()`` as
+    ``span_s.<name>`` and ``span_n.<name>``, with or without a profiler,
+    and while it is open it is the phase that JAX's compile-pipeline
+    events on this thread are put down to (``stage_s.<name>.<stage>``).
+    The step loop's spans are not phases: a steady step changes no
+    counter."""
+    _thread.phases.append(name)
+    t0 = time.perf_counter()
+    try:
+        with span(log, name, **attrs) as at:
+            yield at
+    finally:
+        secs = time.perf_counter() - t0
+        _thread.phases.pop()
+        with _lock:
+            _add("span_s." + name, secs)
+            _add("span_n." + name, 1)
+
+
+@contextlib.contextmanager
+def step_enqueue(log):
+    """The span ``update.enqueue`` around the call of the jitted train
+    step, and a phase on condition: a compilation that fires while it
+    is open is the step's (``stage_s.update.enqueue.<stage>``, read as
+    ``train_step_*``), and only a call in which one fired adds its wall
+    time to ``train_step_compile_call_s``: the whole cost of a call that
+    traced, lowered, compiled or fetched, loaded and dispatched the
+    step.  A steady call reads the clock once and changes no counter."""
+    th = _thread
+    th.phases.append(_ENQUEUE)
+    fired = th.fired
+    t0 = time.perf_counter()
+    try:
+        with span(log, _ENQUEUE):
+            yield
+    finally:
+        th.phases.pop()
+        if th.fired != fired:
+            secs = time.perf_counter() - t0
+            with _lock:
+                _add("train_step_compile_call_s", secs)
+                _add("train_step_compile_calls", 1)
 
 
 def _on_duration(event: str, secs: float, **_) -> None:
-    # fires for a compilation and for a fetch from the persistent cache
-    # alike, and only then: the steady step never reaches this
-    if _enqueue_depth and event == _BACKEND_COMPILE:
-        _counters["train_step_compiles"] += 1
-        _counters["train_step_compile_s"] += secs
+    # JAX's compile pipeline: fires where a program is traced, lowered,
+    # compiled or fetched from the persistent cache (the backend event
+    # holds the fetch), and only then: the steady step never comes here
+    th = _thread
+    if event == _TRACE:
+        # one event for every jax.jit traced, an inner one inside its
+        # caller's: kept until the lowering says which was the program's
+        th.fired += 1
+        th.traces.append((time.time() - secs, secs))
+        return
+    stage = _STAGES.get(event)
+    if stage is None:
+        return
+    th.fired += 1
+    if event == _LOWER:
+        # the outermost trace ends where lowering begins, so the
+        # program's is the last that fired before it: an inner jit's
+        # seconds are inside it and are not summed.  (A trace that began
+        # after the lowering did is one a lowering rule made.)
+        began = time.time() - secs
+        traced = [t for start, t in th.traces if start < began]
+        th.traces.clear()
+        if traced:
+            _staged("trace", traced[-1])
+    _staged(stage, secs)
 
 
+def _on_event(event: str, **_) -> None:
+    name = _CACHE_EVENTS.get(event)
+    if name is not None:
+        _staged(name)
+
+
+# the package's one pair of listeners, for the life of the process
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+jax.monitoring.register_event_listener(_on_event)
+
+
+def compile_totals() -> Dict[str, float]:
+    """The process's compile pipeline over every phase and none, since
+    this module was imported: programs compiled or fetched
+    (``compilations``) and their seconds (``compile_seconds``, the
+    fetches' included), persistent-cache hits and writes."""
+    with _lock:
+        rows = list(_counters.items())
+
+    def total(kind, stage):
+        return sum(v for k, v in rows
+                   if k.startswith(kind) and k.endswith("." + stage))
+
+    return {"compilations": int(total("stage_n.", "backend")),
+            "cache_hits": int(total("stage_n.", "cache_hits")),
+            "cache_writes": int(total("stage_n.", "cache_misses")),
+            "compile_seconds": total("stage_s.", "backend")}
+
+
+_STAT = "/proc/self/stat"
+_first_model_seen = False
+
+
+def process_age_s(stat: str = _STAT) -> Optional[float]:
+    """Seconds since the process of that ``/proc/<pid>/stat`` started:
+    its start time (field 22, clock ticks after boot) against
+    ``CLOCK_BOOTTIME``.  None where either cannot be read."""
+    try:
+        with open(stat) as f:
+            fields = f.read().rpartition(")")[2].split()
+        started = int(fields[19]) / os.sysconf("SC_CLK_TCK")
+        return time.clock_gettime(time.CLOCK_BOOTTIME) - started
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
+def model_made() -> Optional[float]:
+    """Called by ``FFModel.__init__``.  For the process's first model it
+    writes ``before_first_model_s``, the process's age: the interpreter,
+    ``import jax``, the device client, the caller's imports, which no
+    span of the program can cover (absent where the age cannot be read),
+    and returns the clock for ``graph_built``; None for every later one."""
+    global _first_model_seen
+    if _first_model_seen:
+        return None
+    _first_model_seen = True
+    age = process_age_s(_STAT)
+    if age is not None:
+        _counters["before_first_model_s"] = age
+    return time.perf_counter()
+
+
+def graph_built(since: float) -> None:
+    """Called at the entry of the first model's ``compile()``:
+    ``graph_build_s``, the builder's Python since ``model_made``."""
+    _counters["graph_build_s"] = time.perf_counter() - since
+
+
+# ----------------------------------------------------------------------
+# counters
+# ----------------------------------------------------------------------
 def counters() -> Dict[str, float]:
-    """Process-wide counters: ``train_step_compiles``, the XLA
-    compilations (or persistent-cache fetches) that happened inside a
-    train step's call, over every model of the process, and
-    ``train_step_compile_s``, their seconds.  Where a model with routed
+    """Process-wide counters.
+
+    Set-up (docs/observability.md, "The step's own timeline"):
+    ``span_s.<phase>`` / ``span_n.<phase>``, the wall seconds and count
+    of every phase closed; ``stage_s.<phase>.<trace|lower|backend|fetch>``
+    and ``stage_n.<...>``, JAX's compile-pipeline events by the
+    innermost phase open on the thread they fired on (``none`` outside
+    all; ``stage_n`` also counts ``cache_hits`` and ``cache_misses``);
+    of those the step's own under their names: ``train_step_compiles``,
+    the XLA compilations (or persistent-cache fetches) that happened
+    inside a train step's call, over every model of the process,
+    ``train_step_compile_s``, their seconds, ``train_step_trace_s`` and
+    ``train_step_lower_s``; ``train_step_compile_calls`` and
+    ``train_step_compile_call_s``, the step calls in which any of that
+    fired and their wall time; ``before_first_model_s`` and
+    ``graph_build_s``, written once by the process's first model.
+
+    Where a model with routed
     experts has drained its metrics, also the sums its layers counted
     (``ops/moe.py``, ``RoutedExperts.COUNTERS``) and what follows from
     them over all drained steps: ``moe_assignments_made_per_token`` and
@@ -109,7 +316,10 @@ def counters() -> Dict[str, float]:
     ``dsa_index_kl``: the index's objective ``L_I``, the mean over the
     indexed layers and every drained step (the layers summed a term each
     into the step's objective, ``FwdCtx.add_loss``)."""
-    out = dict(_counters)
+    with _lock:
+        out = dict(_counters)
+    for name, source in _TRAIN_STEP.items():
+        out[name] = out.get(source, 0.0)
     if out.get("dsa_layers"):
         out["dsa_index_kl"] /= out["dsa_layers"]
     tokens = out.get("moe_tokens")
@@ -125,23 +335,7 @@ def counters() -> Dict[str, float]:
 def count(sums: Dict[str, float]) -> None:
     """Add what a model's ops counted since its last drain."""
     for name, value in sums.items():
-        _counters[name] = _counters.get(name, 0.0) + value
-
-
-@contextlib.contextmanager
-def step_enqueue(log):
-    """The span ``update.enqueue`` around the call of the jitted train
-    step; a compilation that fires while it is open is the step's."""
-    global _enqueue_depth, _listening
-    if not _listening:
-        _listening = True
-        jax.monitoring.register_event_duration_secs_listener(_on_duration)
-    _enqueue_depth += 1
-    try:
-        with span(log, "update.enqueue"):
-            yield
-    finally:
-        _enqueue_depth -= 1
+        _add(name, value)
 
 
 # ----------------------------------------------------------------------

@@ -41,32 +41,23 @@ def enable_compile_cache() -> str:
 
 class CompileStats:
     """Counts this process's XLA compilations from the moment it is
-    made, through ``jax.monitoring``: how many programs were compiled or
-    fetched (``compilations``), how many of those the persistent cache
-    answered (``cache_hits``), how many it stored (``cache_writes``),
-    and the seconds spent (``compile_seconds``, retrieval included).
+    made: how many programs were compiled or fetched (``compilations``),
+    how many of those the persistent cache answered (``cache_hits``),
+    how many it stored (``cache_writes``), and the seconds spent
+    (``compile_seconds``, retrieval included).  They are differences of
+    the process-wide totals that ``runtime/profiling``'s one
+    ``jax.monitoring`` listener keeps; an instance registers nothing.
     Two ``snapshot()``s bracket a window: a steady-state window's
     ``compilations`` difference is zero."""
 
     def __init__(self):
-        import jax.monitoring
+        self._since = self._totals()
 
-        self._n = {"compilations": 0, "cache_hits": 0, "cache_writes": 0,
-                   "compile_seconds": 0.0}
-        jax.monitoring.register_event_listener(self._on_event)
-        jax.monitoring.register_event_duration_secs_listener(
-            self._on_duration)
+    @staticmethod
+    def _totals() -> dict:
+        from ..runtime.profiling import compile_totals  # imports jax
 
-    def _on_event(self, event, **kw):
-        if event == "/jax/compilation_cache/cache_hits":
-            self._n["cache_hits"] += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self._n["cache_writes"] += 1
-
-    def _on_duration(self, event, duration_secs, **kw):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self._n["compilations"] += 1
-            self._n["compile_seconds"] += duration_secs
+        return compile_totals()
 
     def snapshot(self) -> dict:
-        return dict(self._n)
+        return {k: v - self._since[k] for k, v in self._totals().items()}
