@@ -321,11 +321,11 @@ def _tail_of_the_step(model, classes, steps=3):
     """What the train step spends on the loss, on the metric vector and
     on the final Softmax: instructions under `ff.loss`, `ff.metrics` and
     the Softmax's forward scope in the loaded step programs (the most
-    over the step's signatures), and from a profile of `steps` steps,
-    joined to the scope map as the benchmark joins it: device ms a step
-    under each scope (every phase), and of the instructions under
-    `ff.loss` those whose result is f32 and `classes` wide.  These are
-    None where the trace holds no TPU's operations."""
+    over them, where the process loaded several), and from a profile of
+    `steps` steps, joined to the scope map as the benchmark joins it:
+    device ms a step under each scope (every phase), and of the
+    instructions under `ff.loss` those whose result is f32 and `classes`
+    wide.  These are None where the trace holds no TPU's operations."""
     import glob
     import tempfile
     import types

@@ -1174,12 +1174,13 @@ class FFModel:
         if state is not None:
             place_state(self, state)
         # Device-resident caches keyed on the old mesh: the staged batch
-        # is re-placed by the next set_batch; metric accumulation is
-        # re-hosted (uncommitted) so the new step function may place it.
+        # is re-placed by the next set_batch; what the accumulator holds
+        # moves to the new mesh, placed as the new step function returns it.
         self._batch = None
         if self._metric_acc is not None:
-            self._metric_acc = jnp.asarray(
-                np.asarray(jax.device_get(self._metric_acc)))
+            self._metric_acc = jax.device_put(
+                np.asarray(jax.device_get(self._metric_acc)),
+                self.machine.replicated())
         self._dp_cache = None
         self._he_dev_cache = None
 
@@ -1891,7 +1892,13 @@ class FFModel:
                      for opn, ws in v.items()}
                     if isinstance(v, dict) else v)
                 for k, v in state.items()}
-        return state
+        # A leaf the optimizer made from scratch (optax's step count) is
+        # uncommitted, on one device; the step hands it back committed
+        # to the mesh.  Place it now as the step will.
+        rep = self.machine.replicated()
+        return jax.tree.map(
+            lambda a: jax.device_put(a, rep)
+            if isinstance(a, jax.Array) and not a.committed else a, state)
 
     # ------------------------------------------------------------------
     # forward-graph evaluation (inside jit)
@@ -2272,6 +2279,20 @@ class FFModel:
             keys += list(self._nonfinite_guard.METRIC_KEYS)
         return keys + self._op_counter_keys()
 
+    def _fresh_metric_acc(self, consec_skipped: float = 0.0) -> jax.Array:
+        """An empty metric accumulator, committed and replicated over the
+        model's mesh, which is how the train step hands it back: the
+        first call of a step function so has the signature of every later
+        one, and the step is traced, lowered and compiled once.  The
+        guard's run length survives a reset."""
+        keys = self._metric_keys()
+        acc = jnp.zeros((len(keys),), jnp.float32,
+                        device=self.machine.replicated())
+        if consec_skipped:
+            acc = acc.at[keys.index("consec_skipped")].set(
+                float(consec_skipped))
+        return acc
+
     def _op_counter_keys(self) -> List[str]:
         """The ops' own per-step scalars (``Op.COUNTERS``), which ride the
         metric vector and leave it at the drain for
@@ -2345,13 +2366,10 @@ class FFModel:
         if self._opt_state is None:
             self._opt_state = self._init_opt_state()
         if self._metric_acc is None:
-            self._metric_acc = jnp.zeros((len(self._metric_keys()),), jnp.float32)
             guard = self._nonfinite_guard
-            if guard is not None and guard.consec:
-                # re-seed the run length a reset_metrics discarded
-                ci = self._metric_keys().index("consec_skipped")
-                self._metric_acc = self._metric_acc.at[ci].set(
-                    float(guard.consec))
+            # re-seed the run length a reset_metrics discarded
+            self._metric_acc = self._fresh_metric_acc(
+                guard.consec if guard is not None else 0)
         hp = self.optimizer.hparams()
         # Host-offloaded weights stream on-chip for the step and back
         # after (eager device_put at the jit boundary: the reference's
@@ -2390,7 +2408,7 @@ class FFModel:
         if self._opt_state is None:
             self._opt_state = self._init_opt_state()
         macc = self._metric_acc if self._metric_acc is not None else \
-            jnp.zeros((len(self._metric_keys()),), jnp.float32)
+            self._fresh_metric_acc()
         return fn.lower(
             self._offload_put(self._params, False), self._stats,
             self._offload_put_state(self._opt_state, False),

@@ -75,6 +75,17 @@ class Optimizer:
         """Pin a params-shaped state subtree to the ZeRO-1 shardings so
         the computed state stays sharded between steps (not
         materialized replicated and resharded on re-entry)."""
+        return self._pin_zero_leaves(tree, lambda key: self.zero_specs[key])
+
+    def _constrain_params(self, tree):
+        """Pin the new parameters whose state ZeRO-1 shards to their own
+        specs: the partitioner would hand them back sharded as the state
+        they were computed from, and the next step would then receive
+        them placed otherwise than the first did (a second program)."""
+        return self._pin_zero_leaves(
+            tree, lambda key: self.param_specs[key[0]][key[1]])
+
+    def _pin_zero_leaves(self, tree, spec_of):
         if not self.zero_specs or self.mesh is None:
             return tree
         from jax.sharding import NamedSharding
@@ -85,11 +96,10 @@ class Optimizer:
                 key = tuple(p.key for p in path)
             except AttributeError:
                 return x
-            spec = self.zero_specs.get(key)
-            if spec is None:
+            if key not in self.zero_specs:
                 return x
             return jax.lax.with_sharding_constraint(
-                x, NamedSharding(self.mesh, spec))
+                x, NamedSharding(self.mesh, spec_of(key)))
 
         return tree_map_with_path(f, tree)
 
@@ -189,7 +199,8 @@ class SGDOptimizer(Optimizer):
 
                 out = tree_map_with_path(fupd, params, grads, state["v"])
                 new_params, new_v = _unzip(out, 2)
-                return new_params, {"v": self._constrain_state(new_v)}
+                return (self._constrain_params(new_params),
+                        {"v": self._constrain_state(new_v)})
 
             def fupd_plain(path, w, g):
                 if not self._leaf_fused(path):
@@ -213,7 +224,8 @@ class SGDOptimizer(Optimizer):
 
             out = jax.tree.map(upd, params, grads, state["v"])
             new_params, new_v = _unzip(out, 2)
-            return new_params, {"v": self._constrain_state(new_v)}
+            return (self._constrain_params(new_params),
+                    {"v": self._constrain_state(new_v)})
 
         def upd_plain(w, g):
             return w - lr * (g + wd * w).astype(w.dtype)
@@ -276,8 +288,9 @@ class AdamOptimizer(Optimizer):
             out = tree_map_with_path(fupd, params, grads, state["m"],
                                      state["v"])
             new_params, new_m, new_v = _unzip(out, 3)
-            return new_params, {"m": self._constrain_state(new_m),
-                                "v": self._constrain_state(new_v)}
+            return (self._constrain_params(new_params),
+                    {"m": self._constrain_state(new_m),
+                     "v": self._constrain_state(new_v)})
 
         def upd(w, g, m, v):
             gt = (g + wd * w).astype(jnp.float32)
@@ -287,8 +300,9 @@ class AdamOptimizer(Optimizer):
 
         out = jax.tree.map(upd, params, grads, state["m"], state["v"])
         new_params, new_m, new_v = _unzip(out, 3)
-        return new_params, {"m": self._constrain_state(new_m),
-                            "v": self._constrain_state(new_v)}
+        return (self._constrain_params(new_params),
+                {"m": self._constrain_state(new_m),
+                 "v": self._constrain_state(new_v)})
 
 
 class OptaxOptimizer(Optimizer):
@@ -321,28 +335,9 @@ class OptaxOptimizer(Optimizer):
         self.fused = False
 
     def init_state(self, params):
-        state = self.tx.init(params)
-        if self.mesh is not None:
-            # Param-shaped leaves (zeros_like) inherit the params'
-            # mesh shardings; leaves tx.init creates from scratch (step
-            # counters) land on ONE device and would clash with the
-            # mesh-placed params inside the train step.  Re-place only
-            # those — replicating everything would gather sharded slots.
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            n_dev = self.mesh.devices.size
-            rep = NamedSharding(self.mesh, PartitionSpec())
-
-            def place(x):
-                try:
-                    if len(x.devices()) == n_dev:
-                        return x
-                except AttributeError:
-                    pass
-                return jax.device_put(x, rep)
-
-            state = jax.tree.map(place, state)
-        return {"optax": state}
+        # leaves tx.init makes from scratch (step counters) come back
+        # uncommitted, on one device: whoever holds the state places them
+        return {"optax": self.tx.init(params)}
 
     def hparams(self):
         return {}

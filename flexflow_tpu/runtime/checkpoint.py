@@ -365,6 +365,10 @@ def place_state(model, state: Dict[str, Any]) -> None:
         return placed
 
     state["params"] = place_params_like(state["params"])
+    if state.get("stats"):
+        # replicated, as init_layers places them and the step returns them
+        state["stats"] = jax.device_put(state["stats"],
+                                        model.machine.replicated())
     if "opt_state" in state and isinstance(state["opt_state"], dict):
         # optimizer slots re-take their param's sharding — or the ZeRO-1
         # layout when the optimizer carries zero_specs; non-dict slots
@@ -375,23 +379,24 @@ def place_state(model, state: Dict[str, Any]) -> None:
 
         def place_other(v, key):
             # non-dict (optax NamedTuple) slots: take each leaf's
-            # sharding from a freshly-initialized state TEMPLATE so
-            # param-shaped moments come back sharded like their params
-            # (blanket replication would gather model-parallel slots)
-            if model.machine is None or model.machine.num_devices <= 1:
-                return v
+            # sharding from a state TEMPLATE freshly initialized over the
+            # placed parameters, so param-shaped moments come back
+            # sharded like their params (blanket replication would
+            # gather model-parallel slots); a leaf made from scratch (a
+            # step count) is replicated.  Every leaf ends committed to
+            # the mesh, as the step hands it back.
+            rep = model.machine.replicated()
             if model.optimizer is not None:
                 try:
-                    tmpl = model.optimizer.init_state(model._params).get(key)
+                    tmpl = model.optimizer.init_state(
+                        state["params"]).get(key)
                     return jax.tree.map(
-                        lambda a, t: (jax.device_put(a, t.sharding)
-                                      if hasattr(t, "sharding") else a),
+                        lambda a, t: jax.device_put(
+                            a, t.sharding if getattr(t, "committed", False)
+                            else rep),
                         v, tmpl)
                 except Exception:
                     pass  # structure mismatch — replicate below
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            rep = NamedSharding(model.machine.mesh, PartitionSpec())
             return jax.tree.map(lambda a: jax.device_put(a, rep), v)
 
         state["opt_state"] = {

@@ -501,8 +501,10 @@ def step_scopes() -> Dict[str, List[Dict[str, Dict[str, object]]]]:
                                            ["span": "ff.moe.route",]
                                            "mixed": bool}}, ...]}
 
-    one entry of the list for each loaded program of that name (the
-    train step is loaded once per signature)."""
+    one entry of the list for each loaded program of that name: the
+    train step is one program a step function (every argument of its
+    first call is placed as the step returns it), so several only where
+    a ``recompile`` or a second model built another."""
     out: Dict[str, List[Dict[str, Dict[str, object]]]] = {}
     for name, _, scopes in step_programs():
         out.setdefault(name, []).append(scopes)

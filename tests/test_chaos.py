@@ -42,8 +42,10 @@ def _clean_env(monkeypatch):
     events.reset_active()
 
 
-def _build(n_samples=48, seed=9):
+def _build(n_samples=48, seed=9, n_devices=None):
     cfg = ff.FFConfig(batch_size=16)
+    if n_devices is not None:
+        cfg.parse_args(["-ll:tpu", str(n_devices)])
     m = ff.FFModel(cfg)
     inp = m.create_tensor((16, 8), nchw=False, name="input")
     t = m.dense(inp, 16, activation="relu", name="fc1")
@@ -177,6 +179,30 @@ def test_consec_run_survives_metric_reset(monkeypatch, devices):
             m.train_iteration()
             m.get_metrics()
             m.reset_metrics()  # an epoch boundary between every step
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_a_run_carried_over_a_drain_or_a_reset_keeps_the_steps_program(
+        monkeypatch, devices, n_devices):
+    # the run length written into a fresh accumulator leaves it placed
+    # as the step hands it back: no second program for the step
+    monkeypatch.setenv("FF_CHAOS",
+                       "step:1=nan_loss;step:2=nan_loss;step:4=nan_loss")
+    monkeypatch.setenv("FF_SKIP_NONFINITE", "5")
+    m, dl = _build(n_devices=n_devices)
+    carried = []
+    for i in range(7):
+        dl.next_batch(m)
+        m.train_iteration()
+        assert m._train_step_fn._cache_size() == 1
+        m.get_metrics()  # a drain after every step
+        carried.append(m._nonfinite_guard.consec)
+        acc = m._metric_acc
+        assert acc.committed and acc.sharding == m.machine.replicated()
+        if carried[-1]:
+            m.reset_metrics()  # and a reset while a run is open
+    assert m._nonfinite_guard.total_skipped == 3
+    assert max(carried) == 2
 
 
 # ---------------------------------------------------------------------------

@@ -85,6 +85,36 @@ def test_npz_roundtrip(devices, tmp_path):
     np.testing.assert_allclose(m.get_parameter("fc2"), w)
 
 
+@pytest.mark.parametrize("form", ["npz", "orbax"])
+def test_a_restore_leaves_the_step_one_program(devices, tmp_path, form):
+    """What a restore puts back (parameters, batch-norm statistics, the
+    optimizer's state) is placed as the step hands it back, so the step
+    after it runs the program the steps before it ran."""
+    cfg = ff.FFConfig(batch_size=8, compute_dtype="float32")
+    cfg.parse_args(["-ll:tpu", "1"])
+    m = ff.FFModel(cfg)
+    inp = m.create_tensor((8, 3, 8, 8))
+    t = m.batch_norm(m.conv2d(inp, 4, 3, 3, 1, 1, 1, 1, name="c0"), name="bn")
+    m.softmax(m.dense(m.flat(t, name="flat"), 4, name="fc"), name="sm")
+    m.compile(ff.SGDOptimizer(lr=0.1, momentum=0.9),
+              ff.LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
+              [ff.MetricsType.ACCURACY])
+    m.init_layers(seed=3)
+    rng = np.random.default_rng(0)
+    m.set_batch({inp: rng.standard_normal((8, 8, 8, 3), dtype=np.float32)},
+                rng.integers(0, 4, size=(8, 1), dtype=np.int32))
+    assert m._stats  # the model has statistics to restore
+    for _ in range(2):
+        m.train_iteration()
+    path = str(tmp_path / ("ck.npz" if form == "npz" else "ck"))
+    m.save(path)
+    m.load(path)
+    for _ in range(2):
+        m.train_iteration()
+        assert m._train_step_fn._cache_size() == 1
+    m.sync()
+
+
 def test_checkpoint_manager_rotation(devices, tmp_path):
     from flexflow_tpu.runtime.checkpoint import CheckpointManager
 

@@ -249,17 +249,22 @@ def test_the_reported_loss_is_the_objective(devices):
 
 
 # The steps of the models that take no loss term and none of the new
-# options lower to the text they lowered to at the parent commit (PR 34):
+# options lower to the text they lowered to at the parent commit:
 # sha256 of `train_step_hlo()` at the sizes below, computed there with
 # this function.  A later PR that means to change one of these programs
-# computes the digest anew and says so.
+# computes the digest anew and says so.  PR 38 did: the metric
+# accumulator of the first call is committed to the mesh, so these are
+# the digests its parent's tree gave after its first step (its second
+# signature, the one every steady step ran), where before they were those
+# of the signature that only the first step ran.  PERF.md section 6,
+# PR 38, has the command that computed them there and what it printed.
 STEP_DIGESTS = {
     "deepseek-v2/float32":
-    "6a68da2daab3f0a2769f00179b3f8bc7e325c792971f2cf65d32851df1b10565",
+    "fbe75e0af80c273c6e229a4821b2e9b83e11254e85a06d305e981f658dc9c805",
     "deepseek-v2/bfloat16":
-    "9c47dc417c4c334f2a56746839fd4e942b72afbd84f0721dd21070483b14045d",
+    "02c6cfdf8a4e81269e34c7bfc7d246dd737950764fb3a7612f11bf3cce02c030",
     "gpt2/bfloat16":
-    "ee023f14ea54499bd5ebbdbaf31769df1d30dff19502f63a4cea27b785ad691e"}
+    "53ef7a97cb3ddb0f6c1e5141bec7bc3dc10e89e11d5cd3ee40218cec8c843628"}
 
 
 DSV2_SMALL = dict(

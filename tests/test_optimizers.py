@@ -4,8 +4,10 @@ Reference: sgd_update (optimizer_kernel.cu:23-40), adam_update (:206-225)
 and the alpha_t schedule (optimizer.cc AdamOptimizer::next_epoch).
 """
 
-import numpy as np
+import jax
 import jax.numpy as jnp
+import numpy as np
+import pytest
 
 import flexflow_tpu as ff
 from flexflow_tpu.optimizers import AdamOptimizer, SGDOptimizer
@@ -145,6 +147,34 @@ def test_optax_adamw_trains_and_checkpoints(devices, tmp_path):
     m3.set_batch({inp3: x}, y)
     m3.train_iteration()
     m3.sync()
+
+
+@pytest.mark.parametrize("n_devices", [1, 8])
+def test_optax_state_is_placed_as_the_step_returns_it(devices, n_devices):
+    """A leaf optax makes from scratch (the step count) is committed to
+    the mesh before the first step, on one device too: the step is one
+    program through a drain and a reset."""
+    import optax
+
+    cfg = ff.FFConfig(batch_size=16)
+    cfg.parse_args(["-ll:tpu", str(n_devices)])
+    m = ff.FFModel(cfg)
+    inp = m.create_tensor((16, 8), nchw=False)
+    m.softmax(m.dense(inp, 4, name="fc"), name="sm")
+    m.compile(ff.OptaxOptimizer(optax.adamw(1e-2)),
+              "sparse_categorical_crossentropy", ["accuracy"])
+    m.init_layers(seed=4)
+    leaves = jax.tree.leaves(m._opt_state)
+    assert all(a.committed and len(a.devices()) == n_devices for a in leaves)
+    m.set_batch({inp: np.ones((16, 8), np.float32)},
+                np.zeros((16, 1), np.int32))
+    for i in range(4):
+        m.train_iteration()
+        assert m._train_step_fn._cache_size() == 1
+        if i == 1:
+            m.get_metrics()
+        if i == 2:
+            m.reset_metrics()
 
 
 def test_optax_pipelined_checkpoint_portability(devices, tmp_path):

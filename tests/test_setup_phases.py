@@ -4,6 +4,7 @@ fired in, the step's call as a phase on condition, what lies before the
 first model, and the one pair of `jax.monitoring` listeners that counts
 it all.  Nothing here is a speed."""
 
+import hashlib
 import json
 import subprocess
 import sys
@@ -17,6 +18,7 @@ from jax._src import monitoring
 
 import flexflow_tpu as ff
 from flexflow_tpu.observability import events
+from flexflow_tpu.parallel.mesh import Machine
 from flexflow_tpu.runtime import profiling
 from flexflow_tpu.utils import compile_cache
 
@@ -34,9 +36,9 @@ def _no_telemetry(monkeypatch):
     events.reset_active()
 
 
-def _mlp(batch=8, width=8, telemetry=False):
+def _mlp(batch=8, width=8, telemetry=False, n_devices=1):
     cfg = ff.FFConfig(batch_size=batch, compute_dtype="float32")
-    cfg.parse_args(["-ll:tpu", "1"])
+    cfg.parse_args(["-ll:tpu", str(n_devices)])
     cfg.telemetry = telemetry
     m = ff.FFModel(cfg)
     t = m.dense(m.create_tensor((batch, width), nchw=False), 4, name="fc")
@@ -49,6 +51,10 @@ def _mlp(batch=8, width=8, telemetry=False):
 
 def _staged(m, batch=8, width=8):
     m.init_layers(seed=0)
+    return _with_batch(m, batch, width)
+
+
+def _with_batch(m, batch=8, width=8):
     rng = np.random.default_rng(0)
     m.set_batch({m.input_tensors[0]:
                  rng.standard_normal((batch, width), np.float32)},
@@ -267,20 +273,20 @@ def test_the_steps_calls_hold_their_trace_lowering_and_backend(devices):
     for i in range(4):
         m.train_iteration()
         if i == 1:
-            m.get_metrics()  # a fresh accumulator: the second signature
+            m.get_metrics()  # a fresh accumulator, placed as the step's
     m.sync()
     after = profiling.counters()
     got = _delta(after, before)
-    # both signatures, each one call that traced, lowered and compiled
-    assert got["train_step_compiles"] == 2
-    assert got["train_step_compile_calls"] == 2
-    assert got["stage_n.update.enqueue.trace"] == 2
-    assert got["stage_n.update.enqueue.lower"] == 2
+    # one call that traced, lowered and compiled: the step has one program
+    assert got["train_step_compiles"] == 1
+    assert got["train_step_compile_calls"] == 1
+    assert got["stage_n.update.enqueue.trace"] == 1
+    assert got["stage_n.update.enqueue.lower"] == 1
     parts = [got["train_step_trace_s"], got["train_step_lower_s"],
              got["train_step_compile_s"]]
     assert all(p > 0 for p in parts)
     assert sum(parts) <= got["train_step_compile_call_s"]
-    # the first of them inside the build's phase
+    # inside the build's phase
     assert got["span_n.step_build"] == 1
     # the names the benchmark reads are the stages under update.enqueue
     assert after["train_step_trace_s"] == after["stage_s.update.enqueue.trace"]
@@ -293,6 +299,51 @@ def test_the_steps_calls_hold_their_trace_lowering_and_backend(devices):
     m.get_metrics()
     m.train_iteration()
     assert profiling.counters() == after
+
+
+def _digest(text):
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_a_step_function_has_one_signature_for_its_life(devices, n_devices):
+    """Every array the first call receives is placed as the step hands
+    it back, so no later call (after a drain, a `reset_metrics()`, a
+    `recompile`) meets the jit's cache with another signature."""
+    m = _staged(_mlp(width=24, n_devices=n_devices), width=24)
+    before = profiling.counters()
+
+    def step(compiles):
+        m.train_iteration()
+        assert m._train_step_fn._cache_size() == 1
+        got = _delta(profiling.counters(), before)
+        assert got["train_step_compiles"] == compiles
+        assert got["stage_n.update.enqueue.trace"] == compiles
+        assert got["stage_n.update.enqueue.lower"] == compiles
+
+    for _ in range(3):  # the first step and two steady ones
+        step(1)
+    acc = m._metric_acc
+    assert acc.committed and acc.sharding == m.machine.replicated()
+    assert m.get_metrics().train_all == 3 * 8
+    step(1)  # after a drain
+    m.reset_metrics()
+    assert m._metric_acc is None
+    lowered = _digest(m.train_step_hlo())  # with an accumulator of its own
+    step(1)  # after a reset
+    # what train_step_hlo() lowers is what the step runs
+    assert _digest(m._train_step_fn.lower(*m._step_args()[0]).as_text()) \
+        == lowered
+    # a new step function, here on another mesh where there is one to
+    # take: one more program, and the accumulator goes over as it stands
+    machine = Machine(devices=devices[:2]) if n_devices > 1 else None
+    m.recompile(machine=machine)
+    acc = m._metric_acc
+    assert acc.committed and acc.sharding == m.machine.replicated()
+    _with_batch(m, width=24)
+    step(2)
+    step(2)
+    assert m.get_metrics().train_all == 3 * 8  # one before it, two after
 
 
 # ---------------------------------------------------------------------------

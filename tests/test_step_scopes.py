@@ -503,10 +503,10 @@ def test_train_step_compiles_counts_compilations_not_steps(devices):
         for i in range(10):
             m.train_iteration()
             if i % 3 == 2:
-                m.get_metrics()  # a fresh accumulator: the second signature
+                m.get_metrics()  # a fresh accumulator, placed as the step's
         after = profiling.counters()
         # the small programs update() dispatches beside the step (the
-        # step's index, the accumulator's zeros) are not the step's
+        # step's index, the hyper-parameters) are not the step's
         assert after["train_step_compiles"] \
             - before["train_step_compiles"] == len(fired) >= 1
         assert after["train_step_compile_s"] \

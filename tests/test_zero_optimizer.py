@@ -70,6 +70,19 @@ def test_zero_state_stays_sharded_across_steps(devices):
     assert arr.sharding.spec and arr.sharding.spec[0] is not None
 
 
+@pytest.mark.parametrize("opt", ["adam", "sgd"])
+def test_zero_params_stay_replicated_and_the_step_is_one_program(devices,
+                                                                 opt):
+    """The new parameters are pinned to their own specs: left to the
+    partitioner they came back sharded as the state they were computed
+    from, and the second step was traced and compiled anew for them."""
+    m = _train(True, steps=3, opt=opt)
+    for name in ("fc1", "fc2"):
+        for arr in m._params[name].values():
+            assert all(e is None for e in arr.sharding.spec), arr.sharding
+    assert m._train_step_fn._cache_size() == 1
+
+
 def test_zero_state_checkpoint_roundtrip(tmp_path, devices):
     """Sharded optimizer state survives save/load: values match AND the
     loaded state carries the ZeRO layout again (not silently
