@@ -57,6 +57,19 @@ key in a block leaves rows that the next block with one wipes
 carry their own kernel names (``flash_win_*``, ``flash_sel_*``), so that
 a trace tells the three cores apart.
 
+Groups and a sink.  k and v may hold fewer heads than q (grouped-query
+attention): query head ``j`` reads key/value head ``j // group``, through
+the index maps alone, so no kernel is given a copy of k or v per query
+head; ``dkv`` writes each query head's share in f32 and the shares of a
+group are summed outside it.  A sink is a learned logit ``b_h`` a query
+head that takes mass in every row's softmax and adds no value: the
+forward starts a row's running max at ``b_h`` and its sum at 1, so
+``lse`` holds it, and the backward kernels, which read ``lse``, need
+nothing more; its gradient, ``-sum_t exp(b_h - lse_t) delta_t``, is a
+reduction outside them.  A call with a sink carries ``_sink`` in its
+forward kernel's name (``flash_win_sink_fwd``); its backward kernels are
+the plain ones.
+
 Blocks are a function of (seq_q, seq_k, head_dim) alone
 (``_block_sizes``, chosen by a sweep on the v5e), the bands of the
 kernel; ``tiling()`` says what each kernel does with a shape.
@@ -86,11 +99,14 @@ WINDOW_KERNELS = tuple(k.replace("flash_", "flash_win_") for k in KERNELS)
 SELECT_KERNELS = tuple(k.replace("flash_", "flash_sel_") for k in KERNELS)
 
 
-def kernel_names(window=None, selected=False):
-    """The names of the forward, dq and dkv kernels of a call."""
-    if selected:
-        return SELECT_KERNELS
-    return WINDOW_KERNELS if window is not None else KERNELS
+def kernel_names(window=None, selected=False, sunk=False):
+    """The names of the forward, dq and dkv kernels of a call; a sink
+    changes the forward's body alone, and its name."""
+    names = SELECT_KERNELS if selected else (
+        WINDOW_KERNELS if window is not None else KERNELS)
+    if sunk:      # flash_win_fwd -> flash_win_sink_fwd
+        names = ("_sink_".join(names[0].rsplit("_", 1)),) + names[1:]
+    return names
 # A sequence of up to this many rows can be one block whatever its
 # length; a longer one is cut into divisors that are multiples of 8.
 _WHOLE = 512
@@ -190,7 +206,8 @@ def tiling(seq_q: int, seq_k: int, head_dim: int, causal: bool,
     (the rest lie wholly above the causal diagonal, or wholly below the
     window's band, and fetch nothing), and the bands a block on the
     diagonal is cut into (``_tiles``; one under a window or a
-    selection, whose blocks are masked whole)."""
+    selection, whose blocks are masked whole).  A group or a sink moves
+    none of it (a sink's kernels are named as ``kernel_names`` says)."""
     bq, bk = _block_sizes(seq_q, seq_k, head_dim, block_q, block_k)
     nq, nk = seq_q // bq, seq_k // bk
     body = sum(1 for qi in range(nq) for ki in range(nk)
@@ -330,9 +347,13 @@ def _q_of(causal: bool, block_q: int, block_k: int, nq: int, window=None):
     return q_of
 
 
-def _kv_map(causal: bool, block_q: int, block_k: int, window=None):
-    """Index map of a k or v block on a (batch*heads, q, k) grid."""
+def _kv_map(causal: bool, block_q: int, block_k: int, window=None,
+            group: int = 1):
+    """Index map of a k or v block on a (batch*heads, q, k) grid: query
+    head row ``bh`` reads key/value row ``bh // group``."""
     k_of = _k_of(causal, block_q, block_k, window)
+    if group > 1:
+        return lambda bh_, qi, ki: (bh_ // group, k_of(qi, ki), 0)
     return lambda bh_, qi, ki: (bh_, k_of(qi, ki), 0)
 
 
@@ -353,19 +374,18 @@ _SEMANTICS = pltpu.CompilerParams(
 # 1 s, PERF.md section 6, PR 26).  The scopes are the call sites', so
 # that a cached trace holds no name.
 _STATIC = dict(static_argnames=("scale", "causal", "bq", "bk", "interpret",
-                                "window"))
+                                "window", "group"))
 
 # ---------------------------------------------------------------------------
 # Forward kernel
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(*refs, scale, causal, block_q, block_k, window=None,
-                selected=False):
-    if selected:
-        (q_ref, k_ref, v_ref, sel_ref, o_ref, lse_ref,
-         acc_sc, m_sc, l_sc) = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc = refs
+                selected=False, sunk=False):
+    q_ref, k_ref, v_ref, *refs = refs
+    sel_ref = refs.pop(0) if selected else None
+    sink_ref = refs.pop(0) if sunk else None
+    o_ref, lse_ref, acc_sc, m_sc, l_sc = refs
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -373,8 +393,12 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, window=None,
     @pl.when(ki == 0)
     def _init():
         acc_sc[:] = jnp.zeros_like(acc_sc)
-        m_sc[:] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
+        if sunk:      # the sink's exp(b - max) is 1 at a max of b
+            m_sc[:] = sink_ref[...]
+            l_sc[:] = jnp.ones_like(l_sc)
+        else:
+            m_sc[:] = jnp.full_like(m_sc, NEG_INF)
+            l_sc[:] = jnp.zeros_like(l_sc)
 
     def _body(masked):
         for ks, qs, mask_at in _tiles("flash_fwd", masked, qi, ki,
@@ -419,21 +443,25 @@ def _sel_spec(sel, bh: int, bq: int, bk: int, q_of, k_of):
 
 @functools.partial(jax.jit, **_STATIC)
 def _fwd_call(qr, kr, vr, scale, causal, bq, bk, interpret, window=None,
-              sel=None):
-    """o (bh, sq, dv) and lse (bh, sq) of q, k (bh, s, d) and v (bh, sk,
-    dv) operands."""
+              sel=None, group=1, sink=None):
+    """o (bh, sq, dv) and lse (bh, sq) of q (bh, sq, d), k (bh / group,
+    sk, d) and v (bh / group, sk, dv) operands; ``sink`` (bh, 1, bq), a
+    row's sink logit along the lanes."""
     bh, sq, d = qr.shape
     sk, dv = vr.shape[1:]
     k_of = _k_of(causal, bq, bk, window)
     cols = lambda width: pl.BlockSpec((1, bk, width),
-                                      _kv_map(causal, bq, bk, window))
+                                      _kv_map(causal, bq, bk, window, group))
     extra = [] if sel is None else [_sel_spec(
         sel, bh, bq, bk, lambda qi, ki: qi, k_of)]
+    if sink is not None:
+        extra.append(pl.BlockSpec((None, 1, bq),
+                                  lambda bh_, qi, ki: (bh_, 0, 0)))
 
     call = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, window=window,
-                          selected=sel is not None),
+                          selected=sel is not None, sunk=sink is not None),
         grid=(bh, sq // bq, sk // bk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh_, qi, ki: (bh_, qi, 0)),
@@ -454,31 +482,42 @@ def _fwd_call(qr, kr, vr, scale, causal, bq, bk, interpret, window=None,
             pltpu.VMEM((1, bq), jnp.float32),
         ],
         interpret=interpret,
-        name=kernel_names(window, sel is not None)[0],
+        name=kernel_names(window, sel is not None, sink is not None)[0],
         compiler_params=_SEMANTICS,
     )
-    out, lse = call(qr, kr, vr, *([] if sel is None else [sel]))
+    out, lse = call(qr, kr, vr, *[x for x in (sel, sink) if x is not None])
     return out, lse.reshape(bh, sq)
 
 
-def _variant(window, sel):
+def _variant(window, sel, group=1, sink=None):
     """What a jitted call takes beside the plain causal call's arguments:
-    nothing for that call, so that its trace is the one it was."""
-    return {} if window is None and sel is None else dict(window=window,
+    nothing for that call, so that its trace is the one it was; a group
+    or a sink only where there is one."""
+    more = {} if window is None and sel is None else dict(window=window,
                                                           sel=sel)
+    if group > 1:
+        more["group"] = group
+    if sink is not None:
+        more["sink"] = sink
+    return more
 
 
-def _flash_forward(q, k, v, sel, scale, causal, block_q, block_k, interpret,
-                   window):
+def _flash_forward(q, k, v, sel, sink, scale, causal, block_q, block_k,
+                   interpret, window):
     b, h, sq, d = q.shape
+    hkv = k.shape[1]
     sk, dv = v.shape[2:]
     bq, bk = _block_sizes(sq, sk, d, block_q, block_k)
-    more = _variant(window, sel)
-    with jax.named_scope(
-            "ff.kernel." + kernel_names(window, sel is not None)[0]):
-        out, lse = _fwd_call(q.reshape(b * h, sq, d), k.reshape(b * h, sk, d),
-                             v.reshape(b * h, sk, dv), scale, causal, bq, bk,
-                             interpret, **more)
+    if sink is not None:
+        sink = jnp.broadcast_to(sink[None, :, None, None],
+                                (b, h, 1, bq)).reshape(b * h, 1, bq)
+    more = _variant(window, sel, h // hkv, sink)
+    with jax.named_scope("ff.kernel." + kernel_names(
+            window, sel is not None, sink is not None)[0]):
+        out, lse = _fwd_call(q.reshape(b * h, sq, d),
+                             k.reshape(b * hkv, sk, d),
+                             v.reshape(b * hkv, sk, dv), scale, causal, bq,
+                             bk, interpret, **more)
     return out.reshape(b, h, sq, dv), lse.reshape(b, h, sq)
 
 
@@ -564,9 +603,11 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, window=None,
 
 @functools.partial(jax.jit, **_STATIC)
 def _dkv_call(qr, kr, vr, dor, lse, delta, scale, causal, bq, bk, interpret,
-              window=None, sel=None):
-    """dk (bh, sk, d) and dv (bh, sk, dv); ``lse`` and ``delta`` are
-    (bh, sq)."""
+              window=None, sel=None, group=1):
+    """dk (bh, sk, d) and dv (bh, sk, dv), each query head's share (in
+    f32 where a group of them shares a key/value row, so that the caller
+    sums them before rounding); k and v are (bh / group, sk, .); ``lse``
+    and ``delta`` are (bh, sq)."""
     bh, sq, d = qr.shape
     sk, dv = vr.shape[1:]
     nq = sq // bq
@@ -579,6 +620,12 @@ def _dkv_call(qr, kr, vr, dor, lse, delta, scale, causal, bq, bk, interpret,
     def cols(width):
         return pl.BlockSpec((1, bk, width), lambda bh_, ki, qi: (bh_, ki, 0))
 
+    def kv_cols(width):
+        if group == 1:
+            return cols(width)
+        return pl.BlockSpec((1, bk, width),
+                            lambda bh_, ki, qi: (bh_ // group, ki, 0))
+
     stat = pl.BlockSpec((None, None, 1, bq),
                         lambda bh_, ki, qi: (bh_, q_of(ki, qi), 0, 0))
     extra = [] if sel is None else [_sel_spec(
@@ -588,11 +635,14 @@ def _dkv_call(qr, kr, vr, dor, lse, delta, scale, causal, bq, bk, interpret,
                           block_q=bq, block_k=bk, window=window,
                           selected=sel is not None),
         grid=(bh, sk // bk, nq),
-        in_specs=[rows(d), cols(d), cols(dv), rows(dv), stat, stat] + extra,
+        in_specs=[rows(d), kv_cols(d), kv_cols(dv), rows(dv), stat,
+                  stat] + extra,
         out_specs=[cols(d), cols(dv)],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), kr.dtype),
-            jax.ShapeDtypeStruct((bh, sk, dv), vr.dtype),
+            jax.ShapeDtypeStruct((bh, sk, d),
+                                 kr.dtype if group == 1 else jnp.float32),
+            jax.ShapeDtypeStruct((bh, sk, dv),
+                                 vr.dtype if group == 1 else jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
@@ -608,8 +658,9 @@ def _dkv_call(qr, kr, vr, dor, lse, delta, scale, causal, bq, bk, interpret,
 
 @functools.partial(jax.jit, **_STATIC)
 def _dq_call(qr, kr, vr, dor, lse, delta, scale, causal, bq, bk, interpret,
-             window=None, sel=None):
-    """dq (bh, sq, d); ``lse`` and ``delta`` are (bh, sq)."""
+             window=None, sel=None, group=1):
+    """dq (bh, sq, d); k and v are (bh / group, sk, .); ``lse`` and
+    ``delta`` are (bh, sq)."""
     bh, sq, d = qr.shape
     sk, dv = vr.shape[1:]
     k_of = _k_of(causal, bq, bk, window)
@@ -618,7 +669,8 @@ def _dq_call(qr, kr, vr, dor, lse, delta, scale, causal, bq, bk, interpret,
         return pl.BlockSpec((1, bq, width), lambda bh_, qi, ki: (bh_, qi, 0))
 
     def cols(width):
-        return pl.BlockSpec((1, bk, width), _kv_map(causal, bq, bk, window))
+        return pl.BlockSpec((1, bk, width),
+                            _kv_map(causal, bq, bk, window, group))
 
     stat = pl.BlockSpec((None, None, 1, bq),
                         lambda bh_, qi, ki: (bh_, qi, 0, 0))
@@ -643,43 +695,53 @@ def _dq_call(qr, kr, vr, dor, lse, delta, scale, causal, bq, bk, interpret,
 
 def _flash_backward(scale, causal, block_q, block_k, interpret, window, res,
                     grads):
-    q, k, v, sel, out, lse = res
+    q, k, v, sel, sink, out, lse = res
     do, _ = grads
     b, h, sq, d = q.shape
+    hkv = k.shape[1]
     sk, dv = v.shape[2:]
     bq, bk = _block_sizes(sq, sk, d, block_q, block_k)
     bh = b * h
 
     delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
-    args = (q.reshape(bh, sq, d), k.reshape(bh, sk, d), v.reshape(bh, sk, dv),
-            do.reshape(bh, sq, dv), lse.reshape(bh, sq), delta.reshape(bh, sq),
+    args = (q.reshape(bh, sq, d), k.reshape(b * hkv, sk, d),
+            v.reshape(b * hkv, sk, dv), do.reshape(bh, sq, dv),
+            lse.reshape(bh, sq), delta.reshape(bh, sq),
             scale, causal, bq, bk, interpret)
-    more = _variant(window, sel)
+    more = _variant(window, sel, h // hkv)
     _, dq_name, dkv_name = kernel_names(window, sel is not None)
     with jax.named_scope("ff.kernel." + dkv_name):
         dk, dv_ = _dkv_call(*args, **more)
     with jax.named_scope("ff.kernel." + dq_name):
         dq = _dq_call(*args, **more)
+    if h > hkv:       # each query head's share, summed over its group
+        dk, dv_ = (x.reshape(b, hkv, h // hkv, sk, -1).sum(2).astype(y.dtype)
+                   for x, y in ((dk, k), (dv_, v)))
+    dsink = None
+    if sink is not None:
+        dsink = -jnp.sum(jnp.exp(sink[None, :, None] - lse) * delta,
+                         axis=(0, 2))
     return (dq.reshape(b, h, sq, d),
-            dk.reshape(b, h, sk, d),
-            dv_.reshape(b, h, sk, dv), None)
+            dk.reshape(b, hkv, sk, d),
+            dv_.reshape(b, hkv, sk, dv), None, dsink)
 
 
 # ---------------------------------------------------------------------------
 # Public API
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _flash(q, k, v, sel, scale, causal, block_q, block_k, interpret, window):
-    return _flash_forward(q, k, v, sel, scale, causal, block_q, block_k,
-                          interpret, window)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _flash(q, k, v, sel, sink, scale, causal, block_q, block_k, interpret,
+           window):
+    return _flash_forward(q, k, v, sel, sink, scale, causal, block_q,
+                          block_k, interpret, window)
 
 
-def _flash_fwd(q, k, v, sel, scale, causal, block_q, block_k, interpret,
-               window):
-    out, lse = _flash_forward(q, k, v, sel, scale, causal, block_q, block_k,
-                              interpret, window)
-    return (out, lse), (q, k, v, sel, out, lse)
+def _flash_fwd(q, k, v, sel, sink, scale, causal, block_q, block_k,
+               interpret, window):
+    out, lse = _flash_forward(q, k, v, sel, sink, scale, causal, block_q,
+                              block_k, interpret, window)
+    return (out, lse), (q, k, v, sel, sink, out, lse)
 
 
 _flash.defvjp(_flash_fwd, _flash_backward)
@@ -692,11 +754,16 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     return_lse: bool = False,
                     interpret: bool = False,
                     window: Optional[int] = None,
-                    select=None):
+                    select=None, sink=None):
     """Fused attention: softmax(q k^T * scale [+ causal mask]) v.
 
-    q and k are (B, H, S, D); v is (B, H, S, Dv) and the output has its
-    width, which need not be D (latent attention's 192 | 128).  ``scale``
+    q is (B, H, S, D); k is (B, Hkv, S, D) and v (B, Hkv, S, Dv), where
+    Hkv divides H and query head ``j`` reads key/value head ``j // (H /
+    Hkv)`` (grouped-query attention; Hkv = H is plain multi-head); the
+    output has v's width, which need not be D (192 | 128).  ``sink``
+    (H,), a learned logit a query head, takes mass in each row's softmax
+    and adds no value: ``p_tj = exp(s_tj) / (exp(sink) + sum_j'
+    exp(s_tj'))``; it takes a gradient, and ``lse`` then holds it.  ``scale``
     defaults to 1/sqrt(D).  Returns the output, plus the per-row
     logsumexp (B, H, S) when ``return_lse`` — ring attention uses the
     lse to merge shard-local partials.  Raises ValueError for a sequence
@@ -718,7 +785,12 @@ def flash_attention(q, k, v, *, causal: bool = False,
                          "causal self-attention")
     if window is not None and select is not None:
         raise ValueError("flash_attention: a window or a selection, not both")
-    out, lse = _flash(q, k, v, select, scale, causal, block_q, block_k,
+    if k.shape[1] != v.shape[1] or q.shape[1] % k.shape[1]:
+        raise ValueError(f"flash_attention: {q.shape[1]} query heads over "
+                         f"{k.shape[1]} key and {v.shape[1]} value heads")
+    if sink is not None:
+        sink = sink.astype(jnp.float32)
+    out, lse = _flash(q, k, v, select, sink, scale, causal, block_q, block_k,
                       interpret, window)
     if return_lse:
         return out, lse

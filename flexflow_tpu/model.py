@@ -314,6 +314,18 @@ class FFModel:
         return self._append(LatentAttention(self, input_tensor, num_heads,
                                             name=name, **sizes))
 
+    def grouped_query_attention(self, input_tensor: Tensor, num_heads: int,
+                                num_kv_heads: int,
+                                name: Optional[str] = None, **sizes) -> Tensor:
+        """Causal grouped-query attention (B,S,E)->(B,S,E) over the
+        ``num_heads`` query and ``num_kv_heads`` key/value heads held here;
+        ``sizes`` are ``GroupedQueryAttention``'s (the two head dims, the
+        rotary dims and theta, a window, a sink, the value scale)."""
+        from .ops.attention import GroupedQueryAttention
+
+        return self._append(GroupedQueryAttention(
+            self, input_tensor, num_heads, num_kv_heads, name=name, **sizes))
+
     def routed_experts(self, input_tensor: Tensor, n_routed_experts: int,
                        num_experts_per_tok: int, expert_width: int,
                        name: Optional[str] = None, **kw) -> Tensor:

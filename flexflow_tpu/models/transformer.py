@@ -235,6 +235,103 @@ def build_dots3(ff: FFModel, batch_size: int, seq_length: int = 8192,
         learned_positions=False)
 
 
+def mimo_v2_layer_pattern(num_hidden_layers: int = 48):
+    """MiMo-V2-Flash's published ``hybrid_layer_pattern`` (0 a full layer,
+    1 a window layer) over ``num_hidden_layers``: layer 0 and every layer
+    ``i`` with ``i % 6 == 5`` are full, the rest window layers."""
+    return [0 if i == 0 or i % 6 == 5 else 1 for i in range(num_hidden_layers)]
+
+
+def build_mimo_v2_flash(ff: FFModel, batch_size: int, seq_length: int = 8192,
+                        hidden_size: int = 4096, num_hidden_layers: int = 48,
+                        hybrid_layer_pattern=None, moe_layer_freq=None,
+                        intermediate_size: int = 16384,
+                        moe_intermediate_size: int = 2048,
+                        num_attention_heads: int = 64,
+                        num_key_value_heads: int = 4, head_dim: int = 192,
+                        v_head_dim: int = 128,
+                        swa_num_attention_heads: int = 64,
+                        swa_num_key_value_heads: int = 8,
+                        swa_head_dim: int = 192, swa_v_head_dim: int = 128,
+                        partial_rotary_factor: float = 0.334,
+                        rope_theta: float = 5e6, swa_rope_theta: float = 1e4,
+                        sliding_window: int = 128,
+                        attention_value_scale: float = 0.707,
+                        add_swa_attention_sink_bias: bool = True,
+                        add_full_attention_sink_bias: bool = False,
+                        layernorm_epsilon: float = 1e-5,
+                        n_routed_experts: int = 256,
+                        num_experts_per_tok: int = 8, n_group: int = 1,
+                        topk_group: int = 1, routed_scaling_factor=None,
+                        scoring_func: str = "sigmoid",
+                        norm_topk_prob: bool = True,
+                        vocab_size: int = 152576, experts_held=None,
+                        first_expert: int = 0, capacity_factor: float = 1.0,
+                        tile_rows: int = 128):
+    """MiMo-V2-Flash's language model (the keys are those of its public
+    config.json) for training, as one chip of a deployment that divides
+    each layer holds it: RMSNorm; by ``hybrid_layer_pattern``, grouped-
+    query attention over all keys (0) or over a window of
+    ``sliding_window`` keys with a learned sink a head (1), each with its
+    own head counts (``num_*``, ``swa_num_*``: the heads held here, the
+    model's group their ratio) and rotary theta, rotary positions on
+    ``partial_rotary_factor`` of the query/key head, v scaled by
+    ``attention_value_scale``; by ``moe_layer_freq``, a dense gated MLP
+    (0) or routed experts (1: sigmoid scores, a selection bias, weights
+    renormalised over the chosen, no shared expert; ``experts_held`` of
+    ``n_routed_experts`` from ``first_expert`` on) under the device
+    budget; an untied head without bias over the ``vocab_size`` rows held
+    here.  The defaults are the whole published model, the patterns its
+    published ones (``mimo_v2_layer_pattern``; layer 0 dense).  Returns
+    (tokens_tensor, softmax_output); labels are next-token ids."""
+    if hybrid_layer_pattern is None:
+        hybrid_layer_pattern = mimo_v2_layer_pattern(num_hidden_layers)
+    if moe_layer_freq is None:
+        moe_layer_freq = [0] + [1] * (num_hidden_layers - 1)
+    for key, pattern in (("hybrid_layer_pattern", hybrid_layer_pattern),
+                         ("moe_layer_freq", moe_layer_freq)):
+        if len(pattern) != num_hidden_layers or set(pattern) - {0, 1}:
+            raise ValueError(f"build_mimo_v2_flash: {num_hidden_layers} "
+                             f"layers, {key} {pattern}")
+
+    def attention(ff, h, i):
+        window = hybrid_layer_pattern[i] == 1
+        heads, kv_heads, d, dv, theta = (
+            (swa_num_attention_heads, swa_num_key_value_heads, swa_head_dim,
+             swa_v_head_dim, swa_rope_theta) if window else
+            (num_attention_heads, num_key_value_heads, head_dim, v_head_dim,
+             rope_theta))
+        return ff.grouped_query_attention(
+            h, heads, kv_heads, head_dim=d,
+            v_head_dim=dv, rotary_dim=int(partial_rotary_factor * d),
+            rope_theta=theta, window=sliding_window if window else None,
+            sink=add_swa_attention_sink_bias if window
+            else add_full_attention_sink_bias,
+            value_scale=attention_value_scale, name=f"attn_{i}")
+
+    def mlp(ff, h, i):
+        if not moe_layer_freq[i]:
+            return ff.gated_mlp(h, intermediate_size, name=f"mlp_{i}")
+        return ff.routed_experts(
+            h, n_routed_experts, num_experts_per_tok, moe_intermediate_size,
+            experts_held=experts_held, first_expert=first_expert,
+            n_group=n_group, topk_group=topk_group,
+            routed_scaling_factor=routed_scaling_factor or 1.0,
+            n_shared_experts=0, capacity_factor=capacity_factor,
+            tile_rows=tile_rows, scoring=scoring_func, select_bias=True,
+            norm_topk_prob=norm_topk_prob, name=f"moe_{i}")
+
+    return build_decoder(
+        ff, batch_size, seq_length, num_hidden_layers, hidden_size,
+        vocab_size,
+        norm=lambda ff, x, name: ff.rms_norm(x, eps=layernorm_epsilon,
+                                             name=name),
+        attention=attention, mlp=mlp,
+        head=lambda ff, x: ff.dense(x, vocab_size, use_bias=False,
+                                    name="lm_head"),
+        learned_positions=False)
+
+
 def synthetic_lm_batch(batch_size: int, seq_length: int, vocab_size: int,
                        seed: int = 0):
     """(tokens, positions, next-token labels) for a synthetic LM step —

@@ -15,6 +15,10 @@ Either way the per-chip attention is the Pallas flash kernel
 (kernels/flash_attention.py) on a TPU when the kernel can tile the
 shape, and XLA's ``blockwise_attention`` otherwise; the op records which
 ran and why in ``impl_used`` (``MultiHeadAttention._pick_impl``).
+
+The decoders' attention ops (``LatentAttention``, ``GroupedQueryAttention``)
+take their causal core, plain, banded, selected or with a sink, from one
+function, ``attention_core``.
 """
 
 from __future__ import annotations
@@ -402,6 +406,47 @@ def rotate_pairs(x, inv_freq, scale: float = 1.0):
                            axis=-1).astype(x.dtype)
 
 
+def attention_core(qh, kh, vh, impl: str, scale: float, window=None,
+                   select=None, sink=None):
+    """The per-chip core of causal self-attention, and the one place that
+    chooses between the flash kernels and XLA's forms, by ``impl`` as
+    ``_PicksAttentionImpl._pick_impl`` gives it.
+
+    ``qh`` (B, H, S, d); ``kh`` (B, Hkv, S, d) and ``vh`` (B, Hkv, S, dv),
+    Hkv dividing H: query head ``j`` reads key/value head ``j // (H /
+    Hkv)`` (XLA's forms are given each key/value head repeated over its
+    group; the kernels read it through their index maps).  ``window``:
+    query t sees keys ``t - window + 1 .. t``; ``select``: the pairs kept,
+    bool with the queries down the rows for XLA, of a float dtype with the
+    keys down the rows for the kernels (as ``flash_attention`` takes it);
+    ``sink`` (H,): a logit a head that takes mass in every row's softmax
+    and adds no value.  Returns the heads' outputs (B, H, S, dv) and the
+    rows' log-sum-exp (B, H, S), the sink's mass in it."""
+    if impl == "xla":
+        group = qh.shape[1] // kh.shape[1]
+        if group > 1:
+            kh, vh = (jnp.repeat(t, group, axis=1) for t in (kh, vh))
+        if window is None and select is None and sink is None:
+            from ..parallel.sequence import blockwise_attention
+            return blockwise_attention(qh, kh, vh, causal=True, scale=scale)
+        from .dsa import causal_mask, masked_attention
+        keep = select
+        if keep is None:
+            s = qh.shape[2]
+            if window is None:
+                keep = causal_mask(s)[None]
+            else:
+                t = jnp.arange(s)
+                keep = (causal_mask(s)
+                        & (t[None, :] > t[:, None] - window))[None]
+        return masked_attention(qh, kh, vh, keep, scale, sink)
+    from ..kernels.flash_attention import flash_attention
+    return flash_attention(qh, kh, vh, causal=True, scale=scale,
+                           return_lse=True,
+                           interpret=impl == "pallas_interpret",
+                           window=window, select=select, sink=sink)
+
+
 class LatentAttention(_PicksAttentionImpl, Op):
     """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434, section
     2.1), causal self-attention over (B, S, E) for training:
@@ -601,32 +646,6 @@ class LatentAttention(_PicksAttentionImpl, Op):
         return index_scores(qi, turn(ki[:, :, None, :])[:, :, 0], w,
                             grad_dtype, 256, 1024, impl, q_rope)
 
-    def _core(self, qh, kh, vh, keep, impl):
-        """The attention core of a layer with a window or an index: the
-        flash kernel (banded by the window, or under the selection ``keep``,
-        (B, S, S) with the keys down the rows, as the kernel takes it) or
-        XLA's form of the same (``keep`` bool, queries down the rows).
-        Returns the heads' outputs (B, H, S, dv) and the rows'
-        log-sum-exp, None without a selection."""
-        s = qh.shape[2]
-        if impl == "xla":
-            from .dsa import causal_mask, masked_attention
-            if keep is None:
-                t = jnp.arange(s)
-                keep = (causal_mask(s)
-                        & (t[None, :] > t[:, None] - self.window))[None]
-            return masked_attention(qh, kh, vh, keep, self.softmax_scale)
-        from ..kernels.flash_attention import flash_attention
-        more = {}
-        if self.window is not None:
-            more["window"] = self.window
-        if keep is not None:
-            more.update(select=keep, return_lse=True)
-        out = flash_attention(qh, kh, vh, causal=True,
-                              scale=self.softmax_scale,
-                              interpret=impl == "pallas_interpret", **more)
-        return out if keep is not None else (out, None)
-
     def forward(self, params, xs: List[jax.Array], ctx: FwdCtx):
         from .linear import project
         from .misc import rms_norm
@@ -660,18 +679,9 @@ class LatentAttention(_PicksAttentionImpl, Op):
             vh = heads(kv[..., dn:])
         impl, why = self._pick_impl(s, s)
         self.impl_used = (impl, why)
-        if self.index is None and self.window is None:
-            if impl == "xla":
-                from ..parallel.sequence import blockwise_attention
-                oh, _ = blockwise_attention(qh, kh, vh, causal=True,
-                                            scale=self.softmax_scale)
-            else:
-                from ..kernels.flash_attention import flash_attention
-                oh = flash_attention(qh, kh, vh, causal=True,
-                                     scale=self.softmax_scale,
-                                     interpret=impl == "pallas_interpret")
-        elif self.index is None:
-            oh, _ = self._core(qh, kh, vh, None, impl)
+        if self.index is None:
+            oh, _ = attention_core(qh, kh, vh, impl, self.softmax_scale,
+                                   window=self.window)
         else:
             from .dsa import index_kl, select_topk
             self.index_impl_used = self._pick_index_impl(impl, why, s)
@@ -683,7 +693,8 @@ class LatentAttention(_PicksAttentionImpl, Op):
                 # keys down the rows, as the kernels hold the scores
                 sel = keep if impl == "xla" else jnp.swapaxes(
                     keep, 1, 2).astype(jnp.bfloat16)
-            oh, lse = self._core(qh, kh, vh, sel, impl)
+            oh, lse = attention_core(qh, kh, vh, impl, self.softmax_scale,
+                                     select=sel)
             if ctx.losses is not None:
                 with jax.named_scope("ff.dsa.loss"):
                     sg = jax.lax.stop_gradient
@@ -739,3 +750,158 @@ class LatentAttention(_PicksAttentionImpl, Op):
         flops = 6.0 * (2.0 * s * (self.q_lora_rank * ih * idim
                                   + e * (idim + ih)) + ih * s * s * idim)
         return flops, float(s) * s * (4 * 4 + 2 * 4)
+
+
+class GroupedQueryAttention(_PicksAttentionImpl, Op):
+    """Grouped-query attention (Ainslie et al., arXiv:2305.13245), causal
+    self-attention over (B, S, E) for training:
+
+        q = x W_q,  k = x W_k,  v = value_scale * x W_v         per head
+        rotary positions on the first ``rotary_dim`` of each q and k head
+        score = q_t . k_s * head_dim^-0.5, query head i reading key/value
+                head i // (num_heads / num_kv_heads)
+        out = concat_i(softmax_s(score) v) W_O
+
+    on adjacent pairs at ``rope_theta`` (``rotate_pairs``), no bias.
+    ``num_heads`` query heads and ``num_kv_heads`` key/value heads are held
+    here, the query heads in whole groups of ``num_heads / num_kv_heads``;
+    where fewer query heads than a group are held, ``num_kv_heads`` is 1,
+    the one key/value head they read, held on every chip of its group.
+    ``W_O`` carries the held heads' rows and the output is this share's
+    partial sum.
+
+    ``window``: query t sees keys ``t - window + 1 .. t``.  ``sink``: a
+    learned logit ``b_i`` a held head (zero at first) in the softmax's
+    denominator, with no value: ``p_ts = exp(s_ts) / (exp(b_i) + sum_s'
+    exp(s_ts'))``.  A window layer with a sink counts ``swa_sink_share``,
+    the mass the sink takes (the mean over its heads and queries of
+    ``exp(b_i - lse_t)``; ``profiling.counters()`` gives the mean over
+    such layers and drained steps).  The core is ``attention_core``: the
+    flash kernels on a TPU (k and v read a group at a time through their
+    index maps), XLA's forms elsewhere (``impl_used``).
+
+    Config dim 2 is head parallelism over the held query heads: ``W_q``'s
+    columns, ``W_O``'s rows and the sinks shard over it, ``W_k`` and
+    ``W_v`` are whole on every shard, so that a shard that holds whole
+    groups or part of one reads the key/value heads its queries need; the
+    cost model prices those two projections as work every shard does
+    whole (``unsplit_cost_per_sample``).  The output is placed by the
+    batch degree alone.
+    """
+
+    _type = "GroupedQueryAttention"
+
+    def __init__(self, model, input_tensor, num_heads: int, num_kv_heads: int,
+                 head_dim: int, v_head_dim: int,
+                 rotary_dim: Optional[int] = None, rope_theta: float = 10000.0,
+                 window: Optional[int] = None, sink: bool = False,
+                 value_scale: float = 1.0, kernel_initializer=None,
+                 name: Optional[str] = None):
+        super().__init__(model, [input_tensor], name)
+        self.num_heads, self.num_kv_heads = int(num_heads), int(num_kv_heads)
+        if self.num_kv_heads < 1 or self.num_heads % self.num_kv_heads:
+            raise ValueError(f"{self.name}: {num_heads} query heads held "
+                             f"over {num_kv_heads} key/value heads")
+        self.head_dim, self.v_dim = int(head_dim), int(v_head_dim)
+        self.rotary_dim = self.head_dim if rotary_dim is None \
+            else int(rotary_dim)
+        if self.rotary_dim % 2 or not 0 <= self.rotary_dim <= self.head_dim:
+            raise ValueError(f"{self.name}: rotary dims {rotary_dim} of a "
+                             f"head of {head_dim}")
+        self.inv_freq = yarn_inv_freq(self.rotary_dim, rope_theta)
+        self.window = None if window is None else int(window)
+        self.sink = bool(sink)
+        self.value_scale = float(value_scale)
+        self.softmax_scale = self.head_dim ** -0.5
+        b, s, e = input_tensor.dims
+        h, hkv = self.num_heads, self.num_kv_heads
+        self._add_output((b, s, e), input_tensor.dtype)
+        init = kernel_initializer or DefaultWeightInitializer()
+        self._add_weight("w_q", (e, h * self.head_dim), init,
+                         partition_dims=(None, 2))
+        self._add_weight("w_k", (e, hkv * self.head_dim), init)
+        self._add_weight("w_v", (e, hkv * self.v_dim), init)
+        self._add_weight("w_o", (h * self.v_dim, e), init,
+                         partition_dims=(2, None))
+        if self.sink:
+            self._add_weight("sink", (h,), ZeroInitializer(),
+                             partition_dims=(2,))
+            if self.window is not None:
+                self.COUNTERS = ("swa_sink_share", "swa_layers")
+
+    def _config_dim_bound(self, i: int):
+        if i == 1:
+            return 1   # no sequence parallelism: the ring is MHA's
+        if i == 2:
+            return self.num_heads
+        return super()._config_dim_bound(i)
+
+    constraint_pc = Op.batch_only_pc
+
+    def cost_key(self) -> str:
+        key = (f"gqa{self.num_heads}.{self.num_kv_heads}"
+               f"d{self.head_dim}.{self.v_dim}r{self.rotary_dim}")
+        if self.window is not None:
+            key += f"w{self.window}"
+        return key + ("s" if self.sink else "")
+
+    def forward(self, params, xs: List[jax.Array], ctx: FwdCtx):
+        from .linear import project
+
+        x = xs[0]
+        b, s, _ = x.shape
+        h, hkv, d, dv = self.num_heads, self.num_kv_heads, self.head_dim, \
+            self.v_dim
+        with jax.named_scope("ff.gqa.q_proj"):
+            q = project(x, params["w_q"]).reshape(b, s, h, d)
+        with jax.named_scope("ff.gqa.kv_proj"):
+            k = project(x, params["w_k"]).reshape(b, s, hkv, d)
+            # v's scale is linear: it goes on the float32 weight, which
+            # is rounded once either way
+            w_v = params["w_v"] if self.value_scale == 1.0 \
+                else params["w_v"] * self.value_scale
+            v = project(x, w_v).reshape(b, s, hkv, dv)
+        with jax.named_scope("ff.gqa.rope"):
+            r = self.rotary_dim
+            turn = lambda t: jnp.concatenate(
+                [rotate_pairs(t[..., :r], self.inv_freq), t[..., r:]],
+                axis=-1)
+            heads = lambda t: t.transpose(0, 2, 1, 3)     # (B, H, S, d)
+            qh, kh, vh = heads(turn(q)), heads(turn(k)), heads(v)
+        impl, why = self._pick_impl(s, s)
+        self.impl_used = (impl, why)
+        sink = params["sink"] if self.sink else None
+        oh, lse = attention_core(qh, kh, vh, impl, self.softmax_scale,
+                                 window=self.window, sink=sink)
+        if "swa_layers" in self.COUNTERS and ctx.counters is not None:
+            sg = jax.lax.stop_gradient
+            share = jnp.mean(jnp.exp(
+                sg(sink).astype(jnp.float32)[None, :, None] - sg(lse)))
+            for name, value in zip(self.COUNTERS, (share, jnp.float32(1))):
+                ctx.counters[name] = ctx.counters.get(name, 0.0) + value
+        with jax.named_scope("ff.gqa.o_proj"):
+            out = project(oh.transpose(0, 2, 1, 3).reshape(b, s, h * dv),
+                          params["w_o"])
+        return [out]
+
+    def decode(self, params, xs, cache, pos, ctx):
+        raise NotImplementedError(
+            f"{self.name}: GroupedQueryAttention has no decode path (a "
+            f"window layer's cache would hold its last keys and a sink)")
+
+    def flops_per_sample(self):
+        """What head parallelism divides: the query and output
+        projections and the core over the pairs it is given (the
+        window's band, or the causal square as the other ops count it)."""
+        _, s, e = self.output.dims
+        h, d, dv = self.num_heads, self.head_dim, self.v_dim
+        keys = min(s, self.window) if self.window is not None else s
+        return 2.0 * s * e * h * (d + dv) + 2.0 * h * s * keys * (d + dv)
+
+    def unsplit_cost_per_sample(self):
+        """(FLOPs, bytes) a sample of what every head share does whole:
+        the key and value projections, and their heads written in
+        bfloat16."""
+        _, s, e = self.output.dims
+        width = self.num_kv_heads * (self.head_dim + self.v_dim)
+        return 2.0 * s * e * width, 2.0 * s * width

@@ -220,16 +220,19 @@ def select_topk(scores, topk: int):
         lambda: equal)
 
 
-def masked_attention(q, k, v, keep, scale: float):
+def masked_attention(q, k, v, keep, scale: float, sink=None):
     """XLA's form of attention under a mask, for a platform without the
     kernel: q, k (B, H, T, d), v (B, H, T, dv), ``keep`` (B | 1, T, T)
-    bool, queries down the rows.  Scores and softmax in float32, held
-    whole: for small shapes.  Returns the output and the rows' log-sum-
-    exp (B, H, T)."""
+    bool, queries down the rows; ``sink`` (H,), a logit a head in every
+    row's softmax with no value, or None.  Scores and softmax in float32,
+    held whole: for small shapes.  Returns the output and the rows' log-
+    sum-exp (B, H, T)."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     s = jnp.where(keep[:, None], s, NEG_INF)
     lse = jax.nn.logsumexp(s, axis=-1)
+    if sink is not None:
+        lse = jnp.logaddexp(lse, sink.astype(jnp.float32)[None, :, None])
     p = jnp.exp(s - lse[..., None])
     out = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)

@@ -315,13 +315,19 @@ def counters() -> Dict[str, float]:
     Where a model with a learned index (``ops/dsa.py``) has drained,
     ``dsa_index_kl``: the index's objective ``L_I``, the mean over the
     indexed layers and every drained step (the layers summed a term each
-    into the step's objective, ``FwdCtx.add_loss``)."""
+    into the step's objective, ``FwdCtx.add_loss``).  Where a model with
+    window layers that have a sink (``GroupedQueryAttention``) has
+    drained, ``swa_sink_share``: the softmax's mass the sink took, the
+    mean over those layers, their heads and queries, and every drained
+    step."""
     with _lock:
         out = dict(_counters)
     for name, source in _TRAIN_STEP.items():
         out[name] = out.get(source, 0.0)
     if out.get("dsa_layers"):
         out["dsa_index_kl"] /= out["dsa_layers"]
+    if out.get("swa_layers"):
+        out["swa_sink_share"] /= out["swa_layers"]
     tokens = out.get("moe_tokens")
     if tokens:
         made, kept = out["moe_assignments_made"], out["moe_assignments_kept"]

@@ -1,7 +1,8 @@
-"""The kernels of the DeepSeek-V2 and dots3 cells at their real widths, compiled for a
-v5e that is described and not attached: what the chip's compiler refuses
-(a block Mosaic cannot tile, too much VMEM) costs no chip time.  Nothing
-runs, so nothing here is a result or a time.  The topology is described
+"""The kernels of the DeepSeek-V2, dots3 and MiMo-V2-Flash cells at their real
+widths, compiled for a v5e that is described and not attached: what the
+chip's compiler refuses (a block Mosaic cannot tile, too much VMEM) costs no
+chip time.  Nothing runs, so nothing here is a result or a time.  The
+topology is described
 inside a fixture, in this one file, so that only the worker that runs
 these tests loads the TPU's library."""
 
@@ -85,6 +86,32 @@ def test_selected_kernels_at_the_full_layers_heads(one_chip):
     text = _grads_compile(one_chip, 4, 192, 128, select=True)
     assert text.count("tpu_custom_call") >= 3
     assert "flash_sel_fwd" in text and "flash_sel_dq" in text
+
+
+@pytest.mark.parametrize("kv_heads,window,names", [
+    (1, None, ("flash_fwd", "flash_dq", "flash_dkv")),
+    (2, 128, ("flash_win_sink_fwd", "flash_win_dq", "flash_win_dkv"))])
+def test_grouped_kernels_at_the_mimo_layers_heads(one_chip, kv_heads, window,
+                                                  names):
+    """MiMo-V2-Flash's share: 16 query heads of 192 | 128 over 1 sequence
+    of 8192, reading one key/value head (full layers) or two, within a
+    band of 128 keys and with a sink (window layers)."""
+    def spec(heads, width):
+        return jax.ShapeDtypeStruct((1, heads, 8192, width), jnp.bfloat16,
+                                    sharding=one_chip)
+    sink = [jax.ShapeDtypeStruct((16,), jnp.float32, sharding=one_chip)] \
+        if window else []
+
+    def f(q, k, v, *b):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True, scale=192 ** -0.5, window=window,
+            sink=b[0] if b else None).astype(jnp.float32))
+    compiled = jax.jit(jax.grad(f, argnums=tuple(range(3 + len(sink))))) \
+        .lower(spec(16, 192), spec(kv_heads, 192), spec(kv_heads, 128),
+               *sink).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert all(n in text for n in names)
 
 
 def test_index_kernels_at_the_full_layers_index(one_chip):
