@@ -212,9 +212,12 @@ def phase_kernels(sz, dev, stats):
     ref = graded(mha_reference)
 
     def said(tiling_):
-        # per kernel "block_q x block_k body/grid steps"
+        # per kernel "block_q x block_k body/grid steps", and under a
+        # window "pairs computed/band pairs"
         return {kernel: "{block_q}x{block_k} {body_steps}/{grid_steps}"
-                .format(**t) for kernel, t in tiling_.items()}
+                .format(**t) + (" pairs {pairs_computed}/{band_pairs}"
+                                .format(**t) if "band_pairs" in t else "")
+                for kernel, t in tiling_.items()}
 
     worst, tiles = {}, {}
     for shape in sz["flash_shapes"]:

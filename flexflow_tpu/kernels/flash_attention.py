@@ -45,12 +45,17 @@ into bands of keys that leave out the queries before them (``_tiles``).
 A scale that is a power of two goes on the query, where it is exact.
 
 Window and selection.  With ``window`` a query sees its own key and the
-``window - 1`` before it: a band under the diagonal.  A block wholly
-below the band is treated as one wholly above the diagonal (no body, no
-fetch), and a block the band's lower edge crosses is masked whole, on
-both edges.  With ``select`` (batch, seq_k, seq_q), the pairs a query
-keeps as non-zero entries, keys down the rows as the scores are held,
-every block under the diagonal runs and masks by its block of
+``window - 1`` before it: a band under the diagonal.  The grids are
+banded (``_grid``): the forward's and ``dq``'s inner axis walks the k
+blocks a q block's band touches, ``dkv``'s the q blocks that see a k
+block, and a step past a band the sequence clips runs no body and names
+a block already held.  The blocks are square, so whether a block needs a
+mask and which of its pairs are in the band is a function of its offset
+from the diagonal alone: each offset runs its own branch over tiles of
+whole cells that hold band pairs (``_band_tiles``, ``_cell``).  With
+``select`` (batch, seq_k, seq_q), the pairs a query keeps as non-zero
+entries, keys down the rows as the scores are held, every block under
+the diagonal runs and masks by its block of
 ``select`` alone (the selection is causal already); a query with no kept
 key in a block leaves rows that the next block with one wipes
 (``alpha`` is 0), and its own key's block comes last.  Both variants
@@ -70,9 +75,9 @@ reduction outside them.  A call with a sink carries ``_sink`` in its
 forward kernel's name (``flash_win_sink_fwd``); its backward kernels are
 the plain ones.
 
-Blocks are a function of (seq_q, seq_k, head_dim) alone
-(``_block_sizes``, chosen by a sweep on the v5e), the bands of the
-kernel; ``tiling()`` says what each kernel does with a shape.
+Blocks are a function of (seq_q, seq_k, head_dim) and, under a window,
+of the window (``_block_sizes``, chosen by sweeps on the v5e), the bands
+of the kernel; ``tiling()`` says what each kernel does with a shape.
 
 The kernels compile through Mosaic and run on a TPU only.  Callers pick
 the implementation by platform (ops/attention.py); ``interpret=True``
@@ -115,6 +120,16 @@ _WHOLE = 512
 # fewer matmuls a kernel has, the dearer a band's fixed cost against the
 # masked work it leaves out (sweep on the v5e, PERF.md section 6, PR 26).
 _DIAGONAL_BAND = {"flash_fwd": 512, "flash_dq": 256, "flash_dkv": 128}
+# Rows and keys of a block under a window.  The band's tiles compute about
+# the same pairs whatever the block, so a larger block saves grid steps,
+# each of a fixed cost; but the tiles are unrolled, a branch an offset
+# from the diagonal and a tile a cell of keys, and every start of a
+# process traces and lowers them again, whether the compile cache then
+# holds the program or not.  At a window of 128 a layer ran in 3.01 ms at
+# 256 rows, 2.47 at 512 and 2.13 at 2048, against 5.12 with the causal
+# grid, and 2048 made every start of that model some 6 s longer (sweep
+# and starts on the v5e: PERF.md, the window cores).
+_WINDOW_BLOCK = 256
 
 _NT = (((1,), (1,)), ((), ()))   # a @ b^T
 _NN = (((1,), (0,)), ((), ()))   # a @ b
@@ -162,18 +177,35 @@ def unsupported_reason(seq_q: int, seq_k: int,
 
 
 def _block_sizes(seq_q: int, seq_k: int, head_dim: int,
-                 block_q: Optional[int] = None, block_k: Optional[int] = None
-                 ) -> Tuple[int, int]:
+                 block_q: Optional[int] = None, block_k: Optional[int] = None,
+                 window: Optional[int] = None) -> Tuple[int, int]:
     """(block_q, block_k) all three kernels tile the two sequences with:
-    a function of the shape alone (``block_q`` / ``block_k`` are a
-    test's or a sweep's cap; whether the call is causal did not move
-    the best choice in any kernel, so it is not asked).  ValueError
-    naming the sequence ``unsupported_reason`` rejects."""
+    a function of the shape and the window (``block_q`` / ``block_k``
+    are a test's or a sweep's cap; whether the call is causal did not
+    move the best choice in any kernel, so it is not asked).  A window's
+    call is causal self-attention and its blocks are square, under the
+    smaller cap.  ValueError naming the sequence ``unsupported_reason``
+    rejects."""
     why = unsupported_reason(seq_q, seq_k, block_q, block_k)
     if why is not None:
         raise ValueError(why)
-    return (_largest_block(seq_q, block_q or _max_block(head_dim)),
-            _largest_block(seq_k, block_k or _max_block(head_dim)))
+    if window is not None:
+        block = _largest_block(seq_q, min(block_q or _WINDOW_BLOCK,
+                                          block_k or _WINDOW_BLOCK))
+        return block, block
+    cap = _max_block(head_dim)
+    return (_largest_block(seq_q, block_q or cap),
+            _largest_block(seq_k, block_k or cap))
+
+
+def _max(a, b):
+    """The larger of two Python ints, or of traced scalars."""
+    return max(a, b) if isinstance(a, int) else jnp.maximum(a, b)
+
+
+def _min(a, b):
+    """The smaller of two Python ints, or of traced scalars."""
+    return min(a, b) if isinstance(a, int) else jnp.minimum(a, b)
 
 
 def _runs(qi, ki, block_q: int, block_k: int, window=None):
@@ -191,32 +223,43 @@ def _on_diagonal(qi, ki, block_q: int, block_k: int):
     return ki * block_k + block_k - 1 > qi * block_q
 
 
-def _below_band(qi, ki, block_q: int, block_k: int, window: int):
-    """Window: does block (qi, ki) hold a pair with k <= q - window, so
-    that it needs the mask of the band's lower edge?"""
-    return ki * block_k <= qi * block_q + block_q - 1 - window
-
-
 def tiling(seq_q: int, seq_k: int, head_dim: int, causal: bool,
            block_q: Optional[int] = None, block_k: Optional[int] = None,
            window: Optional[int] = None, selected: bool = False
            ) -> Dict[str, Dict[str, int]]:
     """What each kernel does with one (batch, head) of this shape: its
     blocks, the steps its grid has and those of them that run a body
-    (the rest lie wholly above the causal diagonal, or wholly below the
-    window's band, and fetch nothing), and the bands a block on the
-    diagonal is cut into (``_tiles``; one under a window or a
-    selection, whose blocks are masked whole).  A group or a sink moves
-    none of it (a sink's kernels are named as ``kernel_names`` says)."""
-    bq, bk = _block_sizes(seq_q, seq_k, head_dim, block_q, block_k)
+    (the rest lie wholly above the causal diagonal, or past a band the
+    sequence clips, and fetch nothing), and the bands a block on the
+    diagonal is cut into (``_tiles``; one under a selection, whose blocks
+    are masked whole; under a window the band's tiles of the diagonal
+    block, ``_band_tiles``).  Under a window also the pairs the running
+    tiles compute and the band's own pairs.  A group or a sink moves none
+    of it (a sink's kernels are named as ``kernel_names`` says)."""
+    bq, bk = _block_sizes(seq_q, seq_k, head_dim, block_q, block_k, window)
     nq, nk = seq_q // bq, seq_k // bk
-    body = sum(1 for qi in range(nq) for ki in range(nk)
-               if not causal or _runs(qi, ki, bq, bk, window))
-    whole = window is not None or selected
-    return {name: dict(block_q=bq, block_k=bk, grid_steps=nq * nk,
-                       body_steps=body, diagonal_bands=1 if whole else len(
-                           _tiles(kernel, causal, 0, 0, bq, bk)))
-            for kernel, name in zip(KERNELS, kernel_names(window, selected))}
+    runs = [(qi, ki) for qi in range(nq) for ki in range(nk)
+            if not causal or _runs(qi, ki, bq, bk, window)]
+    inner_k, inner_q = _grid(seq_q, seq_k, bq, bk, window)
+    plans = {}
+    for kernel, name in zip(KERNELS, kernel_names(window, selected)):
+        if window is not None:
+            bands = len(_band_tiles(0, 0, 0, bk, window))
+        else:
+            bands = 1 if selected else len(_tiles(kernel, causal, 0, 0, bq,
+                                                  bk))
+        plans[name] = dict(
+            block_q=bq, block_k=bk,
+            grid_steps=nk * inner_q if kernel == "flash_dkv" else nq * inner_k,
+            body_steps=len(runs), diagonal_bands=bands)
+        if window is not None:
+            plans[name].update(
+                pairs_computed=sum(
+                    (ks.stop - ks.start) * (qs.stop - qs.start)
+                    for qi, ki in runs
+                    for ks, qs, _ in _band_tiles(qi, ki, ki - qi, bk, window)),
+                band_pairs=sum(min(q + 1, window) for q in range(seq_q)))
+    return plans
 
 
 def _folds(scale: float) -> bool:
@@ -267,21 +310,16 @@ def _kept(sel_ref, ks, qs):
     return sel_ref[ks, qs].astype(jnp.float32) > 0.0
 
 
-def _tiles(kernel: str, masked: bool, qi, ki, block_q: int, block_k: int,
-           whole: bool = False):
+def _tiles(kernel: str, masked: bool, qi, ki, block_q: int, block_k: int):
     """[(k rows, q columns, mask_at)] that cover what block (qi, ki) has
     to compute: two slices of the block and what ``_causal_mask`` takes.
     A masked block of square blocks starts on the diagonal (qi == ki),
     so which of its pairs are masked is known here: it is cut into bands
     of ``_DIAGONAL_BAND`` keys, and a band leaves out the queries before
-    its first key.  ``whole``: one tile, masked at the block's own
-    position (a window's block may lie off the diagonal)."""
+    its first key."""
     if not masked:
         return [(slice(0, block_k), slice(0, block_q), None)]
     band = _DIAGONAL_BAND[kernel]
-    if whole:
-        return [(slice(0, block_k), slice(0, block_q),
-                 (qi * block_q, ki * block_k))]
     if block_q != block_k or block_k % band:
         band = block_k                      # one band: the whole block
     return [(slice(lo, lo + band), slice(lo, block_q),
@@ -289,24 +327,68 @@ def _tiles(kernel: str, masked: bool, qi, ki, block_q: int, block_k: int,
             for lo in range(0, block_k, band)]
 
 
-def _when_causal(causal: bool, qi, ki, block_q: int, block_k: int, body,
-                 window=None, selected: bool = False):
-    """Run ``body(masked)`` as block (qi, ki) needs it: not at all above
-    the diagonal or below the window's band, masked on the diagonal or
-    the band's lower edge, plain between them or without ``causal``.  A
-    selection masks every block itself, so its blocks run plain."""
+def _cell(block: int, window: int) -> int:
+    """Keys and query columns of a cell of a window's block: the widest
+    128 lanes times a power of two that divides the block and is no wider
+    than the window (128 at least), so that a tile computes at most about
+    twice the band's pairs and a wide band takes few tiles; a block not
+    of whole 128s is one cell."""
+    if block % 128:
+        return block
+    cell = 128
+    while 2 * cell <= window and block % (2 * cell) == 0:
+        cell *= 2
+    return cell
+
+
+def _band_tiles(qi, ki, offset: int, block: int, window: int):
+    """Window: the tiles (as ``_tiles``) that cover the band's pairs in
+    block (qi, ki) = (qi, qi + offset).  Which pairs those are depends on
+    the offset alone: a pair of local query x and key y is in the band
+    where ``offset * block <= x - y < offset * block + window``.  The block
+    is cut into bands of ``_cell`` keys, each over the query columns, in
+    whole cells, that hold its band pairs; a tile wholly inside the band
+    needs no mask."""
+    cell = _cell(block, window)
+    lo, hi = offset * block, offset * block + window - 1
+    tiles = []
+    for y in range(0, block, cell):
+        first = max(0, y + lo) // cell * cell
+        end = min(block, -(-(y + cell + hi) // cell) * cell)
+        if first >= end:
+            continue
+        inside = first - (y + cell - 1) >= lo and end - 1 - y <= hi
+        tiles.append((slice(y, y + cell), slice(first, end), None if inside
+                      else (qi * block + first, ki * block + y)))
+    return tiles
+
+
+def _when_causal(kernel: str, causal: bool, qi, ki, block_q: int,
+                 block_k: int, body, window=None, selected: bool = False):
+    """Run ``body(tiles)`` as block (qi, ki) needs it: not at all above
+    the diagonal, masked on it, plain below it or without ``causal``.  A
+    selection masks every block itself, so its blocks run plain.  Under a
+    window a block runs the band's tiles (``_band_tiles``), one branch an
+    offset from the diagonal, and none outside the band."""
     if not causal:
-        body(False)
+        body(_tiles(kernel, False, qi, ki, block_q, block_k))
         return
     if selected:
-        pl.when(_runs(qi, ki, block_q, block_k))(lambda: body(False))
+        pl.when(_runs(qi, ki, block_q, block_k))(
+            lambda: body(_tiles(kernel, False, qi, ki, block_q, block_k)))
+        return
+    if window is not None:
+        # the offsets ki - qi of the blocks that hold band pairs, from the
+        # band's lower edge to the diagonal
+        for offset in range(-((window + block_k - 2) // block_k), 1):
+            pl.when(ki - qi == offset)(lambda offset=offset: body(
+                _band_tiles(qi, ki, offset, block_k, window)))
         return
     diag = _on_diagonal(qi, ki, block_q, block_k)
-    if window is not None:
-        diag = diag | _below_band(qi, ki, block_q, block_k, window)
-    pl.when(jnp.logical_and(diag, _runs(qi, ki, block_q, block_k, window)))(
-        lambda: body(True))
-    pl.when(jnp.logical_not(diag))(lambda: body(False))
+    pl.when(jnp.logical_and(diag, _runs(qi, ki, block_q, block_k)))(
+        lambda: body(_tiles(kernel, True, qi, ki, block_q, block_k)))
+    pl.when(jnp.logical_not(diag))(
+        lambda: body(_tiles(kernel, False, qi, ki, block_q, block_k)))
 
 
 def _last_k(qi, block_q: int, block_k: int):
@@ -319,30 +401,62 @@ def _first_q(ki, block_q: int, block_k: int):
     return (ki * block_k) // block_q
 
 
+def _band_first_k(qi, block_q: int, block_k: int, window: int):
+    """Window: the first k block with a key of q block ``qi``'s band."""
+    return _max(qi * block_q - window + 1, 0) // block_k
+
+
+def _band_last_q(ki, block_q: int, block_k: int, window: int, nq):
+    """Window: the last q block with a query that sees a key of k block
+    ``ki``."""
+    return _min((ki * block_k + block_k + window - 2) // block_q, nq - 1)
+
+
+def _grid(seq_q: int, seq_k: int, block_q: int, block_k: int, window=None
+          ) -> Tuple[int, int]:
+    """The grids' inner axes: the k blocks a q block visits (forward,
+    dq) and the q blocks a k block visits (dkv).  Every block; under a
+    window the band's: the most k blocks a q block's band touches and the
+    most q blocks that see a k block (a block whose band the sequence
+    clips has fewer, and runs no body on the steps it has over)."""
+    nq, nk = seq_q // block_q, seq_k // block_k
+    if window is None:
+        return nk, nq
+    return (max(_last_k(qi, block_q, block_k)
+                - _band_first_k(qi, block_q, block_k, window) + 1
+                for qi in range(nq)),
+            max(_band_last_q(ki, block_q, block_k, window, nq)
+                - _first_q(ki, block_q, block_k) + 1 for ki in range(nk)))
+
+
 def _k_of(causal: bool, block_q: int, block_k: int, window=None):
     """The k block a step (qi, ki) of a (batch*heads, q, k) grid names: a
-    step above the causal diagonal, or below the window's band, names a
-    block that is held or will be."""
+    step above the causal diagonal names a block that is held or will be.
+    Under a window the grid is banded: step ``ki`` names the band's
+    ``ki``-th block, and a step past the band its last, which is held."""
     def k_of(qi, ki):
+        if window is not None:
+            return _min(_band_first_k(qi, block_q, block_k, window) + ki,
+                        _last_k(qi, block_q, block_k))
         if causal:
             ki = jnp.minimum(ki, _last_k(qi, block_q, block_k))
-        if window is not None:
-            ki = jnp.maximum(ki, jnp.maximum(
-                qi * block_q - window + 1, 0) // block_k)
         return ki
     return k_of
 
 
 def _q_of(causal: bool, block_q: int, block_k: int, nq: int, window=None):
     """The q block a step (ki, qi) of a (batch*heads, k, q) grid names: a
-    skipped step names the first block that runs, or the last."""
+    skipped step names the first block that runs, or the last.  Under a
+    window the grid is banded and walks back from the last q block that
+    sees block ``ki``: a step past the first names the first, which is
+    held."""
     def q_of(ki, qi):
+        if window is not None:
+            return _max(_band_last_q(ki, block_q, block_k, window, nq) - qi,
+                        _first_q(ki, block_q, block_k))
         if causal:
             qi = jnp.minimum(jnp.maximum(qi, _first_q(ki, block_q, block_k)),
                              nq - 1)
-        if window is not None:
-            qi = jnp.minimum(
-                qi, (ki * block_k + block_k + window - 2) // block_q)
         return qi
     return q_of
 
@@ -387,10 +501,12 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, window=None,
     sink_ref = refs.pop(0) if sunk else None
     o_ref, lse_ref, acc_sc, m_sc, l_sc = refs
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    step = pl.program_id(2)
+    steps = pl.num_programs(2)
+    ki = step if window is None else _band_first_k(
+        qi, block_q, block_k, window) + step
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_sc[:] = jnp.zeros_like(acc_sc)
         if sunk:      # the sink's exp(b - max) is 1 at a max of b
@@ -400,9 +516,8 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, window=None,
             m_sc[:] = jnp.full_like(m_sc, NEG_INF)
             l_sc[:] = jnp.zeros_like(l_sc)
 
-    def _body(masked):
-        for ks, qs, mask_at in _tiles("flash_fwd", masked, qi, ki,
-                                      block_q, block_k, window is not None):
+    def _body(tiles):
+        for ks, qs, mask_at in tiles:
             st = _causal_mask(                       # (keys, queries)
                 _scores_t(q_ref[0, qs, :], k_ref[0, ks, :], scale), mask_at,
                 window)
@@ -421,9 +536,10 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, window=None,
                 preferred_element_type=jnp.float32)
             m_sc[:, qs] = m_new
 
-    _when_causal(causal, qi, ki, block_q, block_k, _body, window, selected)
+    _when_causal("flash_fwd", causal, qi, ki, block_q, block_k, _body, window,
+                 selected)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == steps - 1)
     def _finish():
         l = l_sc[...]
         empty = l == 0.0
@@ -462,7 +578,7 @@ def _fwd_call(qr, kr, vr, scale, causal, bq, bk, interpret, window=None,
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, window=window,
                           selected=sel is not None, sunk=sink is not None),
-        grid=(bh, sq // bq, sk // bk),
+        grid=(bh, sq // bq, _grid(sq, sk, bq, bk, window)[0]),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh_, qi, ki: (bh_, qi, 0)),
             cols(d), cols(dv),
@@ -507,7 +623,7 @@ def _flash_forward(q, k, v, sel, sink, scale, causal, block_q, block_k,
     b, h, sq, d = q.shape
     hkv = k.shape[1]
     sk, dv = v.shape[2:]
-    bq, bk = _block_sizes(sq, sk, d, block_q, block_k)
+    bq, bk = _block_sizes(sq, sk, d, block_q, block_k, window)
     if sink is not None:
         sink = jnp.broadcast_to(sink[None, :, None, None],
                                 (b, h, 1, bq)).reshape(b * h, 1, bq)
@@ -534,17 +650,19 @@ def _bwd_dkdv_kernel(*refs, scale, causal, block_q, block_k, window=None,
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dk_ref, dv_ref, dk_sc, dv_sc) = refs
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+    step = pl.program_id(2)
+    steps = pl.num_programs(2)
+    # a window's blocks are square: as many q blocks as k blocks
+    qi = step if window is None else _band_last_q(
+        ki, block_q, block_k, window, pl.num_programs(1)) - step
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_sc[:] = jnp.zeros_like(dk_sc)
         dv_sc[:] = jnp.zeros_like(dv_sc)
 
-    def _body(masked):
-        for ks, qs, mask_at in _tiles("flash_dkv", masked, qi, ki,
-                                      block_q, block_k, window is not None):
+    def _body(tiles):
+        for ks, qs, mask_at in tiles:
             q, do = q_ref[0, qs, :], do_ref[0, qs, :]
             pt, dst = _dscores_t(
                 q, k_ref[0, ks, :], v_ref[0, ks, :], do, lse_ref[:, qs],
@@ -557,9 +675,10 @@ def _bwd_dkdv_kernel(*refs, scale, causal, block_q, block_k, window=None,
                 dst.astype(q.dtype), q, _NN,
                 preferred_element_type=jnp.float32)
 
-    _when_causal(causal, qi, ki, block_q, block_k, _body, window, selected)
+    _when_causal("flash_dkv", causal, qi, ki, block_q, block_k, _body, window,
+                 selected)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == steps - 1)
     def _finish():
         dk_ref[0] = (dk_sc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
@@ -574,16 +693,17 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, window=None,
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dq_ref, dq_sc) = refs
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    step = pl.program_id(2)
+    steps = pl.num_programs(2)
+    ki = step if window is None else _band_first_k(
+        qi, block_q, block_k, window) + step
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         dq_sc[:] = jnp.zeros_like(dq_sc)
 
-    def _body(masked):
-        for ks, qs, mask_at in _tiles("flash_dq", masked, qi, ki,
-                                      block_q, block_k, window is not None):
+    def _body(tiles):
+        for ks, qs, mask_at in tiles:
             k = k_ref[0, ks, :]
             dst = _dscores_t(
                 q_ref[0, qs, :], k, v_ref[0, ks, :], do_ref[0, qs, :],
@@ -594,9 +714,10 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, window=None,
                 k, dst.astype(k.dtype), _TN,
                 preferred_element_type=jnp.float32)
 
-    _when_causal(causal, qi, ki, block_q, block_k, _body, window, selected)
+    _when_causal("flash_dq", causal, qi, ki, block_q, block_k, _body, window,
+                 selected)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == steps - 1)
     def _finish():
         dq_ref[0] = (dq_sc[...] * scale).T.astype(dq_ref.dtype)
 
@@ -634,7 +755,7 @@ def _dkv_call(qr, kr, vr, dor, lse, delta, scale, causal, bq, bk, interpret,
         functools.partial(_bwd_dkdv_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, window=window,
                           selected=sel is not None),
-        grid=(bh, sk // bk, nq),
+        grid=(bh, sk // bk, _grid(sq, sk, bq, bk, window)[1]),
         in_specs=[rows(d), kv_cols(d), kv_cols(dv), rows(dv), stat,
                   stat] + extra,
         out_specs=[cols(d), cols(dv)],
@@ -680,7 +801,7 @@ def _dq_call(qr, kr, vr, dor, lse, delta, scale, causal, bq, bk, interpret,
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, window=window,
                           selected=sel is not None),
-        grid=(bh, sq // bq, sk // bk),
+        grid=(bh, sq // bq, _grid(sq, sk, bq, bk, window)[0]),
         in_specs=[rows(d), cols(d), cols(dv), rows(dv), stat, stat] + extra,
         out_specs=rows(d),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), qr.dtype),
@@ -700,7 +821,7 @@ def _flash_backward(scale, causal, block_q, block_k, interpret, window, res,
     b, h, sq, d = q.shape
     hkv = k.shape[1]
     sk, dv = v.shape[2:]
-    bq, bk = _block_sizes(sq, sk, d, block_q, block_k)
+    bq, bk = _block_sizes(sq, sk, d, block_q, block_k, window)
     bh = b * h
 
     delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)

@@ -69,6 +69,12 @@ def test_smoke_tiny_mode_runs_every_phase():
     line = next(ln for ln in lines if " phase alexnet: ok " in ln)
     assert json.loads(line.split(" ok ", 1)[1])["conv_space_to_depth"] == \
         ["conv1"]
+    # the kernels line says, under the window, the pairs the running tiles
+    # compute against the band's
+    line = next(ln for ln in lines if " phase kernels: ok " in ln)
+    tiles = json.loads(line.split(" ok ", 1)[1])["tiling"]
+    assert all(" pairs " in t for t in tiles["1x2x128x32/window9"].values())
+    assert not any(" pairs " in t for t in tiles["1x2x128x32"].values())
     # the transformer's line says which tensor the accuracy read: no
     # instruction under the final Softmax's scope, and the two device
     # times a step, which a CPU has not

@@ -447,21 +447,35 @@ def _compare(how, keep, seq=64, d=32, dv=16, **blocks):
 
 
 @pytest.mark.parametrize("window,block_q,block_k", [
-    (9, 16, 16), (17, 16, 16), (5, 32, 16), (40, 16, 32), (100, 16, 16)])
+    (9, 16, 16), (17, 16, 16), (5, 32, 16), (40, 16, 32), (100, 16, 16),
+    (16, 16, 16), (24, 16, 16), (12, 16, 32), (40, 8, 8), (13, None, None)])
 def test_window_kernel_against_a_dense_mask(window, block_q, block_k):
-    """Bands narrower and wider than a block, unequal blocks, and a window
-    longer than the sequence (plain causal)."""
+    """Bands narrower than a block, as wide as one, not dividing one and
+    wider than two, unequal caps (square blocks under the smaller), the
+    blocks of the rule (no caps), and a window longer than the sequence
+    (plain causal)."""
     t = jnp.arange(64)
     keep = (t[None, :] <= t[:, None]) & (t[None, :] > t[:, None] - window)
     _compare(dict(window=window), keep[None, None], block_q=block_q,
              block_k=block_k)
     steps = tiling(64, 64, 32, True, block_q, block_k, window=window)
     assert set(steps) == {"flash_win_fwd", "flash_win_dq", "flash_win_dkv"}
+    bq, bk = (steps["flash_win_fwd"][b] for b in ("block_q", "block_k"))
+    assert bq == bk == min(block_q or 64, block_k or 64)
     # the blocks that run are those that hold a pair of the band
-    blocks = np.asarray(keep).reshape(64 // block_q, block_q,
-                                      64 // block_k, block_k)
-    assert steps["flash_win_fwd"]["body_steps"] \
-        == int(blocks.any(axis=(1, 3)).sum())
+    blocks = np.asarray(keep).reshape(64 // bq, bq, 64 // bk, bk).any(
+        axis=(1, 3))
+    assert steps["flash_win_fwd"]["body_steps"] == int(blocks.sum())
+    # the grids are banded: a q block visits as many k blocks as the
+    # widest band touches, and a k block as many q blocks as see it the
+    # most, not every block of the sequence
+    assert steps["flash_win_fwd"]["grid_steps"] \
+        == steps["flash_win_dq"]["grid_steps"] \
+        == blocks.shape[0] * int(blocks.sum(axis=1).max())
+    assert steps["flash_win_dkv"]["grid_steps"] \
+        == blocks.shape[1] * int(blocks.sum(axis=0).max())
+    assert steps["flash_win_fwd"]["band_pairs"] == int(keep.sum())
+    assert steps["flash_win_fwd"]["pairs_computed"] >= int(keep.sum())
 
 
 @pytest.mark.parametrize("topk,block_q,block_k", [

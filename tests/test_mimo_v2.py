@@ -223,17 +223,34 @@ def _qkv(seed, heads, kv_heads, seq=64, d=24, dv=16):
     return q, k, v, w, jax.random.normal(ks[4], (heads,))
 
 
-@pytest.mark.parametrize("kv_heads,window,sink,blocks", [
-    (2, None, False, (16, 16)), (2, 8, True, (16, 16)),
-    (1, 8, True, (32, 16)), (4, 40, True, (16, 32)),
-    (8, None, True, (16, 16))])
-def test_flash_kernels_with_groups_and_a_sink(kv_heads, window, sink, blocks):
+@pytest.mark.parametrize("kv_heads,window,sink,blocks,seq", [
+    # the cases before the grids were banded keep their ids
+    pytest.param(2, None, False, (16, 16), 64, id="2-None-False-blocks0"),
+    pytest.param(2, 8, True, (16, 16), 64, id="2-8-True-blocks1"),
+    pytest.param(1, 8, True, (32, 16), 64, id="1-8-True-blocks2"),
+    pytest.param(4, 40, True, (16, 32), 64, id="4-40-True-blocks3"),
+    pytest.param(8, None, True, (16, 16), 64, id="8-None-True-blocks4"),
+    (8, 1, True, (None, None), 64), (1, 16, False, (16, 16), 64),
+    (2, 24, True, (16, 16), 64), (4, 12, False, (32, 16), 64),
+    (1, 100, True, (None, None), 64), (2, 128, True, (None, None), 512),
+    (4, 200, False, (128, 128), 512), (8, 300, True, (256, 256), 512),
+    (1, 513, False, (None, None), 1024), (2, 128, False, (128, 256), 512)])
+def test_flash_kernels_with_groups_and_a_sink(kv_heads, window, sink, blocks,
+                                              seq):
     """The three kernels in the Pallas interpreter, 8 query heads over
-    fewer key/value heads (and as many), a window narrower and wider than a
-    block, unequal blocks, with and without a sink: the output, the rows'
-    log-sum-exp and the gradients of q, k, v and the sink against XLA's
-    form of the same core."""
-    q, k, v, w, b = _qkv(kv_heads, 8, kv_heads)
+    fewer key/value heads (and as many), with and without a sink: the
+    output, the rows' log-sum-exp and the gradients of q, k, v and the
+    sink against XLA's form of the same core.  Under a window the banded
+    grids and the band's tiles: window 1, windows narrower than a block,
+    as wide as one, not dividing one, wider than two and longer than the
+    sequence; the rule's blocks (no caps), capped blocks whose tiles are
+    cells of 128, and unequal caps (square blocks under the smaller)."""
+    names = kernel_names(window, sunk=sink)
+    assert ("_sink_" in names[0]) == sink      # the forward's body differs
+    assert names[1:] == kernel_names(window)[1:]
+    assert set(tiling(seq, seq, 24, True, *blocks, window=window)) \
+        == set(kernel_names(window))
+    q, k, v, w, b = _qkv(kv_heads, 8, kv_heads, seq)
     b = b if sink else None
     scale = 24 ** -0.5
 
@@ -257,11 +274,6 @@ def test_flash_kernels_with_groups_and_a_sink(kv_heads, window, sink, blocks):
     assert _rel(lse_a, lse_c) <= 1e-6
     for x, y in zip(ga, gc):
         assert _rel(x, y) <= 1e-5
-    names = kernel_names(window, sunk=sink)
-    assert ("_sink_" in names[0]) == sink      # the forward's body differs
-    assert names[1:] == kernel_names(window)[1:]
-    assert set(tiling(64, 64, 24, True, *blocks, window=window)) \
-        == set(kernel_names(window))
 
 
 def test_a_group_is_read_through_the_index_maps(devices):

@@ -6,7 +6,10 @@ topology is described
 inside a fixture, in this one file, so that only the worker that runs
 these tests loads the TPU's library."""
 
+import base64
+import hashlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -14,7 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from flexflow_tpu.kernels.dsa_index import index_scores
-from flexflow_tpu.kernels.flash_attention import flash_attention
+from flexflow_tpu.kernels.flash_attention import flash_attention, tiling
 from flexflow_tpu.kernels.grouped_matmul import grouped_matmul
 
 
@@ -112,6 +115,80 @@ def test_grouped_kernels_at_the_mimo_layers_heads(one_chip, kv_heads, window,
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3
     assert all(n in text for n in names)
+
+
+@pytest.mark.parametrize("window,head_dim", [(128, 192), (513, 256)])
+def test_window_grids_hold_no_step_outside_the_band(window, head_dim):
+    """MiMo-V2-Flash's and dots3's window layers at 8192 tokens: every
+    step of a banded grid runs a body but for those of the blocks whose
+    band the sequence clips (the forward's and dq's first q blocks,
+    dkv's last k blocks), and the running tiles compute a bounded
+    multiple of the band's pairs."""
+    plans = tiling(8192, 8192, head_dim, True, window=window)
+    b = plans["flash_win_dq"]["block_q"]
+    assert b == plans["flash_win_dq"]["block_k"]
+    n = 8192 // b
+    edge = -(-(window - 1) // b)             # k blocks left of the diagonal
+    for kernel, plan in plans.items():
+        assert plan["grid_steps"] == n * (edge + 1), kernel
+        clipped = edge * (edge + 1) // 2     # the corner the sequence cuts
+        assert plan["grid_steps"] - plan["body_steps"] == clipped, kernel
+        assert plan["band_pairs"] <= plan["pairs_computed"] \
+            <= 2 * plan["band_pairs"], kernel
+
+
+def _decoded(text):
+    """Lowered text with each Mosaic kernel body decoded and printed
+    without source locations (the body carries the kernel file's line
+    numbers)."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    ctx = mlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True
+
+    def body(match):
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(match.group(1)))
+        return module.operation.get_asm(enable_debug_info=False)
+    return re.sub(r"\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22", body, text)
+
+
+# The plain causal and the selected kernels' gradients lower to the text
+# they lowered to before the window kernels' grids were banded: sha256 of
+# `_decoded` of the lowered text at the widths below (MiMo-V2-Flash's full
+# layers, DeepSeek-V2's heads, dots3's full layers), computed with this
+# function on a `git archive` of the commit before that change.
+KERNEL_DIGESTS = {
+    "plain-16-over-1": (
+        (1, 16, 1, 192, 128, 8192), {},
+        "64f172fa8456176e79a23532dd6804daa65e083cb560e591ea39e6c1a1f6d68a"),
+    "plain-8-over-8": (
+        (2, 8, 8, 192, 128, 4096), {},
+        "584e4deaddd72f1e8e8e6868391d3acf38806baa3925439813e7607d9bbebd74"),
+    "selected-4-over-4": (
+        (1, 4, 4, 192, 128, 8192), dict(select=True),
+        "bc28bfe86c8e108b0e0b1783d8c412427d48054069a98145d8d2e9fe948f48e6"),
+}
+
+
+@pytest.mark.parametrize("which", sorted(KERNEL_DIGESTS))
+def test_other_kernels_are_the_programs_they_were(one_chip, which):
+    (batch, heads, kv_heads, d, dv, seq), how, digest = KERNEL_DIGESTS[which]
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    extra = [spec(batch, seq, seq)] if "select" in how else []
+
+    def f(q, k, v, *sel):
+        kw = dict(how, select=sel[0]) if sel else how
+        return jnp.sum(flash_attention(q, k, v, causal=True, scale=d ** -0.5,
+                                       **kw).astype(jnp.float32))
+    text = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(
+        spec(batch, heads, seq, d), spec(batch, kv_heads, seq, d),
+        spec(batch, kv_heads, seq, dv), *extra).as_text()
+    assert hashlib.sha256(_decoded(text).encode()).hexdigest() == digest
 
 
 def test_index_kernels_at_the_full_layers_index(one_chip):
