@@ -8,7 +8,9 @@ compiler — blockwise (flash) attention (``flash_attention``: the
 ``flash_*``, ``flash_win_*`` and ``flash_sel_*`` kernels), the routed
 experts' grouped products (``grouped_matmul``: ``gmm``, ``gmm_t``,
 ``tgmm``), the learned index's scores and their gradient (``dsa_index``:
-``dsa_index_fwd``, ``dsa_index_bwd``) and the fused optimizer updates.
+``dsa_index_fwd``, ``dsa_index_bwd``), Mamba's selective scan and its
+gradient (``ssm_scan``: ``ssm_scan_fwd``, ``ssm_scan_bwd``) and the fused
+optimizer updates.
 Each ``pallas_call`` is named as the scope it runs under,
 ``ff.kernel.<name>``.
 """

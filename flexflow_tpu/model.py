@@ -320,11 +320,47 @@ class FFModel:
         """Causal grouped-query attention (B,S,E)->(B,S,E) over the
         ``num_heads`` query and ``num_kv_heads`` key/value heads held here;
         ``sizes`` are ``GroupedQueryAttention``'s (the two head dims, the
-        rotary dims and theta, a window, a sink, the value scale)."""
+        rotary dims and theta, a window, a sink, the value scale, and
+        ``kv``, another layer's keys and values to read, or
+        ``return_kv``).  With ``return_kv`` it returns the triple (output,
+        k, v)."""
         from .ops.attention import GroupedQueryAttention
 
-        return self._append(GroupedQueryAttention(
-            self, input_tensor, num_heads, num_kv_heads, name=name, **sizes))
+        op = GroupedQueryAttention(self, input_tensor, num_heads,
+                                   num_kv_heads, name=name, **sizes)
+        self.ops.append(op)
+        return tuple(op.outputs) if op.return_kv else op.output
+
+    def mamba(self, input_tensor: Tensor, d_inner: int, d_state: int,
+              d_conv: int, dt_rank: int, memory: bool = False,
+              name: Optional[str] = None):
+        """Mamba's selective state-space mixer (B,S,E)->(B,S,E)
+        (``ops/ssm.py``); with ``memory`` it returns the pair (output,
+        the gated scan output a gated memory unit reads)."""
+        from .ops.ssm import Mamba
+
+        op = Mamba(self, input_tensor, d_inner, d_state, d_conv, dt_rank,
+                   memory, name=name)
+        self.ops.append(op)
+        return tuple(op.outputs) if memory else op.output
+
+    def gated_memory_unit(self, input_tensor: Tensor, memory: Tensor,
+                          name: Optional[str] = None) -> Tensor:
+        """``(memory * silu(x W_1)) W_2`` (B,S,E)->(B,S,E), ``memory`` a
+        Mamba layer's gated scan output (``ops/ssm.py``)."""
+        from .ops.ssm import GatedMemoryUnit
+
+        return self._append(GatedMemoryUnit(self, input_tensor, memory,
+                                            name=name))
+
+    def tied_head(self, input_tensor: Tensor, embedding: Tensor,
+                  name: Optional[str] = None) -> Tensor:
+        """Logits ``x E^T`` over the table of the ``Embedding`` whose
+        output ``embedding`` is (``ops/embedding.TiedHead``)."""
+        from .ops.embedding import TiedHead
+
+        return self._append(TiedHead(self, input_tensor, embedding.owner_op,
+                                     name=name))
 
     def routed_experts(self, input_tensor: Tensor, n_routed_experts: int,
                        num_experts_per_tok: int, expert_width: int,
@@ -1297,6 +1333,11 @@ class FFModel:
         ``_sparse_embed_ok`` covers multi-process."""
         if not (isinstance(op, Embedding) and op.share_from is None
                 and any(op.inputs[0] is t for t in self.input_tensors)):
+            return False
+        # A head tied to the table (``TiedHead``) reads all of its rows,
+        # not the batch's: the table stays whole on the device.
+        if any(o.share_from is op and not isinstance(o, Embedding)
+               for o in self.ops):
             return False
         # Swap-in remaps the index input to the compact row space, so
         # row-sparse execution additionally requires every consumer of

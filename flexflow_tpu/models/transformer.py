@@ -26,11 +26,13 @@ def build_decoder(ff: FFModel, batch_size: int, seq_length: int,
     ``ln1_<i>``, before the MLP as ``ln2_<i>``, before the head as
     ``ln_f``); ``attention(ff, h, i)`` and ``mlp(ff, h, i)`` build layer
     ``i``'s two residual branches, so a model may change either by layer
-    (a leading dense MLP, then experts); ``head(ff, x)`` gives the logits
-    (an untied dense layer today: no op shares an embedding's table with
-    a dense kernel).  With ``learned_positions`` a second input carries
+    (a leading dense MLP, then experts), and may keep what one layer makes
+    for later ones to read; ``head(ff, x)`` gives the logits (a dense
+    layer, or ``ff.tied_head`` over the table of the embedding op
+    ``tok_embed``).  With ``learned_positions`` a second input carries
     0..S-1 per row into a position table added to the token embedding;
-    without, positions are the attention's own business (rotary)."""
+    without, positions are the layers' own business (rotary attention,
+    or a scan)."""
     inputs = tuple(
         ff.create_tensor((batch_size, seq_length), name=name, dtype="int32",
                          nchw=False)
@@ -330,6 +332,110 @@ def build_mimo_v2_flash(ff: FFModel, batch_size: int, seq_length: int = 8192,
         head=lambda ff, x: ff.dense(x, vocab_size, use_bias=False,
                                     name="lm_head"),
         learned_positions=False)
+
+
+def phi4_flash_layer_kinds(num_hidden_layers: int = 32,
+                           mb_per_layer: int = 2):
+    """The mixer of each of ``num_hidden_layers`` layers in SambaY's
+    decoder-hybrid-decoder (arXiv:2507.06607), as Phi-4-mini-flash's
+    config reads: in the self-decoder (i < N/2) a Mamba layer where
+    ``i % mb_per_layer == 0`` and window attention elsewhere; layer N/2 a
+    Mamba layer whose gated scan output is the memory; layer N/2 + 1 full
+    attention whose keys and values are kept; in the cross-decoder a gated
+    memory unit where ``i % mb_per_layer == 0`` and cross-attention
+    elsewhere.  Kinds: ``mamba``, ``window``, ``memory``, ``full``,
+    ``gmu``, ``cross``."""
+    half = num_hidden_layers // 2
+    kinds = []
+    for i in range(num_hidden_layers):
+        even = i % mb_per_layer == 0
+        if i < half:
+            kinds.append("mamba" if even else "window")
+        elif i == half:
+            kinds.append("memory")
+        elif i == half + 1:
+            kinds.append("full")
+        else:
+            kinds.append("gmu" if even else "cross")
+    return kinds
+
+
+def build_phi4_flash(ff: FFModel, batch_size: int, seq_length: int = 8192,
+                     hidden_size: int = 2560, num_hidden_layers: int = 32,
+                     layers=None, intermediate_size: int = 10240,
+                     num_attention_heads: int = 40,
+                     num_key_value_heads: int = 20,
+                     sliding_window: int = 512, mb_per_layer: int = 2,
+                     layer_norm_eps: float = 1e-5, d_state: int = 16,
+                     d_conv: int = 4, expand: int = 2, dt_rank=None,
+                     vocab_size: int = 200064, tie_word_embeddings=True):
+    """Phi-4-mini-flash-reasoning's language model (the keys are those of
+    its public config.json; the Mamba sizes the Mamba family's defaults)
+    for training: LayerNorm with a bias, by ``phi4_flash_layer_kinds``
+    Mamba layers (``ops/ssm.py``; d_inner ``expand * hidden``, dt_rank
+    ``ceil(hidden / 16)``), grouped-query attention within
+    ``sliding_window`` keys or over all of them, gated memory units that
+    read the memory layer's gated scan output, cross-attention that reads
+    the full layer's keys and values; no positional encoding (the Mamba
+    layers carry position); SiLU-gated MLPs without bias; the head tied to
+    the token embedding's table, over the ``vocab_size`` rows held here.
+    ``layers``: the published layer indices built, in order (the defaults
+    are the whole model); a kept gated memory unit or cross-attention
+    layer needs the memory and full layers kept before it.  Returns
+    (tokens_tensor, softmax_output); labels are next-token ids."""
+    import math
+
+    if not tie_word_embeddings:
+        raise ValueError("build_phi4_flash: the head is the token "
+                         "embedding's table (tie_word_embeddings)")
+    kinds = phi4_flash_layer_kinds(num_hidden_layers, mb_per_layer)
+    layers = list(range(num_hidden_layers)) if layers is None \
+        else [int(i) for i in layers]
+    built = [kinds[i] for i in layers]
+    for reader, maker in (("gmu", "memory"), ("cross", "full")):
+        if reader in built and (maker not in built or built.index(maker)
+                                > built.index(reader)):
+            raise ValueError(f"build_phi4_flash: layers {layers} read the "
+                             f"{maker} layer's output without building it "
+                             f"first")
+    d_inner = expand * hidden_size
+    dt_rank = dt_rank or math.ceil(hidden_size / 16)
+    head_dim = hidden_size // num_attention_heads
+    kept = {}
+
+    def mixer(ff, h, i):
+        kind, name = built[i], f"{built[i]}_{i}"
+        if kind in ("mamba", "memory"):
+            out = ff.mamba(h, d_inner, d_state, d_conv, dt_rank,
+                           memory=kind == "memory", name=name)
+            if kind == "memory":
+                out, kept["memory"] = out
+            return out
+        if kind == "gmu":
+            return ff.gated_memory_unit(h, kept["memory"], name=name)
+        sizes = dict(head_dim=head_dim, v_head_dim=head_dim, rotary_dim=0,
+                     window=sliding_window if kind == "window" else None,
+                     return_kv=kind == "full",
+                     kv=kept.get("kv") if kind == "cross" else None)
+        out = ff.grouped_query_attention(h, num_attention_heads,
+                                         num_key_value_heads, name=name,
+                                         **sizes)
+        if kind == "full":
+            out, *kept["kv"] = out
+        return out
+
+    def head(ff, x):
+        embed = next(op for op in ff.ops if op.name == "tok_embed")
+        return ff.tied_head(x, embed.output, name="lm_head")
+
+    return build_decoder(
+        ff, batch_size, seq_length, len(layers), hidden_size, vocab_size,
+        norm=lambda ff, x, name: ff.layer_norm(x, eps=layer_norm_eps,
+                                               name=name),
+        attention=mixer,
+        mlp=lambda ff, h, i: ff.gated_mlp(h, intermediate_size,
+                                          name=f"mlp_{i}"),
+        head=head, learned_positions=False)
 
 
 def synthetic_lm_batch(batch_size: int, seq_length: int, vocab_size: int,

@@ -770,6 +770,14 @@ class GroupedQueryAttention(_PicksAttentionImpl, Op):
     ``W_O`` carries the held heads' rows and the output is this share's
     partial sum.
 
+    ``rotary_dim`` 0 leaves the heads unturned (no positions: a model
+    whose other layers carry them).  ``return_kv``: the op gives its k and
+    v, as the projections make them ((B, S, Hkv * d) and (B, S, Hkv *
+    dv)), as its second and third outputs, for later layers to read.
+    ``kv=(k, v)``: cross-attention, the op's queries against those of
+    another such op, with no key or value projection of its own (the
+    gradients of k and v flow back into the layer that made them).
+
     ``window``: query t sees keys ``t - window + 1 .. t``.  ``sink``: a
     learned logit ``b_i`` a held head (zero at first) in the softmax's
     denominator, with no value: ``p_ts = exp(s_ts) / (exp(b_i) + sum_s'
@@ -796,8 +804,9 @@ class GroupedQueryAttention(_PicksAttentionImpl, Op):
                  rotary_dim: Optional[int] = None, rope_theta: float = 10000.0,
                  window: Optional[int] = None, sink: bool = False,
                  value_scale: float = 1.0, kernel_initializer=None,
+                 return_kv: bool = False, kv=None,
                  name: Optional[str] = None):
-        super().__init__(model, [input_tensor], name)
+        super().__init__(model, [input_tensor] + list(kv or ()), name)
         self.num_heads, self.num_kv_heads = int(num_heads), int(num_kv_heads)
         if self.num_kv_heads < 1 or self.num_heads % self.num_kv_heads:
             raise ValueError(f"{self.name}: {num_heads} query heads held "
@@ -813,14 +822,25 @@ class GroupedQueryAttention(_PicksAttentionImpl, Op):
         self.sink = bool(sink)
         self.value_scale = float(value_scale)
         self.softmax_scale = self.head_dim ** -0.5
+        self.return_kv, self.cross = bool(return_kv), kv is not None
         b, s, e = input_tensor.dims
         h, hkv = self.num_heads, self.num_kv_heads
+        widths = (hkv * self.head_dim, hkv * self.v_dim)
+        if self.cross and tuple(t.dims for t in kv) != tuple(
+                (b, s, w) for w in widths):
+            raise ValueError(f"{self.name}: keys and values "
+                             f"{[t.dims for t in kv]} for {hkv} heads of "
+                             f"{self.head_dim} | {self.v_dim}")
         self._add_output((b, s, e), input_tensor.dtype)
+        if self.return_kv:
+            for w in widths:
+                self._add_output((b, s, w), input_tensor.dtype)
         init = kernel_initializer or DefaultWeightInitializer()
         self._add_weight("w_q", (e, h * self.head_dim), init,
                          partition_dims=(None, 2))
-        self._add_weight("w_k", (e, hkv * self.head_dim), init)
-        self._add_weight("w_v", (e, hkv * self.v_dim), init)
+        if not self.cross:
+            self._add_weight("w_k", (e, widths[0]), init)
+            self._add_weight("w_v", (e, widths[1]), init)
         self._add_weight("w_o", (h * self.v_dim, e), init,
                          partition_dims=(2, None))
         if self.sink:
@@ -843,7 +863,7 @@ class GroupedQueryAttention(_PicksAttentionImpl, Op):
                f"d{self.head_dim}.{self.v_dim}r{self.rotary_dim}")
         if self.window is not None:
             key += f"w{self.window}"
-        return key + ("s" if self.sink else "")
+        return key + ("s" if self.sink else "") + ("x" if self.cross else "")
 
     def forward(self, params, xs: List[jax.Array], ctx: FwdCtx):
         from .linear import project
@@ -854,18 +874,24 @@ class GroupedQueryAttention(_PicksAttentionImpl, Op):
             self.v_dim
         with jax.named_scope("ff.gqa.q_proj"):
             q = project(x, params["w_q"]).reshape(b, s, h, d)
-        with jax.named_scope("ff.gqa.kv_proj"):
-            k = project(x, params["w_k"]).reshape(b, s, hkv, d)
-            # v's scale is linear: it goes on the float32 weight, which
-            # is rounded once either way
-            w_v = params["w_v"] if self.value_scale == 1.0 \
-                else params["w_v"] * self.value_scale
-            v = project(x, w_v).reshape(b, s, hkv, dv)
+        if self.cross:
+            k2, v2 = xs[1], xs[2]
+            k, v = k2.reshape(b, s, hkv, d), v2.reshape(b, s, hkv, dv)
+        else:
+            with jax.named_scope("ff.gqa.kv_proj"):
+                k2 = project(x, params["w_k"])
+                k = k2.reshape(b, s, hkv, d)
+                # v's scale is linear: it goes on the float32 weight,
+                # which is rounded once either way
+                w_v = params["w_v"] if self.value_scale == 1.0 \
+                    else params["w_v"] * self.value_scale
+                v2 = project(x, w_v)
+                v = v2.reshape(b, s, hkv, dv)
         with jax.named_scope("ff.gqa.rope"):
             r = self.rotary_dim
-            turn = lambda t: jnp.concatenate(
+            turn = (lambda t: jnp.concatenate(
                 [rotate_pairs(t[..., :r], self.inv_freq), t[..., r:]],
-                axis=-1)
+                axis=-1)) if r else (lambda t: t)
             heads = lambda t: t.transpose(0, 2, 1, 3)     # (B, H, S, d)
             qh, kh, vh = heads(turn(q)), heads(turn(k)), heads(v)
         impl, why = self._pick_impl(s, s)
@@ -882,7 +908,7 @@ class GroupedQueryAttention(_PicksAttentionImpl, Op):
         with jax.named_scope("ff.gqa.o_proj"):
             out = project(oh.transpose(0, 2, 1, 3).reshape(b, s, h * dv),
                           params["w_o"])
-        return [out]
+        return [out, k2, v2] if self.return_kv else [out]
 
     def decode(self, params, xs, cache, pos, ctx):
         raise NotImplementedError(
@@ -901,7 +927,9 @@ class GroupedQueryAttention(_PicksAttentionImpl, Op):
     def unsplit_cost_per_sample(self):
         """(FLOPs, bytes) a sample of what every head share does whole:
         the key and value projections, and their heads written in
-        bfloat16."""
+        bfloat16 (none for cross-attention, which reads another layer's)."""
+        if self.cross:
+            return 0.0, 0.0
         _, s, e = self.output.dims
         width = self.num_kv_heads * (self.head_dim + self.v_dim)
         return 2.0 * s * e * width, 2.0 * s * width

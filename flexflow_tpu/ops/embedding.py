@@ -195,3 +195,45 @@ class Embedding(Op):
     def flops_per_sample(self):
         n_idx = self.inputs[0].dims[1] if len(self.inputs[0].dims) > 1 else 1
         return float(n_idx * self.out_dim)
+
+
+class TiedHead(Op):
+    """The output head of a model whose head is its token embedding's
+    table, tied: ``logits = x E^T`` over (..., E), ``E`` (rows, E) the
+    table of ``embedding`` (an ``Embedding`` with a table of its own).
+    The op holds no weight: it reads the embedding's parameters
+    (``share_from``), so the table's gradient is the sum of the lookup's
+    and the head's.  The product is ``linear.project``'s, under the scope
+    ``ff.tied_head`` (outside ``ff.embed.``, which means the lookup and its
+    gradient).  Only the batch splits: the table is the embedding's, laid
+    out as its strategy says."""
+
+    _type = "TiedHead"
+
+    def __init__(self, model, input_tensor, embedding: Embedding,
+                 name: Optional[str] = None):
+        super().__init__(model, [input_tensor], name)
+        owner = embedding.share_from or embedding
+        if not isinstance(owner, Embedding) \
+                or owner.out_dim != input_tensor.dims[-1]:
+            raise ValueError(f"{self.name}: ties a head of width "
+                             f"{input_tensor.dims[-1]} to {owner!r}")
+        self.share_from = owner
+        self.num_entries = owner.num_entries
+        self._add_output(input_tensor.dims[:-1] + (owner.num_entries,),
+                         input_tensor.dtype)
+
+    def _config_dim_bound(self, i: int):
+        return super()._config_dim_bound(i) if i == 0 else 1
+
+    constraint_pc = Op.batch_only_pc
+
+    def forward(self, params, xs: List[jax.Array], ctx: FwdCtx):
+        from .linear import project
+
+        with jax.named_scope("ff.tied_head"):
+            return [project(xs[0], params["weight"].T)]
+
+    def flops_per_sample(self):
+        tokens = math.prod(self.output.dims[1:-1])
+        return 2.0 * tokens * self.inputs[0].dims[-1] * self.num_entries

@@ -319,7 +319,9 @@ def counters() -> Dict[str, float]:
     window layers that have a sink (``GroupedQueryAttention``) has
     drained, ``swa_sink_share``: the softmax's mass the sink took, the
     mean over those layers, their heads and queries, and every drained
-    step."""
+    step.  Where a model with Mamba layers (``ops/ssm.py``) has drained,
+    ``ssm_dt_mean``: the scan's step size Delta, the mean over those
+    layers, their tokens and channels, and every drained step."""
     with _lock:
         out = dict(_counters)
     for name, source in _TRAIN_STEP.items():
@@ -328,6 +330,8 @@ def counters() -> Dict[str, float]:
         out["dsa_index_kl"] /= out["dsa_layers"]
     if out.get("swa_layers"):
         out["swa_sink_share"] /= out["swa_layers"]
+    if out.get("ssm_layers"):
+        out["ssm_dt_mean"] /= out["ssm_layers"]
     tokens = out.get("moe_tokens")
     if tokens:
         made, kept = out["moe_assignments_made"], out["moe_assignments_kept"]

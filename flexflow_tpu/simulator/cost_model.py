@@ -394,6 +394,11 @@ class CostModel:
         eff = m.op_efficiency.get(fam, m.mxu_efficiency)
         t = max(flops / (m.peak_flops * eff),
                 bytes_moved / m.hbm_bandwidth) + m.kernel_launch_overhead
+        vector = getattr(op, "vector_cost_per_sample", None)
+        if vector is not None:
+            # element work that no product carries (a scan's recurrence,
+            # one token after another): the VPU's, after the MXU's
+            t += vector() * sub[0] / m.vpu_ops_per_s
         if which == "backward":
             # dgrad + wgrad (fitted per family where measured; default 2×)
             t *= m.op_backward_multiplier.get(fam, m.backward_multiplier)

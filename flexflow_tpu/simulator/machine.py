@@ -63,6 +63,11 @@ class TPUMachineModel:
     kernel_launch_overhead: float = 2e-6   # s; XLA per-fused-region overhead
     mxu_efficiency: float = 0.45      # achievable fraction of peak for convs/matmuls
     backward_multiplier: float = 2.0  # bwd ≈ dgrad + wgrad vs one fwd
+    # float32 element operations a second on the VPU and EUP, for work
+    # that runs there and not on the MXU (Mamba's scan: ops/ssm.py): the
+    # scan's forward kernel, 7 a token, channel and state, measured on a
+    # v5e at 8192 x 5120 x 16 (4.7 G in 1.38 ms; PERF.md section 6)
+    vpu_ops_per_s: float = 3.4e12
     # Host tier (row-sparse host-resident embeddings, reference hetero
     # ZCM placement): chip<->host PCIe and host DDR stream bandwidth.
     pcie_bandwidth: float = 32e9      # bytes/s per direction (gen4 x16)

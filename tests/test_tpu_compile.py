@@ -1,13 +1,14 @@
-"""The kernels of the DeepSeek-V2, dots3 and MiMo-V2-Flash cells at their real
-widths, compiled for a v5e that is described and not attached: what the
-chip's compiler refuses (a block Mosaic cannot tile, too much VMEM) costs no
-chip time.  Nothing runs, so nothing here is a result or a time.  The
-topology is described
-inside a fixture, in this one file, so that only the worker that runs
-these tests loads the TPU's library."""
+"""The kernels of the DeepSeek-V2, dots3, MiMo-V2-Flash and Phi-4-mini-flash
+cells at their real widths, compiled for a v5e that is described and not
+attached: what the chip's compiler refuses (a block Mosaic cannot tile,
+too much VMEM) costs no chip time.  Nothing runs, so nothing here is a
+result or a time.  The topology is described inside a fixture, in this
+one file, so that only the worker that runs these tests loads the TPU's
+library."""
 
 import base64
 import hashlib
+import math
 import os
 import re
 
@@ -19,6 +20,7 @@ from jax.sharding import SingleDeviceSharding
 from flexflow_tpu.kernels.dsa_index import index_scores
 from flexflow_tpu.kernels.flash_attention import flash_attention, tiling
 from flexflow_tpu.kernels.grouped_matmul import grouped_matmul
+from flexflow_tpu.kernels.ssm_scan import selective_scan
 
 
 @pytest.fixture(scope="module")
@@ -227,3 +229,46 @@ def test_grouped_products_at_expert_widths(one_chip, k, n):
     compiled = jax.jit(jax.value_and_grad(f, argnums=(0, 1))).lower(
         x, w, tg).compile()
     assert _custom_calls(compiled) >= 3        # gmm, gmm_t, tgmm
+
+
+def test_scan_kernels_at_the_mamba_layers_widths(one_chip):
+    """Phi-4-mini-flash's Mamba layers: 1 sequence of 8192 tokens, 5120
+    channels, a state of 16, x and Delta in bfloat16; forward and
+    backward.  No buffer holds a state a token: nothing of 8192 x 5120 x
+    16 elements or more, in any shape."""
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def f(x, dt, a, b, c):
+        return jnp.sum(selective_scan(x, dt, a, b, c).astype(jnp.float32))
+    compiled = jax.jit(jax.grad(f, argnums=range(5))).lower(
+        spec(1, 8192, 5120), spec(1, 8192, 5120),
+        spec(5120, 16, dtype=jnp.float32), spec(1, 8192, 16,
+                                                dtype=jnp.float32),
+        spec(1, 8192, 16, dtype=jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "ssm_scan_fwd" in text and "ssm_scan_bwd" in text
+    sizes = [math.prod(int(d) for d in dims.split(",") if d)
+             for dims in re.findall(r"[a-z0-9]+\[([0-9,]*)\]", text)]
+    assert max(sizes) < 8192 * 5120 * 16
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+@pytest.mark.parametrize("window,names", [
+    (None, ("flash_fwd", "flash_dq", "flash_dkv")),
+    (512, ("flash_win_fwd", "flash_win_dq", "flash_win_dkv"))])
+def test_grouped_kernels_at_the_phi4_flash_heads(one_chip, window, names):
+    """Phi-4-mini-flash's attention: 40 query heads of 64 over 20
+    key/value heads, 1 sequence of 8192, causal (the full and cross
+    layers) or within a band of 512 keys (the window layers)."""
+    def spec(heads):
+        return jax.ShapeDtypeStruct((1, heads, 8192, 64), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def f(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, scale=0.125,
+                                       window=window).astype(jnp.float32))
+    text = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(
+        spec(40), spec(20), spec(20)).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert all(n in text for n in names)
