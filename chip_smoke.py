@@ -17,7 +17,10 @@ out by the repo's own means:
                    samples/s for the reader, not a claim.
   kernels          kernels/flash_attention.py against mha_reference on
                    the chip, forward and gradients, causal, bf16 and f32;
-                   its line says which tiling ran at each shape.
+                   its line says which tiling ran at each shape, and
+                   what the selective scan's kernels do with the Mamba
+                   layers of Phi-4-mini-flash (the MXU's rows of lanes
+                   a chunk for dB / dC among them).
   transformer      the bench transformer (4 layers x 512, 8 heads of 64,
                    seq 512, batch 16, bf16) for three train steps with
                    its attention in the compiled Pallas kernels (the
@@ -290,6 +293,13 @@ def phase_kernels(sz, dev, stats):
     for shape in ((t, h, d), (8192, 64, 128)):
         tiles["index/" + "x".join(map(str, shape))] = said(
             dsa_index.tiling(*shape))
+    # the selective scan at phi4flash's Mamba layers: 8192 tokens, 5120
+    # channels, a state of 16
+    from flexflow_tpu.kernels import ssm_scan
+    tiles["scan/8192x5120x16"] = (
+        "{channels_per_block}x{chunk} {grid_steps} steps, lane sums "
+        "{lane_sum_rows} rows a chunk".format(
+            **ssm_scan.tiling(8192, 5120, 16)))
     result("kernels", compiled=not interpret, tolerance=KERNEL_TOL,
            max_normalized_error=worst, tiling=tiles)
 

@@ -29,10 +29,14 @@ and backward its adjoint and dA, stay in VMEM across a block's chunks.
 
 The backward's sums over channels, dB_t = sum_d g_t B-part and dC_t =
 sum_d s_t dy_t, are products a token, a state and a vreg that go to VMEM
-scratch; after a chunk one product with a row of ones on the MXU, at
-``Precision.HIGHEST`` (float32 in three bfloat16 terms, exact for the ones),
-sums each over its lanes and turns the sums into one lane-dense row, which
-the caller sums over the sublanes and lane blocks.
+scratch.  After a chunk, loads strided over the sublanes sum each product
+over its 8 sublanes into one row of lanes (a load strided by 4 or 2
+sublanes is one load; by 8 it is two and a merge), and one product of
+those rows with a row of ones on the MXU, at ``Precision.HIGHEST``, sums
+each over its lanes into one lane-dense row, which the caller sums over
+the lane blocks.  The MXU takes those rows as its weights, six bfloat16
+passes over them, one row a token and product: 4096 a chunk at a state
+of 16 (``tiling()``'s ``lane_sum_rows``).
 
 Arithmetic in float32 throughout; x, delta, y, dy and their gradients in
 the caller's dtype in HBM.  Work: one exponential a token, channel and
@@ -94,18 +98,21 @@ def unsupported_reason(seq: int, d_inner: int) -> Optional[str]:
 def tiling(seq: int, d_inner: int, d_state: int) -> Dict[str, int]:
     """What the kernels do with one sequence of this shape: the lanes and
     channels of a block, the tokens of a chunk, the grid's steps, the
-    chunk-start states the forward writes for the backward (bytes), and
-    the VMEM the backward's scratch takes."""
+    chunk-start states the forward writes for the backward (bytes), the
+    VMEM the backward's scratch takes, and the rows of lanes a chunk of
+    the backward pushes through the MXU for the lane sums of dB / dC."""
     width = d_inner // SUBLANES
     lanes = _lanes(width)
     vreg = SUBLANES * lanes * 4
     chunk = _CHUNK
+    products = chunk * 2 * d_state
     return dict(
         chunk=chunk, lanes=lanes, channels_per_block=SUBLANES * lanes,
         grid_steps=(width // lanes) * (seq // chunk),
         saved_state_bytes=(seq // chunk) * d_state * d_inner * 4,
-        bwd_scratch_bytes=vreg * ((chunk + 1) * d_state
-                                  + chunk * 2 * d_state + d_state))
+        bwd_scratch_bytes=vreg * ((chunk + 1) * d_state + products
+                                  + products // 4 + d_state),
+        lane_sum_rows=products)
 
 
 # ---------------------------------------------------------------------------
@@ -199,8 +206,8 @@ def _fwd_kernel(x_ref, dt_ref, a_ref, bc_ref, y_ref, h_ref, s_scr, *,
 
 
 def _bwd_kernel(x_ref, dt_ref, a_ref, bc_ref, dy_ref, h_ref,
-                dx_ref, ddt_ref, da_ref, dbc_ref, s_scr, p_scr, ds_scr, *,
-                chunk, n_state):
+                dx_ref, ddt_ref, da_ref, dbc_ref, s_scr, p_scr, h_scr, ds_scr,
+                *, chunk, n_state):
     @pl.when(pl.program_id(2) == 0)
     def _start():
         ds_scr[...] = jnp.zeros_like(ds_scr)
@@ -234,8 +241,9 @@ def _bwd_kernel(x_ref, dt_ref, a_ref, bc_ref, dy_ref, h_ref,
         for n in range(n_state):
             a = a_ref[n]
             g = ds[n] + dy * bc_ref[base + n_state + n]     # dL / ds_t
-            p_scr[t, n] = g * dx                            # -> dB_t
-            p_scr[t, n_state + n] = s_scr[t + 1, n] * dy    # -> dC_t
+            p_scr[t, pl.ds(n * SUBLANES, SUBLANES)] = g * dx  # -> dB_t
+            p_scr[t, pl.ds((n_state + n) * SUBLANES, SUBLANES)] = (
+                s_scr[t + 1, n] * dy)                           # -> dC_t
             ddx = ddx + g * bc_ref[base + n]
             decay = jnp.exp(dt * a)
             w = g * s_scr[t, n] * decay                     # dL / d(dt a)
@@ -249,10 +257,17 @@ def _bwd_kernel(x_ref, dt_ref, a_ref, bc_ref, dy_ref, h_ref,
     ds = _loop(chunk, backward, tuple(ds_scr[n] for n in range(n_state)))
     for n in range(n_state):
         ds_scr[n] = ds[n]
-    lanes = p_scr.shape[-1]
-    rows = p_scr[...].reshape(chunk * 2 * n_state * SUBLANES, lanes)
-    sums = lax.dot_general(jnp.ones((SUBLANES, lanes), jnp.float32), rows,
-                           _NT, precision=lax.Precision.HIGHEST,
+    # A token's products are rows 8k .. 8k+7 of p_scr[t].  Loads strided
+    # by 4 at offsets 0-3 sum each product's two halves of 4 sublanes into
+    # rows 2k and 2k+1 of h_scr[t]; loads strided by 2 add the halves.
+    per = 2 * n_state
+    parts = [p_scr[:, pl.ds(r, 2 * per, stride=4)] for r in range(4)]
+    h_scr[...] = (parts[0] + parts[1]) + (parts[2] + parts[3])
+    rows = (h_scr[:, pl.ds(0, per, stride=2)]
+            + h_scr[:, pl.ds(1, per, stride=2)])
+    sums = lax.dot_general(jnp.ones((SUBLANES, rows.shape[-1]), jnp.float32),
+                           rows.reshape(chunk * per, -1), _NT,
+                           precision=lax.Precision.HIGHEST,
                            preferred_element_type=jnp.float32)
     dbc_ref[...] = sums[0:1]
 
@@ -302,7 +317,7 @@ def _bwd_call(x4, dt4, a3, bc, dy4, h, chunk, interpret):
     lanes = _lanes(width)
     nc = t // chunk
     seq, a, bcs, state = _specs(chunk, lanes, n_state, reverse=True, nc=nc)
-    row = chunk * 2 * n_state * SUBLANES
+    row = chunk * 2 * n_state
     return pl.pallas_call(
         functools.partial(_bwd_kernel, chunk=chunk, n_state=n_state),
         grid=(bsz, width // lanes, nc),
@@ -320,7 +335,8 @@ def _bwd_call(x4, dt4, a3, bc, dy4, h, chunk, interpret):
                                          // chunk), jnp.float32)],
         scratch_shapes=[
             pltpu.VMEM((chunk + 1, n_state, SUBLANES, lanes), jnp.float32),
-            pltpu.VMEM((chunk, 2 * n_state, SUBLANES, lanes), jnp.float32),
+            pltpu.VMEM((chunk, 2 * n_state * SUBLANES, lanes), jnp.float32),
+            pltpu.VMEM((chunk, 4 * n_state, lanes), jnp.float32),
             pltpu.VMEM((n_state, SUBLANES, lanes), jnp.float32)],
         interpret=interpret,
         name=KERNELS[1],
@@ -386,8 +402,7 @@ def _scan_bwd(chunk, interpret, res, dy):
             *_operands(x, delta, a, b, c), _tokens(dy.astype(x.dtype)), h,
             chunk, interpret)
         da = jnp.sum(da3, axis=0).reshape(n_state, -1).T
-        dbc = jnp.sum(dbc.reshape(bsz, -1, t, 2 * n_state, SUBLANES),
-                      axis=(1, 4))
+        dbc = jnp.sum(dbc.reshape(bsz, -1, t, 2 * n_state), axis=1)
     return (_channels(dx4), _channels(ddt4).astype(delta.dtype),
             da.astype(a.dtype), dbc[..., :n_state].astype(b.dtype),
             dbc[..., n_state:].astype(c.dtype))

@@ -273,6 +273,7 @@ def _scan_inputs(seed, t, d, n, dtype=jnp.float32):
     ("chunked", 48, 2048, 4, 16),
     ("chunked", 64, 128, 4, 16),    # four chunks
     ("chunked", 32, 1024, 16, 8),   # the published state, four chunks
+    ("kernels", 32, 1024, 16, 8),
 ])
 def test_scan_against_the_sequential_scan(form, t, d, n, chunk):
     """y and the gradients of x, Delta, A, B and C, the kernels in the
@@ -308,10 +309,50 @@ def test_scan_kernels_keep_the_caller_s_dtypes():
     assert _rel(y.astype(jnp.float32), want.astype(jnp.float32)) < 1e-2
 
 
+def test_scan_backward_sums_every_bit_of_the_products():
+    """dB and dC to the last bit of a float32: A = 0 and Delta = 1, B one
+    at the first token and C at the last, the cotangent a sign, so that
+    dB_t = sum_d dy_{T-1} x_t and dC_t = sum_d x_0 dy_t; x is zero but in
+    8 channels a token, spread over the sublanes, lanes and both blocks of
+    1024, where it holds values such as 1 + 2^-9 + 2^-17 + 2^-20 with
+    either sign.  Every product and every partial sum of eight is a
+    float32 (multiples of 2^-20 under 16), so the kernels in the
+    interpreter (two chunks) must give the float64 sums to 2e-7 of the
+    products' magnitude; the two leading bfloat16 terms of each product
+    alone miss them by some 1e-6."""
+    t, d, n = 16, 2048, 4
+    rng = np.random.default_rng(43)
+    values = np.array([1 + 2 ** -9 + 2 ** -17 + 2 ** -20,
+                       1.5 + 2 ** -10 + 2 ** -18 + 2 ** -19,
+                       1.75 + 2 ** -9 + 2 ** -17 + 2 ** -20,
+                       0.5 + 2 ** -10 + 2 ** -18 + 2 ** -20,
+                       1.25 + 2 ** -8 + 2 ** -16 + 2 ** -19,
+                       0.75 + 2 ** -11 + 2 ** -19 + 2 ** -20])
+    x = np.zeros((1, t, d))
+    for i in range(t):
+        x[0, i, rng.choice(d, 8, replace=False)] = (
+            rng.choice(values, 8) * np.where(rng.random(8) < 0.75, 1, -1))
+    dy = np.where(rng.random((1, t, d)) < 0.5, 1.0, -1.0)
+    b = np.zeros((1, t, n))
+    b[:, 0] = 1
+    c = np.zeros((1, t, n))
+    c[:, -1] = 1
+    args = [jnp.asarray(v, jnp.float32) for v in
+            (x, np.ones((1, t, d)), np.zeros((d, n)), b, c)]
+    _, vjp = jax.vjp(lambda *v: ssm_scan.selective_scan(*v, 8, True), *args)
+    _, _, _, db, dc = vjp(jnp.asarray(dy, jnp.float32))
+    for got, products in ((db, dy[0, -1] * x[0]), (dc, x[0, 0] * dy[0])):
+        got = np.asarray(got, np.float64)[0]
+        err = np.abs(got - products.sum(-1)[:, None])
+        assert (err <= 2e-7 * np.abs(products).sum(-1)[:, None]).all()
+
+
 def test_scan_tiling_and_refusals():
     """The cell's shape: blocks of 1024 channels, chunks of 128 tokens, 5
     x 64 grid steps; the chunk-start states a layer keeps are T / 128 x
-    5120 x 16 float32, where every token's would be 2.7 GB."""
+    5120 x 16 float32, where every token's would be 2.7 GB; a chunk of the
+    backward pushes one row of lanes a token and product through the MXU
+    for the lane sums of dB / dC, 128 x 32, and not the product's 8."""
     tile = ssm_scan.tiling(8192, 5120, 16)
     assert (tile["lanes"], tile["channels_per_block"], tile["chunk"]) == (
         128, 1024, 128)
@@ -319,6 +360,7 @@ def test_scan_tiling_and_refusals():
     assert tile["saved_state_bytes"] == 64 * 5120 * 16 * 4
     assert tile["saved_state_bytes"] * 128 == 8192 * 5120 * 16 * 4
     assert tile["bwd_scratch_bytes"] < 64 * 2 ** 20
+    assert tile["lane_sum_rows"] == 128 * 2 * 16 == 4096
     assert ssm_scan.unsupported_reason(8192, 5120) is None
     assert "channels" in ssm_scan.unsupported_reason(8192, 2560)
     assert "chunks" in ssm_scan.unsupported_reason(8200, 5120)
