@@ -251,8 +251,21 @@ def route(scores, *, top_k: int, n_group: int, topk_group: int, first: int,
     padding; differentiable in ``scores``), ``slot_row`` (tokens, top_k)
     the buffer row of each of a token's choices (M, past the last, where
     it is not here or was dropped), ``tile_group`` (M / tile_rows,) the
-    expert of each tile, and the counters ``made``, ``kept``, ``sizes``
-    (held,)."""
+    expert of each tile, ``compact_rows`` and ``token_position``, the
+    same choices compacted for ``collect_rows`` (below), and the counters
+    ``made``, ``kept``, ``with_choice`` (tokens with a kept choice),
+    ``sizes`` (held,).
+
+    The compaction: a token with r kept choices uses r of the budget's
+    rows, so at most ``B_r = min(tokens, budget // r)`` tokens keep r or
+    more.  With the tokens ordered by their number of kept choices (most
+    first, then by token), those with r or more are the first ``B_r`` of
+    that order, so the places ``B_(r+1)`` .. ``B_r`` hold tokens with r
+    kept choices or fewer.  ``compact_rows`` holds, for each such span
+    that is not empty, from the first place on, a block (places, r) of
+    the buffer rows of its tokens' kept choices in top_k's order, M past
+    a token's last; places from ``B_1`` on hold tokens with none.
+    ``token_position`` (tokens,) is each token's place."""
     t, e = scores.shape
     if n_group > 1:
         group_score = scores.reshape(t, n_group, e // n_group).max(axis=-1)
@@ -313,47 +326,65 @@ def route(scores, *, top_k: int, n_group: int, topk_group: int, first: int,
     slot_row = jnp.where(
         kept_slot, jnp.take_along_axis(first_row, jnp.clip(local, 0, held - 1),
                                        axis=1), num_tiles * tile_rows)
+    count = jnp.sum(kept_slot, axis=1)
+    ranks = min(top_k, held, budget)
+    by_count = jnp.argsort((ranks - count) * t + jnp.arange(t))
+    # a token's kept rows first, in top_k's order (the sort is stable)
+    _, front = jax.lax.sort((jnp.where(kept_slot, 0, 1), slot_row),
+                            dimension=1, num_keys=1, is_stable=True)
+    compact = front[by_count[:min(t, budget)], :ranks]
+    ends = [min(t, budget // r) for r in range(1, ranks + 1)] + [0]
+    compact_rows = tuple(compact[ends[r]:ends[r - 1], :r]
+                         for r in range(ranks, 0, -1) if ends[r] < ends[r - 1])
     return {"row_token": jnp.where(filled, token[src], t),
             "row_weight": jnp.where(filled, kept_s[src], 0.0),
             "slot_row": slot_row, "tile_group": tile_group,
-            "made": jnp.sum(here), "kept": jnp.sum(valid), "sizes": sizes}
+            "compact_rows": compact_rows,
+            "token_position": jnp.argsort(by_count),
+            "made": jnp.sum(here), "kept": jnp.sum(valid),
+            "with_choice": jnp.sum(count > 0), "sizes": sizes}
 
 
 def _take_rows(src, idx):
     """src[idx] along axis 0, zeros where idx is past the last row."""
-    return jnp.take(src, idx, axis=0, mode="fill", fill_value=0)
+    return src.at[idx].get(mode="fill", fill_value=0)
 
 
 @jax.custom_vjp
-def spread_rows(x, row_token, slot_row):
+def spread_rows(x, row_token, compact_rows, token_position):
     """The buffer's rows from the tokens': row p is ``x[row_token[p]]``
     (zeros for padding).  Its transpose sums a token's rows back, which
-    ``collect_rows`` does as a gather by ``slot_row``, where JAX's own
-    transpose would scatter-add row by row."""
+    ``collect_rows`` does by gathers, where JAX's own transpose would
+    scatter-add row by row."""
     return _take_rows(x, row_token)
 
 
 @jax.custom_vjp
-def collect_rows(rows, row_token, slot_row):
-    """The tokens' sums of their buffer rows: token t gets the sum over
-    its choices j of ``rows[slot_row[t, j]]`` (nothing for a choice that
-    is not here), accumulated in float32.  Transpose: ``spread_rows``.
-    A gather a choice, added up: on the v5e 1.6 ms at 8192 x 6 x 5120
-    where one gather of all choices and a sum took 3.8 (PERF.md, PR 32)."""
-    acc = jnp.zeros((slot_row.shape[0], rows.shape[1]), jnp.float32)
-    for j in range(slot_row.shape[1]):
-        acc = acc + _take_rows(rows, slot_row[:, j]).astype(jnp.float32)
-    return acc.astype(rows.dtype)
+def collect_rows(rows, row_token, compact_rows, token_position):
+    """The tokens' sums of their buffer rows, in float32 and in top_k's
+    order, over ``route``'s compaction: one gather a block of
+    ``compact_rows``, its columns added in order, then each token reads
+    its sum, cast to the rows' dtype, at ``token_position`` (zeros past
+    the blocks).  Some ``budget`` times the harmonic number of top_k row
+    gathers and one of the tokens, where a gather a choice over every
+    token took tokens times top_k (PERF.md section 6).  Transpose:
+    ``spread_rows``."""
+    parts = []
+    for block in compact_rows:
+        got = _take_rows(rows, block).astype(jnp.float32)  # (places, r, width)
+        acc = got[:, 0]
+        for j in range(1, block.shape[1]):
+            acc = acc + got[:, j]
+        parts.append(acc.astype(rows.dtype))
+    return _take_rows(jnp.concatenate(parts), token_position)
 
 
 spread_rows.defvjp(
-    lambda x, row_token, slot_row: (spread_rows(x, row_token, slot_row),
-                                    (row_token, slot_row)),
-    lambda res, g: (collect_rows(g, *res), None, None))
+    lambda x, *index: (spread_rows(x, *index), index),
+    lambda index, g: (collect_rows(g, *index), None, None, None))
 collect_rows.defvjp(
-    lambda rows, row_token, slot_row: (collect_rows(rows, row_token, slot_row),
-                                       (row_token, slot_row)),
-    lambda res, g: (spread_rows(g, *res), None, None))
+    lambda rows, *index: (collect_rows(rows, *index), index),
+    lambda index, g: (spread_rows(g, *index), None, None, None))
 
 
 class RoutedExperts(_ExpertDim, Op):
@@ -376,12 +407,13 @@ class RoutedExperts(_ExpertDim, Op):
     Config dim 1 is the expert-parallel degree over the held experts, as
     ``ExpertMLP`` declares it; the output is placed by the batch degree.
     Counters (``ctx.counters``, summed into the step's metric vector):
-    assignments made and kept, tokens, and the held experts' largest
-    load over their mean load."""
+    assignments made and kept, tokens, the held experts' largest load
+    over their mean load, and tokens with a kept choice here."""
 
     _type = "RoutedExperts"
     COUNTERS = ("moe_assignments_made", "moe_assignments_kept", "moe_tokens",
-                "moe_load_max_over_mean", "moe_layers")
+                "moe_load_max_over_mean", "moe_layers",
+                "moe_tokens_with_choice")
 
     def __init__(self, model, input_tensor, n_routed_experts: int,
                  num_experts_per_tok: int, expert_width: int,
@@ -488,14 +520,16 @@ class RoutedExperts(_ExpertDim, Op):
             sizes = r["sizes"].astype(jnp.float32)
             for name, value in zip(self.COUNTERS, (
                     r["made"], r["kept"], tokens,
-                    jnp.max(sizes) / jnp.maximum(jnp.mean(sizes), 1e-9), 1)):
+                    jnp.max(sizes) / jnp.maximum(jnp.mean(sizes), 1e-9), 1,
+                    r["with_choice"])):
                 ctx.counters[name] = ctx.counters.get(name, 0.0) \
                     + jnp.float32(value)
+        index = (r["row_token"], r["compact_rows"], r["token_position"])
         product = functools.partial(grouped_matmul, tile_group=r["tile_group"],
                                     tile_m=self.tile_rows,
                                     impl=self._grouped_impl())
         with jax.named_scope("ff.moe.dispatch"):
-            rows = spread_rows(xf, r["row_token"], r["slot_row"])
+            rows = spread_rows(xf, *index)
         with jax.named_scope("ff.moe.experts"):
             mid = jax.nn.silu(product(rows, params["w_gate"])) \
                 * product(rows, params["w_up"])
@@ -503,7 +537,7 @@ class RoutedExperts(_ExpertDim, Op):
         with jax.named_scope("ff.moe.combine"):
             weight = (r["row_weight"] * self.scaling)[:, None]
             y = collect_rows((y_rows.astype(jnp.float32) * weight).astype(dt),
-                             r["row_token"], r["slot_row"])
+                             *index)
         if self.n_shared:
             with jax.named_scope("ff.moe.shared"):
                 y = y + gated_mlp(xf, params["shared_gate"],

@@ -25,11 +25,11 @@ Reference instrumentation (SURVEY §5.1): per-op cudaEvent timers behind
     are put down to the phase they fired in by the package's one pair
     of ``jax.monitoring`` listeners,
   * ``counters()`` — process-wide counters: set-up by phase and stage,
-    how often the train step
-    was compiled, and for how long, and what the graph's ops counted in
-    their steps (``Op.COUNTERS``: the routed experts' assignments made,
-    kept and dropped, and their load), which arrive with the metric
-    drain (``count``),
+    how often the train step was compiled, and for how long, and what
+    the graph's ops counted in their steps (``Op.COUNTERS``: the routed
+    experts' assignments made, kept and dropped, the tokens with a kept
+    choice, and their load), which arrive with the metric drain
+    (``count``),
   * ``op_profile(model)`` — per-op forward/backward wall times, measured
     by compiling and timing each op standalone on the real device, the
     way the reference's ``measure_compute_time`` does per-op benchmarks;
@@ -310,8 +310,10 @@ def counters() -> Dict[str, float]:
     them over all drained steps: ``moe_assignments_made_per_token`` and
     ``moe_assignments_kept_per_token`` (a token and expert layer),
     ``moe_dropped_share`` (of the assignments made, those the device
-    budget dropped) and ``moe_load_max_over_mean`` (the held experts'
-    largest kept load over their mean, averaged over layers and steps).
+    budget dropped), ``moe_tokens_with_choice_share`` (of the tokens,
+    those with a kept choice here: how full the combine's compact sums
+    are) and ``moe_load_max_over_mean`` (the held experts' largest kept
+    load over their mean, averaged over layers and steps).
     Where a model with a learned index (``ops/dsa.py``) has drained,
     ``dsa_index_kl``: the index's objective ``L_I``, the mean over the
     indexed layers and every drained step (the layers summed a term each
@@ -338,6 +340,8 @@ def counters() -> Dict[str, float]:
         out["moe_assignments_made_per_token"] = made / tokens
         out["moe_assignments_kept_per_token"] = kept / tokens
         out["moe_dropped_share"] = (made - kept) / made if made else 0.0
+        out["moe_tokens_with_choice_share"] = \
+            out["moe_tokens_with_choice"] / tokens
         out["moe_load_max_over_mean"] /= out["moe_layers"]
     return out
 

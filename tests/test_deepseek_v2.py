@@ -7,6 +7,7 @@ the chip's share against the uncut layer, the budget, the rotary
 frequencies, data parallelism, and the benchmark's formulas against hand
 counts."""
 
+import functools
 import importlib.util
 import json
 import math
@@ -278,6 +279,220 @@ def test_budget_breaks_ties_by_token_then_expert():
     np.testing.assert_array_equal(
         kept, np.asarray(ref.kept_assignments(s, 3, 4, 2, 4, 4, 5 / 6)))
     assert kept[0, :3].all() and kept[1, :2].all() and kept.sum() == 5
+
+
+# The combine as it was until the compaction: a gather of every token a
+# choice, added up in float32 in top_k's order.  The compacted form must
+# give the same numbers, element for element, forward and backward.
+def _by_choice(rows, slot_row):
+    acc = jnp.zeros((slot_row.shape[0], rows.shape[1]), jnp.float32)
+    for j in range(slot_row.shape[1]):
+        acc = acc + jnp.take(rows, slot_row[:, j], axis=0, mode="fill",
+                             fill_value=0).astype(jnp.float32)
+    return acc.astype(rows.dtype)
+
+
+@jax.custom_vjp
+def _spread_by_choice(x, row_token, slot_row):
+    return jnp.take(x, row_token, axis=0, mode="fill", fill_value=0)
+
+
+@jax.custom_vjp
+def _collect_by_choice(rows, row_token, slot_row):
+    return _by_choice(rows, slot_row)
+
+
+_spread_by_choice.defvjp(
+    lambda x, *index: (_spread_by_choice(x, *index), index),
+    lambda index, g: (_collect_by_choice(g, *index), None, None))
+_collect_by_choice.defvjp(
+    lambda rows, *index: (_collect_by_choice(rows, *index), index),
+    lambda index, g: (_spread_by_choice(g, *index), None, None))
+
+
+def _logits(seed, tokens=64, experts=16):
+    return 2.0 * jax.random.normal(jax.random.key(seed), (tokens, experts),
+                                   jnp.float32)
+
+
+def _all_on_held(tokens, per_token):
+    """Softmax scores under which the first ``tokens`` of 64 put
+    ``per_token`` of their three choices on experts 4..7 (group 1) and
+    the other two on experts 0 and 1 (group 0), and the rest choose none
+    of 4..7."""
+    z = _logits(9)
+    z = z.at[:, 4:8].add(-20.0)
+    z = z.at[:tokens, 4:4 + per_token].add(30.0)
+    z = z.at[:tokens, :3 - per_token].add(25.0)
+    return jax.nn.softmax(z, axis=-1)
+
+
+# (routing keywords, scores, budget): softmax with groups (DeepSeek-V2)
+# and sigmoid with a selection bias and normalised weights (dots3,
+# MiMo-V2), under a budget that drops and one that pads; the first 16
+# tokens putting all three choices here under a budget of 48, so that
+# the rank-3 prefix (48 // 3 rows) is exactly full; 48 tokens of one
+# choice each under the same budget, so that the rank-1 prefix is full.
+SOFTMAX = dict(n_group=4, topk_group=2)
+SIGMOID = dict(n_group=1, topk_group=1, norm_topk_prob=True)
+ROUTES = {
+    "softmax-groups-drops": (SOFTMAX, lambda: _scores(5), 24),
+    "softmax-groups-pads": (SOFTMAX, lambda: _scores(5), 144),
+    "sigmoid-bias-norm-drops": (
+        SIGMOID, lambda: jax.nn.sigmoid(_logits(6)), 24),
+    "sigmoid-bias-norm-pads": (
+        SIGMOID, lambda: jax.nn.sigmoid(_logits(6)), 144),
+    "full-top-rank": (SOFTMAX, lambda: _all_on_held(16, 3), 48),
+    "full-first-rank": (SOFTMAX, lambda: _all_on_held(48, 1), 48),
+}
+
+
+def _route_case(which, **over):
+    kw, scores, budget = ROUTES[which]
+    kw = dict(kw, top_k=3, first=4, held=4, budget=budget, tile_rows=8)
+    if "norm_topk_prob" in kw:
+        kw["select_bias"] = 0.3 * jax.random.normal(jax.random.key(8), (16,))
+    return scores(), dict(kw, **over)
+
+
+@pytest.mark.parametrize("which", sorted(ROUTES))
+def test_compact_combine_is_a_gather_a_choice(which):
+    """The combine over ``route``'s compaction against a gather of every
+    token a choice, and the transposes of the dispatch and the combine
+    through ``jax.vjp`` in the tokens and the scores: equal, element for
+    element, in float32 and bfloat16."""
+    from flexflow_tpu.ops.moe import collect_rows, spread_rows
+    s, kw = _route_case(which)
+    r = route(s, **kw)
+    kept = np.asarray(r["slot_row"]) < len(r["row_token"])
+    spans = [block.shape for block in r["compact_rows"]]
+    if which == "full-top-rank":         # 48 // 3 places hold three choices
+        assert (kept.sum(axis=1) == np.repeat([3, 0], [16, 48])).all()
+        assert spans == [(16, 3), (8, 2), (24, 1)]
+    if which == "full-first-rank":       # as many places as the budget
+        assert (kept.sum(axis=1) == np.repeat([1, 0], [48, 16])).all()
+        assert spans[-1] == (24, 1) and sum(n for n, _ in spans) == 48
+    if which.endswith("drops"):
+        assert int(r["made"]) > int(r["kept"]) == kw["budget"]
+    if which.endswith("pads"):
+        assert int(r["made"]) == int(r["kept"]) < kw["budget"]
+
+    def layer(spread, collect, compact, x, scores):
+        r = route(scores, **kw)
+        index = (r["row_token"], r["compact_rows"], r["token_position"]) \
+            if compact else (r["row_token"], r["slot_row"])
+        rows = jnp.tanh(spread(x, *index).astype(jnp.float32))
+        return collect((rows * r["row_weight"][:, None]).astype(x.dtype),
+                       *index)
+
+    for dtype in (jnp.float32, jnp.bfloat16):
+        x = jax.random.normal(jax.random.key(1), (64, 32), dtype)
+        g = jax.random.normal(jax.random.key(2), (64, 32), dtype)
+        rows = jax.random.normal(jax.random.key(3), (len(r["row_token"]), 32),
+                                 dtype)
+        np.testing.assert_array_equal(
+            collect_rows(rows, r["row_token"], r["compact_rows"],
+                         r["token_position"]),
+            _by_choice(rows, r["slot_row"]))
+        got, got_vjp = jax.vjp(
+            lambda x, s: layer(spread_rows, collect_rows, True, x, s), x, s)
+        want, want_vjp = jax.vjp(
+            lambda x, s: layer(_spread_by_choice, _collect_by_choice, False,
+                               x, s), x, s)
+        np.testing.assert_array_equal(got, want)
+        for a, b in zip(got_vjp(g), want_vjp(g)):
+            np.testing.assert_array_equal(a, b)
+        assert float(jnp.abs(got_vjp(g)[1]).max()) > 0
+    # neither the dispatch nor the combine, nor their transposes, scatters
+    index = (r["row_token"], r["compact_rows"], r["token_position"])
+    text = jax.jit(jax.grad(lambda x: jnp.sum(collect_rows(
+        jnp.tanh(spread_rows(x, *index)), *index)))).lower(x).as_text()
+    assert "gather" in text and "scatter" not in text
+
+
+def _ties(budget):
+    s = jnp.full((8, 16), 1.0 / 16).at[:, 4:7].set(0.2)
+    return s, dict(SOFTMAX, top_k=3, first=4, held=4, budget=budget,
+                   tile_rows=8)
+
+
+BOUND_ROUTES = {
+    **{which: functools.partial(_route_case, which) for which in ROUTES},
+    "fewer-ranks-than-choices": functools.partial(
+        _route_case, "softmax-groups-drops", budget=2),
+    "one-expert-held": functools.partial(
+        _route_case, "sigmoid-bias-norm-pads", held=1, first=5),
+    "all-choices-here-drops": lambda: (
+        jax.nn.softmax(_logits(4).at[:, 4:8].add(10.0), axis=-1),
+        dict(SOFTMAX, top_k=3, first=4, held=4, budget=48, tile_rows=8)),
+    "ties-5": functools.partial(_ties, 5),
+    "ties-24": functools.partial(_ties, 24)}
+
+
+@pytest.mark.parametrize("which", sorted(BOUND_ROUTES))
+def test_compaction_holds_every_kept_choice_once(which):
+    """Every kept (token, choice) of ``slot_row`` stands once in the
+    compact blocks, in its token's row and in top_k's order among the
+    token's kept choices, the rest of the row past the buffer; the blocks
+    stand place after place, the one of the places ``budget // (r + 1)``
+    to ``budget // r`` (at most ``tokens``) r wide, so no token with r
+    kept choices stands past the first ``budget // r`` places."""
+    s, kw = BOUND_ROUTES[which]()
+    r = route(s, **kw)
+    t, m, budget = s.shape[0], len(r["row_token"]), kw["budget"]
+    slots = np.asarray(r["slot_row"])
+    position = np.asarray(r["token_position"])
+    assert sorted(position) == list(range(t))
+    order = np.argsort(position)
+    ranks = min(3, kw["held"], budget)
+    ends = [min(t, budget // rank) for rank in range(1, ranks + 1)] + [0]
+    want = [(ends[rank - 1] - ends[rank], rank)
+            for rank in range(ranks, 0, -1) if ends[rank] < ends[rank - 1]]
+    assert [block.shape for block in r["compact_rows"]] == want
+    got = [None] * t
+    place = 0
+    for block in map(np.asarray, r["compact_rows"]):
+        for row in block:
+            kept = [v for v in row if v < m]
+            assert list(row) == kept + [m] * (len(row) - len(kept))
+            got[order[place]] = kept
+            place += 1
+    assert place == ends[0]
+    for tok in range(t):
+        assert (got[tok] or []) == [row for row in slots[tok] if row < m]
+    assert sum(map(len, filter(None, got))) == int(r["kept"]) <= budget
+    assert int(r["with_choice"]) == sum(1 for rows in got if rows)
+
+
+@pytest.mark.parametrize("capacity", [0.25, 3.0])
+def test_tokens_with_choice_share_is_counted(monkeypatch, capacity):
+    """``moe_tokens_with_choice_share`` as the metric drain folds what the
+    layers counted: over two calls, the share of their tokens with a kept
+    choice here, from ``slot_row`` in numpy."""
+    from flexflow_tpu.ops.moe import router_scores
+    from flexflow_tpu.runtime import profiling
+    monkeypatch.setattr(profiling, "_counters", {})
+    op = _bare_op(RoutedExperts, 16, 3, 32, n_group=4, topk_group=2,
+                  experts_held=4, first_expert=4, capacity_factor=capacity,
+                  tile_rows=8)
+    assert "moe_tokens_with_choice" in op.COUNTERS
+    with_choice = []
+    for seed in (0, 1):
+        p = _random(op, seed)
+        x = jax.random.normal(jax.random.key(seed + 7), (2, 16, 64))
+        counts = {}
+        op.forward(p, [x], FwdCtx(counters=counts))
+        profiling.count({k: float(v) for k, v in counts.items()})
+        r = route(router_scores(x.reshape(-1, 64), p["router"]), top_k=3,
+                  n_group=4, topk_group=2, first=4, held=4,
+                  budget=op.budget(32), tile_rows=8)
+        slots = np.asarray(r["slot_row"])
+        with_choice.append((slots < len(r["row_token"])).any(axis=1))
+    want = np.concatenate(with_choice).mean()
+    assert 0 < want < 1
+    got = profiling.counters()
+    assert got["moe_tokens_with_choice_share"] == pytest.approx(want)
+    assert (got["moe_dropped_share"] > 0) == (capacity < 1)
 
 
 def test_padding_adds_nothing_and_shapes_ignore_the_seed():

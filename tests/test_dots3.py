@@ -258,11 +258,15 @@ def test_the_reported_loss_is_the_objective(devices):
 # signature, the one every steady step ran), where before they were those
 # of the signature that only the first step ran.  PERF.md section 6,
 # PR 38, has the command that computed them there and what it printed.
+# DeepSeek-V2's two were computed anew with this function when the
+# routed experts' combine took route's compaction (ops/moe.py
+# collect_rows, one more counter): its program changed there on purpose,
+# GPT-2's did not.
 STEP_DIGESTS = {
     "deepseek-v2/float32":
-    "fbe75e0af80c273c6e229a4821b2e9b83e11254e85a06d305e981f658dc9c805",
+    "cac5fda5b1d9915e37aa323aa0c86f7aa5f05159ceedf4f67884148a9fe0d614",
     "deepseek-v2/bfloat16":
-    "02c6cfdf8a4e81269e34c7bfc7d246dd737950764fb3a7612f11bf3cce02c030",
+    "502bf0376c8e1418357fd35546ba728ee5cd74d4d314ba33defe8b68cddee99b",
     "gpt2/bfloat16":
     "53ef7a97cb3ddb0f6c1e5141bec7bc3dc10e89e11d5cd3ee40218cec8c843628"}
 
